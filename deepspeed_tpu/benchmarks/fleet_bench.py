@@ -1381,6 +1381,8 @@ def _ensure_virtual_devices(n: int = 8) -> None:
 
 
 def main(argv=None):
+    from ..utils.platform import enable_compile_cache
+    enable_compile_cache()       # before any compile
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--n-requests", type=int, default=8)
     ap.add_argument("--max-new-tokens", type=int, default=32)

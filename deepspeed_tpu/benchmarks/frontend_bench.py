@@ -618,6 +618,8 @@ def run_bench(n_requests: int = 48, overload_factor: float = 4.0,
 
 
 def main(argv=None):
+    from ..utils.platform import enable_compile_cache
+    enable_compile_cache()       # before any compile
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--n-requests", type=int, default=48)
     ap.add_argument("--overload-factor", type=float, default=4.0)

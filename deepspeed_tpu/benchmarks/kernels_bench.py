@@ -82,7 +82,8 @@ def megakernel_case(spec_s: int = 4, seed: int = 0) -> dict:
     from ..ops.quantizer import quantize_kv
     from ..serving.sampling import filter_logits, fused_sample_tokens
 
-    on_tpu = jax.default_backend() == "tpu"
+    from ..utils.platform import on_chip
+    on_tpu = on_chip()
 
     # ---- parity leg: small geometry the interpreter can chew ----------
     # int8 pools need sublane-aligned blocks (bs % 32 == 0)
@@ -110,8 +111,8 @@ def megakernel_case(spec_s: int = 4, seed: int = 0) -> dict:
 
     # composed reference (impl="xla"): dequantizing gather through the
     # table, then the masked einsum over the dense view — the exact
-    # program the engine falls back to. Fused: the Pallas megakernel
-    # (interpret mode off-TPU).
+    # program the engine runs at decode_impl="xla". Fused: the Pallas
+    # megakernel (interpret mode on the CPU).
     composed = paged_decode_attention(
         q, k_pool, v_pool, table, fill + s, scale=scale,
         k_scale=ks_pool, v_scale=vs_pool, impl="xla")
@@ -256,7 +257,8 @@ def decode_microbench_case() -> dict:
     interpreter — so the value is null and benchdiff reports the metric
     as skipped (never missing)."""
     import jax
-    if jax.default_backend() != "tpu":
+    from ..utils.platform import on_chip
+    if not on_chip():
         return {"value": None, "skipped_on": jax.default_backend()}
     root = os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
@@ -275,6 +277,8 @@ def run_bench(spec_s: int = 4, seed: int = 0) -> dict:
 
 
 def main(argv=None):
+    from ..utils.platform import enable_compile_cache
+    enable_compile_cache()       # before any compile
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--spec-s", type=int, default=4,
                     help="speculative verify width (query positions per "

@@ -1020,6 +1020,8 @@ def run_bench(n_requests: int = 8, max_new_tokens: int = 32,
 
 
 def main(argv=None):
+    from ..utils.platform import enable_compile_cache
+    enable_compile_cache()       # before any compile
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--n-requests", type=int, default=8)
     ap.add_argument("--max-new-tokens", type=int, default=32)

@@ -33,7 +33,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..utils.jax_compat import shard_map  # check_vma/check_rep + jax-version shim
+from jax import shard_map
 
 from ..parallel import mesh as mesh_lib
 from ..utils.logging import logger

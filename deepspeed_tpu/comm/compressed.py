@@ -36,7 +36,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from ..utils.jax_compat import shard_map  # check_vma/check_rep + jax-version shim
+from jax import shard_map
 
 import numpy as np
 

@@ -8,6 +8,12 @@ Env contract written for each child (consumed by ``comm.init_distributed``):
   NUM_PROCESSES        world size (total processes across hosts)
   PROCESS_ID           this child's global rank
   LOCAL_RANK           this child's slot on this host
+
+This parent never initialises a JAX backend (it only imports the package),
+so its children find the host's chips free. On a TPU host that means ONE
+child: a JAX process takes every local chip, nothing here divides a host's
+chips among several, and a second child dies at backend init — so more than
+one slot on a TPU host is refused with the reason (:func:`_refuse_chip_sharing`).
 """
 
 from __future__ import annotations
@@ -47,6 +53,34 @@ def global_rank_mapping(world_info: Dict[str, List[int]]) -> Dict[str, List[int]
     return mapping
 
 
+def _tpu_device_nodes() -> List[str]:
+    """The host's TPU device nodes, found without touching JAX (a v5e VM
+    exposes its chips under /dev/vfio; older generations as /dev/accel*)."""
+    import glob
+    return sorted(glob.glob("/dev/accel*") + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _refuse_chip_sharing(n_local: int) -> None:
+    """More than one slot on a host whose children would run on its TPU
+    chips cannot work: every child's JAX takes ALL local chips, and all
+    but the first fail with "Unable to initialize backend 'tpu': ABORTED:
+    Internal error when accessing libtpu multi-process lockfile" (v5e,
+    PR 21). Dividing a host's chips among processes is not implemented;
+    one process per host drives all of them (the mesh's dp/tp/pp axes
+    divide the chips)."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if n_local <= 1 or platforms.split(",")[0] == "cpu":
+        return
+    nodes = _tpu_device_nodes()
+    if nodes:
+        raise SystemExit(
+            f"launch: {n_local} slots asked on a host with TPU chips "
+            f"({', '.join(nodes)}): a JAX process takes every local chip, so "
+            f"all but the first child would die at backend init. Launch ONE "
+            f"slot per TPU host (it drives all local chips through the "
+            f"mesh), or set JAX_PLATFORMS=cpu for a CPU rehearsal.")
+
+
 def main(args=None):
     args = parse_args(args)
     world_info = decode_world_info(args.world_info)
@@ -58,6 +92,7 @@ def main(args=None):
 
     logger.info(f"node {args.node_rank} ({node_host}): slots={local_slots}, "
                 f"world_size={world_size}")
+    _refuse_chip_sharing(len(local_slots))
 
     children: List[subprocess.Popen] = []
     for local_rank, slot in enumerate(local_slots):

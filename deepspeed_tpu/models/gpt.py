@@ -210,17 +210,21 @@ class GPTConfig:
     # (pinned_host); everything else recomputes in backward. Activation HBM
     # becomes O(one layer) regardless of depth. Requires remat=True.
     cpu_checkpointing: bool = False
-    # "auto" resolves to the Pallas flash kernel on TPU (measured ~1.6x
-    # train-step speedup over the einsum path at seq 1024 on v5e) and to the
-    # XLA einsum elsewhere (partition-friendly on the virtual CPU mesh)
+    # "auto" resolves to the Pallas flash kernel on a TPU when its gate
+    # accepts the shape and to the XLA einsum on the CPU test mesh, logging
+    # the choice once; "pallas" asks for the kernel by name and raises where
+    # "auto" would take the einsum. Kernel against einsum as a train step:
+    # not measured (PERF.md, PR 21 has the kernel-level smoke readings).
     attention_impl: str = "auto"     # auto | xla | pallas | sparse
     sparse_attention: Any = None     # SparsityConfig when attention_impl=sparse
-    # "auto" resolves to the fused prefix-only Pallas kernel on TPU (manual
-    # DMA pipeline over live cache blocks — O(cache_len) HBM traffic; the
-    # KV cache is stored FLAT [b, S, h*d] so XLA's d-dim lane padding never
-    # costs a relayout) and to the masked einsum elsewhere. Default stays
-    # "xla" until the kernel shows a measured win on hardware (the r2 grid
-    # version lost to XLA; this rewrite is pending chip re-measurement).
+    # "auto" resolves to the fused prefix-only Pallas kernel on a TPU when
+    # its gate accepts the shape (manual DMA pipeline over live cache blocks
+    # — O(cache_len) HBM traffic; the KV cache is stored FLAT [b, S, h*d] so
+    # XLA's d-dim lane padding never costs a relayout) and to the masked
+    # einsum otherwise, logging the choice once; "pallas" asks by name and
+    # raises where "auto" would take the einsum. Default stays "xla": the
+    # kernel compiles and agrees with the einsum on a v5e (PR 21) but has
+    # not been timed against it (ROADMAP S5).
     decode_impl: str = "xla"         # auto | xla | pallas
     # KV-cache storage dtype: "auto" stores at the compute dtype; "int8"
     # stores symmetric per-token-group int8 (ops/quantizer.quantize_kv —
@@ -336,6 +340,44 @@ def rotary_embedding(x: jnp.ndarray, positions: jnp.ndarray, rotary_dim: int):
     return jnp.concatenate([rot.astype(x.dtype), x_pass], axis=-1)
 
 
+# GSPMD cannot partition a Mosaic custom call: a Pallas kernel inside a jit
+# over several devices fails at lowering with "Mosaic kernels cannot be
+# automatically partitioned. Please wrap the call in a shard_map" (v5e x4,
+# PR 21 — the first time any of this met more than one real chip).
+_NO_AUTO_PARTITION = ("GSPMD cannot partition a Mosaic custom call and this "
+                      "kernel call is not wrapped in shard_map")
+
+
+def _mesh_refusal(b: int, h: int) -> Optional[str]:
+    """Why the flash kernel cannot run [b, S, h, d] over the constraint
+    mesh; None when it can. On several devices the kernel runs under
+    shard_map — batch over dp, heads over tp — so both must divide."""
+    from ..parallel import mesh as mesh_lib
+    mesh = mesh_lib.get_constraint_mesh()
+    dp, tp = mesh.shape["dp"], mesh.shape["tp"]
+    if mesh.size > 1 and (b % dp or h % tp):
+        return (f"batch {b} / heads {h} do not divide over the mesh's "
+                f"dp={dp} / tp={tp} ({_NO_AUTO_PARTITION} unless they do)")
+    return None
+
+
+def _flash_over_mesh(q, k, v, scale):
+    """The flash kernel over the constraint mesh. Attention is independent
+    across batch rows and heads, so on several devices each runs the kernel
+    on its own [b/dp, S, h/tp, d] shard under shard_map. A shape that does
+    not divide (or a one-device mesh) calls the kernel directly — right in
+    a single-device jit, and JAX's own error in a multi-device one."""
+    from ..ops.pallas.flash_attention import flash_attention
+    from ..parallel import mesh as mesh_lib
+    fn = partial(flash_attention, causal=True, sm_scale=scale)
+    mesh = mesh_lib.get_constraint_mesh()
+    if mesh.size == 1 or _mesh_refusal(q.shape[0], q.shape[2]) is not None:
+        return fn(q, k, v)
+    spec = P("dp", None, "tp", None)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+
 def causal_attention(q, k, v, *, dtype, impl: str = "xla", sparse_config=None,
                      mask: Optional[jnp.ndarray] = None,
                      scale: Optional[float] = None,
@@ -344,11 +386,17 @@ def causal_attention(q, k, v, *, dtype, impl: str = "xla", sparse_config=None,
     ``window``: local (sliding-window) attention over the last N keys."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if impl == "pallas" and window is None:
-        from ..ops.pallas.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=True, sm_scale=scale)
+    if impl in ("auto", "pallas"):
+        from ..ops.pallas import _utils as kernels
+        from ..ops.pallas.flash_attention import flash_refusal
+        refusal = ("the flash kernel has no local-window mask"
+                   if window is not None else flash_refusal(q.shape[1]))
+        if impl == "pallas" and refusal is not None:
+            kernels.refuse("attention_impl='pallas'", q.shape, refusal)
+        if impl == "pallas" or kernels.auto_path(
+                "attention", refusal or _mesh_refusal(q.shape[0],
+                                                      q.shape[2])):
+            return _flash_over_mesh(q, k, v, scale)
     if impl == "sparse" and sparse_config is not None:
         from ..ops.sparse_attention.sparse_self_attention import sparse_attention
         # causal=True regardless of the layout's attention mode: a decoder
@@ -367,6 +415,18 @@ def causal_attention(q, k, v, *, dtype, impl: str = "xla", sparse_config=None,
         logits = jnp.where(mask[:, None, None, :], logits, -1e10)
     probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def _decode_mesh_refusal() -> Optional[str]:
+    """Compiled by Mosaic, the decode kernels run on a one-device mesh only
+    (one serving engine per chip is ROADMAP R9's placement). Interpreted on
+    the CPU test mesh there is no custom call for GSPMD to refuse."""
+    from ..parallel import mesh as mesh_lib
+    from ..utils.platform import on_chip
+    n = mesh_lib.get_constraint_mesh().size
+    if n > 1 and on_chip():
+        return f"mesh of {n} devices: {_NO_AUTO_PARTITION}"
+    return None
 
 
 class SelfAttention(nn.Module):
@@ -416,9 +476,17 @@ class SelfAttention(nn.Module):
                     # each chip attends over the FULL sequence for H/sp
                     # heads. The einsum path partitions over heads under
                     # GSPMD; the pallas custom call does not
-                    # auto-partition, so force xla here
+                    # auto-partition, so Ulysses runs the einsum
                     q, k, v = map(sp_shard_heads, (q, k, v))
-                    if impl in ("auto", "pallas"):
+                    why = ("sequence_parallel (Ulysses) head-shards q/k/v "
+                           "under GSPMD, which cannot partition the Pallas "
+                           "custom call")
+                    from ..ops.pallas import _utils as kernels
+                    if impl == "pallas":
+                        kernels.refuse("attention_impl='pallas'", q.shape,
+                                       why)
+                    if impl == "auto":
+                        kernels.log_path_once("attention", "xla", why)
                         impl = "xla"
                 out = causal_attention(q, k, v, dtype=cfg.dtype,
                                        impl=impl,
@@ -470,15 +538,23 @@ class SelfAttention(nn.Module):
             # injected per-slot block tables, so reads and writes route
             # through them instead of slot rows
             return self._paged_decode_attention(q, k, v)
-        impl = cfg.decode_impl
-        if impl == "auto":
-            impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-        from ..ops.pallas.decode_attention import pallas_decode_supported
+        from ..ops.pallas import _utils as kernels
+        from ..ops.pallas.decode_attention import decode_refusal
         int8 = cfg.kv_cache_dtype == "int8"
         kv_dt = jnp.int8 if int8 else cfg.dtype
-        use_flat = (impl == "pallas" and self.window is None
-                    and pallas_decode_supported(b, cfg.max_seq_len, h, d,
-                                                cfg.dtype))
+        # the cache LAYOUT follows the choice (flat for the kernel), so the
+        # gate is asked at the decode width s=1 whatever this call's width
+        refusal = ("the decode kernel has no local-window path"
+                   if self.window is not None else
+                   decode_refusal(b, cfg.max_seq_len, h, d, cfg.dtype)
+                   or _decode_mesh_refusal())
+        impl = cfg.decode_impl
+        if impl == "pallas" and refusal is not None:
+            kernels.refuse("decode_impl='pallas'",
+                           f"b={b} S={cfg.max_seq_len} h={h} d={d}", refusal)
+        use_flat = impl == "pallas" or (
+            impl == "auto" and kernels.auto_path("decode_attention",
+                                                 refusal))
         scale = (cfg.qk_scale if cfg.qk_scale is not None
                  else 1.0 / math.sqrt(d))
         idx = self.variable("cache", "cache_index",
@@ -543,14 +619,6 @@ class SelfAttention(nn.Module):
             ck.value = _kv_write(ck.value, k.astype(cfg.dtype), cur)
             cv.value = _kv_write(cv.value, v.astype(cfg.dtype), cur)
         idx.value = cur + s
-        if self.window is None and impl == "pallas" and not int8:
-            from ..ops.pallas.decode_attention import (MAX_SPEC_S,
-                                                       decode_attention)
-            if s == 1 or (s <= MAX_SPEC_S and not cfg.sequence_parallel):
-                # rank-4 cache: decode_attention relayouts the view, but
-                # keeps spec widths on the fused kernel path
-                return decode_attention(q, ck.value, cv.value, cur + s,
-                                        scale=scale)
         if int8:
             from ..ops.quantizer import dequantize_kv
             kf = dequantize_kv(ck.value, ksc.value[..., None], cfg.dtype)
@@ -578,9 +646,6 @@ class SelfAttention(nn.Module):
         if self.window is not None:
             raise NotImplementedError(
                 "paged KV decode has no local-window path")
-        impl = cfg.decode_impl
-        if impl == "auto":
-            impl = "pallas" if jax.default_backend() == "tpu" else "xla"
         int8 = cfg.kv_cache_dtype == "int8"
         scale = (cfg.qk_scale if cfg.qk_scale is not None
                  else 1.0 / math.sqrt(d))
@@ -588,6 +653,18 @@ class SelfAttention(nn.Module):
         ck = self.variable("cache", "cached_key")
         cv = self.variable("cache", "cached_value")
         bt = self.get_variable("cache", "block_tables")
+        from ..ops.pallas import _utils as kernels
+        from ..ops.pallas.decode_attention import paged_decode_refusal
+        refusal = paged_decode_refusal(b, ck.value.shape[1], h, d,
+                                       ck.value.dtype, s) \
+            or _decode_mesh_refusal()
+        impl = cfg.decode_impl
+        if impl == "pallas" and refusal is not None:
+            kernels.refuse("decode_impl='pallas'",
+                           f"q={q.shape} pool={ck.value.shape}", refusal)
+        if impl == "auto":
+            impl = ("pallas" if kernels.auto_path("paged_decode_attention",
+                                                  refusal) else "xla")
         cur = idx.value                       # [b] per-slot write positions
         ksc = vsc = None
         if int8:
@@ -854,9 +931,13 @@ def count_params(params) -> int:
 
 
 def gpt_flops_per_token(cfg: GPTConfig, seq_len: Optional[int] = None) -> float:
-    """6N + attention flops per token (for MFU accounting)."""
+    """Training flops per token for MFU accounting: 6 per matmul weight
+    (forward + backward) plus the attention score/context term. Each
+    matmul weight counts ONCE: per layer the qkv and out projections
+    (4 d^2) and the MLP's up and down (2 d d_ff); the logits matmul
+    (V d — the embedding lookup is a gather and costs nothing).
+    Recomputation under remat does not count."""
     s = seq_len or cfg.max_seq_len
-    n = (12 * cfg.d_model ** 2 + 2 * cfg.d_model * cfg.d_ff) * cfg.num_layers \
-        + 2 * cfg.vocab_size * cfg.d_model
-    # dense params approx: use actual 6*N plus attention quadratic term
+    n = (4 * cfg.d_model ** 2 + 2 * cfg.d_model * cfg.d_ff) * cfg.num_layers \
+        + cfg.vocab_size * cfg.d_model
     return 6.0 * n + 12.0 * cfg.num_layers * cfg.d_model * s

@@ -40,22 +40,15 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._utils import interpret_mode
-
-# jax >= 0.5 renames TPUMemorySpace -> MemorySpace (and ANY -> HBM for
-# refs the kernel DMAs out of itself); accept either so the kernel runs
-# against both toolchains
-if hasattr(pltpu, "MemorySpace"):
-    _MEM_HBM = pltpu.MemorySpace.HBM
-else:
-    _MEM_HBM = pltpu.TPUMemorySpace.ANY
+from ._utils import interpret_mode, refuse
 
 NEG_INF = float(np.finfo(np.float32).min)
 
 # Widest speculative-verify query width (k+1 draft positions) the kernels
 # take in-kernel. The qmat lane dim is s*hp, so wider shapes would start
-# eating MXU lanes for masked-out work; past this the wrappers fall back
-# to the gather/einsum path (prefill always does — s there is prompt-len).
+# eating MXU lanes for masked-out work; past this the gates refuse and the
+# model takes the masked einsum (prefill always does — s there is
+# prompt-len).
 MAX_SPEC_S = 8
 
 
@@ -179,7 +172,11 @@ def _pick_block(s: int, want: int = 256) -> Optional[int]:
     return s if s <= 128 and s % 8 == 0 else None
 
 
-_VMEM_BUDGET = 8 * 1024 * 1024   # staging window budget (2 slots x k+v)
+# Staging window budget (2 slots x k+v). At GPT-2 125M (b 8, h*d 768) the
+# 256-row bf16 window is 6 MiB and compiles on a v5e inside Mosaic's
+# default scoped VMEM limit (chip_smoke kernel leg, PR 21); nothing larger
+# has been through the compiler, so the gate stays here.
+_VMEM_BUDGET = 8 * 1024 * 1024
 
 
 def _choose_block(b: int, S: int, h: int, d: int, itemsize: int,
@@ -206,18 +203,31 @@ def _choose_block(b: int, S: int, h: int, d: int, itemsize: int,
     return bk
 
 
+def decode_refusal(b: int, S: int, h: int, d: int, dtype, s: int = 1,
+                   block_k: Optional[int] = None) -> Optional[str]:
+    """Why the dense kernel cannot run this shape; None when it can.
+    Callers choosing a cache LAYOUT (models/gpt.py flat cache) ask the same
+    gate the kernel does. ``s``: query positions per lane (1 = plain
+    decode, 2..MAX_SPEC_S = the speculative-verify shape)."""
+    if not 1 <= s <= MAX_SPEC_S:
+        return (f"query width s={s} outside 1..{MAX_SPEC_S} (wider calls "
+                f"are prefill and take the masked einsum)")
+    if (h * d) % 128 != 0:
+        return f"h*d={h * d} is not a multiple of the 128-lane tile"
+    itemsize = jnp.dtype(dtype).itemsize
+    if _choose_block(b, S, h, d, itemsize, block_k) is None:
+        return (f"no kv block: cache length {S} must split into blocks of "
+                f">= 128 rows (or be <= 128 and a multiple of 8) whose "
+                f"double-buffered k+v window 4*b*bk*h*d*{itemsize} B stays "
+                f"within the {_VMEM_BUDGET >> 20} MiB staging budget "
+                f"(b={b}, h*d={h * d}: the 128-row window is "
+                f"{4 * b * 128 * h * d * itemsize / 2 ** 20:.1f} MiB)")
+    return None
+
+
 def pallas_decode_supported(b: int, S: int, h: int, d: int, dtype,
                             s: int = 1) -> bool:
-    """Callers choosing a cache LAYOUT (models/gpt.py flat cache) must agree
-    with the kernel's own feasibility test — a flat cache whose every decode
-    falls back to the XLA path would pay a full-cache relayout per token.
-    ``s``: query positions per lane (1 = plain decode, 2..MAX_SPEC_S = the
-    speculative-verify shape)."""
-    if not 1 <= s <= MAX_SPEC_S:
-        return False
-    if (h * d) % 128 != 0:
-        return False
-    return _choose_block(b, S, h, d, jnp.dtype(dtype).itemsize) is not None
+    return decode_refusal(b, S, h, d, dtype, s) is None
 
 
 def _spec_qmat(q: jnp.ndarray, hp: int) -> jnp.ndarray:
@@ -262,30 +272,25 @@ def decode_attention(q: jnp.ndarray, cached_key: jnp.ndarray,
     output is garbage the caller discards, never an OOB access.
     ``k_scale``/``v_scale`` [b, S] f32 mark an int8 cache
     (kv_cache_dtype="int8"): per-position dequant multipliers, applied in
-    VMEM on the Pallas path and before the masked einsum on the fallback.
+    VMEM.
     ``s_q`` in 2..MAX_SPEC_S is the speculative-verify shape and stays on
-    the kernel (s-position qmat); wider s_q (prefill) falls back.
+    the kernel (s-position qmat). A shape the gate refuses
+    (:func:`decode_refusal`) raises ``KernelUnsupported``; callers that
+    may take the masked einsum instead ask the gate first.
     Returns [b, s_q, h, d] (so [b, 1, h, d] for plain decode)."""
     b, s_q, h, d = q.shape
     S = cached_key.shape[1]
     cache_len = jnp.minimum(jnp.asarray(cache_len, jnp.int32), S)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    reason = decode_refusal(b, S, h, d, cached_key.dtype, s_q, block_k)
+    if reason is not None:
+        refuse("decode_attention",
+               f"q={q.shape} cache={cached_key.shape}", reason)
     bk = _choose_block(b, S, h, d, jnp.dtype(cached_key.dtype).itemsize,
                        block_k)
     flat = cached_key.ndim == 3
     quantized = k_scale is not None
-    if not 1 <= s_q <= MAX_SPEC_S or bk is None or (h * d) % 128 != 0:
-        if quantized:
-            from ..quantizer import dequantize_kv
-            sk = k_scale[..., None] if flat else k_scale[..., None, None]
-            sv = v_scale[..., None] if flat else v_scale[..., None, None]
-            cached_key = dequantize_kv(cached_key, sk, q.dtype)
-            cached_value = dequantize_kv(cached_value, sv, q.dtype)
-        if flat:
-            cached_key = cached_key.reshape(b, S, h, d)
-            cached_value = cached_value.reshape(b, S, h, d)
-        return _xla_decode(q, cached_key, cached_value, cache_len, scale)
 
     hp = -(-h // 8) * 8
     hd = h * d
@@ -310,8 +315,8 @@ def decode_attention(q: jnp.ndarray, cached_key: jnp.ndarray,
         pl.BlockSpec((b, hd, s_q * hp), lambda g, meta: (0, 0, 0)),
         # the cache never enters VMEM wholesale: the kernel DMAs only
         # live blocks out of HBM
-        pl.BlockSpec(memory_space=_MEM_HBM),
-        pl.BlockSpec(memory_space=_MEM_HBM),
+        pl.BlockSpec(memory_space=pltpu.HBM),
+        pl.BlockSpec(memory_space=pltpu.HBM),
     ]
     scratch = [
         pltpu.VMEM((2, b, bk, hd), cached_key.dtype),
@@ -320,8 +325,8 @@ def decode_attention(q: jnp.ndarray, cached_key: jnp.ndarray,
     sems = [pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA((2,))]
     operands = [meta, qmat, kf, vf]
     if quantized:
-        in_specs += [pl.BlockSpec(memory_space=_MEM_HBM),
-                     pl.BlockSpec(memory_space=_MEM_HBM)]
+        in_specs += [pl.BlockSpec(memory_space=pltpu.HBM),
+                     pl.BlockSpec(memory_space=pltpu.HBM)]
         scratch += [pltpu.VMEM((2, b, bk), jnp.float32),
                     pltpu.VMEM((2, b, bk), jnp.float32)]
         sems += [pltpu.SemaphoreType.DMA((2,)),
@@ -338,6 +343,7 @@ def decode_attention(q: jnp.ndarray, cached_key: jnp.ndarray,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s_q * hp, hd), q.dtype),
+        name="decode_attention",
         interpret=interpret_mode(),
     )(*operands)
     # block diagonal: (query i, head g)'s output is row i*hp+g, segment g
@@ -359,33 +365,29 @@ def _paged_decode_kernel(meta_ref, bt_ref, qmat_ref, *refs, scale, b, hp,
     over rows), [1 + bi] row bi's filled prefix. bt_ref: [b, T] block
     tables (scalar-prefetch, so the DMA source indices are host-known
     ints at issue time); entries past a row's reservation are clamped
-    into the pool and masked dead by the fill. ``quantized``: int8 pools
-    with per-position f32 dequant multiplier pools ks_hbm/vs_hbm
-    [nb_total, bs], DMA'd per-(row, block) alongside the payload and
-    applied in VMEM. ``s``: static query positions per lane (the
+    into the pool and masked dead by the fill. ``quantized``: int8 pools;
+    the per-position f32 dequant multipliers arrive ALREADY gathered
+    through the table as VMEM blocks ks_ref/vs_ref [b, T, bs] (4 bytes a
+    position against h*d payload bytes — see the wrapper for why they are
+    not DMA'd per block) and are applied in VMEM.
+    ``s``: static query positions per lane (the
     speculative-verify shape — same widened qmat / staggered mask as
     :func:`_decode_kernel`)."""
     if quantized:
-        (k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
-         k_sem, v_sem, ks_sem, vs_sem) = refs
+        (k_hbm, v_hbm, ks_ref, vs_ref, o_ref, k_buf, v_buf, k_sem,
+         v_sem) = refs
     else:
         k_hbm, v_hbm, o_ref, k_buf, v_buf, k_sem, v_sem = refs
     nb = meta_ref[0]
 
     def row_copies(i, slot, bi):
         blk = jnp.minimum(bt_ref[bi, i], nb_total - 1)
-        out = [
+        return [
             pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[slot, bi],
                                   k_sem.at[slot, bi]),
             pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[slot, bi],
                                   v_sem.at[slot, bi]),
         ]
-        if quantized:
-            out.append(pltpu.make_async_copy(
-                ks_hbm.at[blk], ks_buf.at[slot, bi], ks_sem.at[slot, bi]))
-            out.append(pltpu.make_async_copy(
-                vs_hbm.at[blk], vs_buf.at[slot, bi], vs_sem.at[slot, bi]))
-        return out
 
     for bi in range(b):                    # prologue: stage block 0
         for c in row_copies(0, 0, bi):
@@ -413,8 +415,8 @@ def _paged_decode_kernel(meta_ref, bt_ref, qmat_ref, *refs, scale, b, hp,
             kbk = k_buf[slot, bi].astype(jnp.float32)     # [bs, h*d]
             vbk = v_buf[slot, bi].astype(jnp.float32)
             if quantized:
-                kbk = kbk * ks_buf[slot, bi][:, None]
-                vbk = vbk * vs_buf[slot, bi][:, None]
+                kbk = kbk * ks_ref[bi, i][:, None]
+                vbk = vbk * vs_ref[bi, i][:, None]
             qmat = qmat_ref[bi].astype(jnp.float32)       # [h*d, s*hp]
             sc = jax.lax.dot(kbk, qmat,
                              preferred_element_type=jnp.float32) * scale
@@ -438,21 +440,32 @@ def _paged_decode_kernel(meta_ref, bt_ref, qmat_ref, *refs, scale, b, hp,
     o_ref[...] = (acc / l_safe[:, :, None]).astype(o_ref.dtype)
 
 
-def paged_decode_supported(b: int, block_size: int, h: int, d: int,
-                           dtype, s: int = 1) -> bool:
-    """Kernel feasibility for the paged layout: lane-aligned h*d,
-    sublane-aligned block_size (the DMA unit), the double-buffered
-    staging window within the VMEM budget, and the query width s within
-    the in-kernel speculative-verify range (1..MAX_SPEC_S)."""
+def paged_decode_refusal(b: int, block_size: int, h: int, d: int,
+                         dtype, s: int = 1) -> Optional[str]:
+    """Why the paged kernel cannot run this shape; None when it can:
+    lane-aligned h*d, sublane-aligned block_size (the DMA unit), the
+    double-buffered staging window within the VMEM budget, and the query
+    width s within the in-kernel speculative-verify range."""
     if not 1 <= s <= MAX_SPEC_S:
-        return False
+        return f"query width s={s} outside 1..{MAX_SPEC_S}"
     if (h * d) % 128 != 0:
-        return False
+        return f"h*d={h * d} is not a multiple of the 128-lane tile"
     itemsize = jnp.dtype(dtype).itemsize
     sublane = max(8, 32 // itemsize)
     if block_size % sublane != 0:
-        return False
-    return 4 * b * block_size * h * d * itemsize <= _VMEM_BUDGET
+        return (f"kv block_size={block_size} is not a multiple of the "
+                f"{sublane}-row sublane tile of {jnp.dtype(dtype).name} "
+                f"(the per-block DMA unit)")
+    window = 4 * b * block_size * h * d * itemsize
+    if window > _VMEM_BUDGET:
+        return (f"double-buffered k+v window {window / 2 ** 20:.1f} MiB "
+                f"exceeds the {_VMEM_BUDGET >> 20} MiB staging budget")
+    return None
+
+
+def paged_decode_supported(b: int, block_size: int, h: int, d: int,
+                           dtype, s: int = 1) -> bool:
+    return paged_decode_refusal(b, block_size, h, d, dtype, s) is None
 
 
 def paged_gather_kv(pool: jnp.ndarray,
@@ -486,13 +499,14 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     sentinel entries past T*bs are clamped. ``k_scale``/``v_scale``
     [nb, bs] f32 mark int8 pools (per-position dequant multipliers).
 
-    The reference path (CPU / unsupported shapes) gathers the pool
-    through the table and calls the SAME masked einsum as the dense
-    decode path — gathered values are bit-identical to the dense
-    arena's rows, masked positions underflow to exact zeros, so greedy
-    outputs are bit-identical to the dense oracle (the tier-1 parity
-    gate). The Pallas path DMAs per-(row, block) through the table —
-    compute and HBM traffic stay O(cache_len) per token."""
+    ``impl="xla"`` (the reference) gathers the pool through the table
+    and calls the SAME masked einsum as the dense decode path — gathered
+    values are bit-identical to the dense arena's rows, masked positions
+    underflow to exact zeros, so greedy outputs are bit-identical to the
+    dense oracle (the tier-1 parity gate). ``impl="pallas"`` DMAs
+    per-(row, block) through the table — compute and HBM traffic stay
+    O(cache_len) per token — and raises ``KernelUnsupported`` at a shape
+    :func:`paged_decode_refusal` refuses."""
     b, s_q, h, d = q.shape
     nb, bs, hd = k_pool.shape
     T = block_tables.shape[1]
@@ -502,8 +516,11 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     quantized = k_scale is not None
-    if (impl == "pallas"
-            and paged_decode_supported(b, bs, h, d, k_pool.dtype, s_q)):
+    if impl == "pallas":
+        reason = paged_decode_refusal(b, bs, h, d, k_pool.dtype, s_q)
+        if reason is not None:
+            refuse("paged_decode_attention",
+                   f"q={q.shape} pool={k_pool.shape}", reason)
         hp = -(-h // 8) * 8
         qmat = _spec_qmat(q, hp)                        # [b, hd, s*hp]
         nb_live = jnp.clip((jnp.max(clen) + bs - 1) // bs, 1, T)
@@ -513,8 +530,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
             bs=bs, nb_total=nb, quantized=quantized, s=s_q)
         in_specs = [
             pl.BlockSpec((b, hd, s_q * hp), lambda g, meta, bt: (0, 0, 0)),
-            pl.BlockSpec(memory_space=_MEM_HBM),
-            pl.BlockSpec(memory_space=_MEM_HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
         ]
         scratch = [
             pltpu.VMEM((2, b, bs, hd), k_pool.dtype),
@@ -525,14 +542,21 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         operands = [meta, block_tables.astype(jnp.int32), qmat,
                     k_pool, v_pool]
         if quantized:
-            in_specs += [pl.BlockSpec(memory_space=_MEM_HBM),
-                         pl.BlockSpec(memory_space=_MEM_HBM)]
-            scratch += [pltpu.VMEM((2, b, bs), jnp.float32),
-                        pltpu.VMEM((2, b, bs), jnp.float32)]
-            sems += [pltpu.SemaphoreType.DMA((2, b)),
-                     pltpu.SemaphoreType.DMA((2, b))]
-            operands += [k_scale.astype(jnp.float32),
-                         v_scale.astype(jnp.float32)]
+            # The scales are gathered through the table HERE, by XLA, and
+            # ride into VMEM whole as [b, T, bs] blocks. A per-block DMA
+            # out of the [nb, bs] scale pool is refused by Mosaic on a v5e
+            # — a block's bs=32 f32 scales are a quarter of a 128-lane
+            # tile: "Slice shape along dimension 1 must be aligned to
+            # tiling (128), but is 32" (PR 21), the same with the pool
+            # reshaped [nb, 1, bs] — and they are 4 bytes a position next
+            # to h*d payload bytes, so the O(S) gather is noise.
+            def gathered(scale):
+                return paged_gather_kv(
+                    scale.astype(jnp.float32)[..., None],
+                    block_tables).reshape(b, T, bs)
+            spec = pl.BlockSpec((b, T, bs), lambda g, meta, bt: (0, 0, 0))
+            in_specs += [spec, spec]
+            operands += [gathered(k_scale), gathered(v_scale)]
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,          # meta + block tables
             grid=(1,),
@@ -544,6 +568,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         out = pl.pallas_call(
             kernel, grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, s_q * hp, hd), q.dtype),
+            name="paged_decode_attention",
             interpret=interpret_mode(),
         )(*operands)
         return _slice_block_diagonal(out, s_q, h, d)
@@ -563,8 +588,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
 
 
 def masked_cache_attention(q, ck, cv, first_q_pos, scale, window=None):
-    """The ONE masked-einsum cache attention (shared by the kernel's XLA
-    fallback and the model's prefill/window paths, so the two can't drift):
+    """The ONE masked-einsum cache attention (the kernels' reference and the
+    model's xla/prefill/window paths, so the two can't drift):
     q [b, s, h, d] with query i at absolute position ``first_q_pos + i``,
     ck/cv [b, S, h, d]; each query sees keys at positions <= its own
     (within the trailing local ``window`` if given). ``first_q_pos``:
@@ -585,8 +610,3 @@ def masked_cache_attention(q, ck, cv, first_q_pos, scale, window=None):
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, cv)
 
-
-def _xla_decode(q, ck, cv, cache_len, scale):
-    """Masked-einsum fallback."""
-    first_q = jnp.asarray(cache_len, jnp.int32) - q.shape[1]
-    return masked_cache_attention(q, ck, cv, first_q, scale)

@@ -17,7 +17,7 @@ head). The backward pass recomputes attention per tile from the saved
 per-row logsumexp — the rematerialization trade the reference makes with
 activation checkpointing, here at kernel granularity.
 
-On non-TPU backends the kernels run in Pallas interpret mode, which is how
+On the CPU backend the kernels run in Pallas interpret mode, which is how
 the CPU test mesh exercises them (tests/test_pallas_ops.py).
 """
 
@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._utils import interpret_mode
+from ._utils import interpret_mode, refuse
 
 NEG_INF = -1e30
 
@@ -128,6 +128,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=interpret_mode(),
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), (qt, kt, vt, out, lse)
@@ -246,6 +247,7 @@ def _flash_bwd(causal, scale, block_q, block_k, res, g):
                                lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), qt.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        name="flash_attention_bwd_dq",
         interpret=interpret_mode(),
     )(qt, kt, vt, dot, lse, delta)
 
@@ -281,6 +283,7 @@ def _flash_bwd(causal, scale, block_q, block_k, res, g):
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
+        name="flash_attention_bwd_dkv",
         interpret=interpret_mode(),
     )(qt, kt, vt, dot, lse, delta)
 
@@ -305,7 +308,8 @@ def _flash_attention_fwd(q, k, v, causal, scale, block_q, block_k):
 _flash_attention.defvjp(_flash_attention_fwd, _flash_bwd)
 
 
-def _reference_attention(q, k, v, causal, scale):
+def reference_attention(q, k, v, causal, scale):
+    """The XLA einsum the kernel is checked against (tests, chip_smoke)."""
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
         s = q.shape[1]
@@ -326,22 +330,33 @@ def _pick_block(s: int, prefer: int) -> Optional[int]:
     return None
 
 
+def flash_refusal(s: int, block_q: int = 1024,
+                  block_k: int = 1024) -> Optional[str]:
+    """Why the kernel cannot run a sequence of length ``s`` (None when it
+    can): the sequence must fit one tile or split into tiles of >= 128."""
+    if _pick_block(s, block_q) is None or _pick_block(s, block_k) is None:
+        return (f"sequence length {s} exceeds one tile and no tile in "
+                f"(1024, 512, 256, 128) divides it")
+    return None
+
+
 def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: int = 1024, block_k: int = 1024):
     """Fused attention. q, k, v: [B, S, H, D] -> [B, S, H, D].
 
-    Default 1024-wide tiles measured fastest on v5e at seq 1024 (2x over
-    128x128); sequences that don't tile at the preferred size degrade to the
-    largest power-of-two tile that divides S, and only fall back to the XLA
-    einsum path when no tile >=128 divides S (dynamic/tiny shapes) —
-    mirroring the reference's kernel-compatibility gating (op_builder
-    ``is_compatible`` checks).
+    Default 1024-wide tiles: at GPT-2 125M shapes (b 8, s 1024, 12 heads of
+    64, bf16) every tile from 128 to 1024 compiles on a v5e and the 1024
+    tile read fastest (chip_smoke kernel leg, PR 21 — a smoke reading, not
+    a benchmark; see PERF.md). Sequences that don't tile at the preferred
+    size degrade to the largest power-of-two tile that divides S; a shape
+    no tile >= 128 divides raises :class:`KernelUnsupported` — callers
+    that may take the einsum instead ask :func:`flash_refusal` first.
     """
     b, s, h, d = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    bq = _pick_block(s, block_q)
-    bk = _pick_block(s, block_k)
-    if bq is None or bk is None:
-        return _reference_attention(q, k, v, causal, scale)
-    return _flash_attention(q, k, v, causal, scale, bq, bk)
+    reason = flash_refusal(s, block_q, block_k)
+    if reason is not None:
+        refuse("flash_attention", q.shape, reason)
+    return _flash_attention(q, k, v, causal, scale,
+                            _pick_block(s, block_q), _pick_block(s, block_k))

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._utils import interpret_mode, rows_block
+from ._utils import interpret_mode, require_rows, rows_block
 
 _SQRT_2_OVER_PI = 0.7978845608028654
 
@@ -43,7 +43,7 @@ def _bwd_kernel(x_ref, b_ref, dy_ref, dx_ref):
 
 
 
-def _run_rowwise(kernel, inputs, d, out_dtype):
+def _run_rowwise(kernel, name, inputs, d, out_dtype):
     n = inputs[0].shape[0]
     bn = rows_block(n, 256)
     specs = []
@@ -58,6 +58,7 @@ def _run_rowwise(kernel, inputs, d, out_dtype):
         in_specs=specs,
         out_specs=pl.BlockSpec((bn, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), out_dtype),
+        name=name,
         interpret=interpret_mode(),
     )(*inputs)
 
@@ -66,7 +67,8 @@ def _run_rowwise(kernel, inputs, d, out_dtype):
 def _bias_gelu_pallas(x, bias):
     orig = x.shape
     d = x.shape[-1]
-    y = _run_rowwise(_fwd_kernel, (x.reshape(-1, d), bias), d, x.dtype)
+    y = _run_rowwise(_fwd_kernel, "bias_gelu_fwd", (x.reshape(-1, d), bias),
+                     d, x.dtype)
     return y.reshape(orig)
 
 
@@ -78,7 +80,7 @@ def _bias_gelu_bwd(res, g):
     x, bias = res
     orig = x.shape
     d = x.shape[-1]
-    dx = _run_rowwise(_bwd_kernel,
+    dx = _run_rowwise(_bwd_kernel, "bias_gelu_bwd",
                       (x.reshape(-1, d), bias, g.reshape(-1, d)), d, x.dtype)
     dx = dx.reshape(orig)
     dbias = jnp.sum(dx.astype(jnp.float32),
@@ -89,12 +91,16 @@ def _bias_gelu_bwd(res, g):
 _bias_gelu_pallas.defvjp(_bias_gelu_fwd, _bias_gelu_bwd)
 
 
+def bias_gelu_reference(x, bias):
+    """The XLA expression the kernel is checked against."""
+    xf = x.astype(jnp.float32) + bias.astype(jnp.float32)
+    return jax.nn.gelu(xf, approximate=True).astype(x.dtype)
+
+
 def bias_gelu(x, bias):
-    """gelu(x + bias) fused. x: [..., D]; bias: [D]. Row counts TPU can't
-    tile fall back to XLA (which fuses this fine anyway)."""
-    import numpy as _n
-    if rows_block(int(_n.prod(x.shape[:-1])), 256) == 0:
-        return jax.nn.gelu(x + bias, approximate=True)
+    """gelu(x + bias) fused. x: [..., D]; bias: [D]. Row counts the kernel
+    cannot tile raise ``KernelUnsupported``."""
+    require_rows("bias_gelu", x.shape, 256)
     return _bias_gelu_pallas(x, bias)
 
 

@@ -4,8 +4,12 @@ Reference analogue: ``csrc/transformer/normalize_kernels.cu`` (2121 LoC of
 fused layer-norm fwd/bwd variants, incl. residual fusions) exposed through
 the transformer kernel. Here: one row-parallel Pallas kernel each for
 forward and input-gradient; the (small) parameter gradients are XLA
-reductions. Saves mean/rstd for the backward pass like the reference's
-training kernels.
+reductions. The backward kernel recomputes each row's mean/rstd from the
+saved input instead of reading saved statistics: two row reductions over
+data already in VMEM, against two extra HBM operands — and the rank-1
+``[rows]`` statistics outputs the first version wrote do not pass Mosaic's
+operand-layout check on a v5e (XLA tiles f32[8192] as T(1024), a 256-row
+block asks for T(256); PERF.md, PR 21).
 """
 
 from __future__ import annotations
@@ -16,91 +20,78 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._utils import interpret_mode, rows_block
+from ._utils import interpret_mode, require_rows, rows_block
 
 
-def _fwd_kernel(x_ref, g_ref, b_ref, y_ref, mean_ref, rstd_ref, *, eps):
-    x = x_ref[...].astype(jnp.float32)
+def _row_stats(x, eps):
     mean = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
-    rstd = jax.lax.rsqrt(var + eps)
-    xhat = (x - mean) * rstd
-    y = xhat * g_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
+    return mean, jax.lax.rsqrt(var + eps)
+
+
+def _fwd_kernel(x_ref, g_ref, b_ref, y_ref, *, eps):
+    x = x_ref[...].astype(jnp.float32)
+    mean, rstd = _row_stats(x, eps)
+    y = ((x - mean) * rstd * g_ref[...].astype(jnp.float32)
+         + b_ref[...].astype(jnp.float32))
     y_ref[...] = y.astype(y_ref.dtype)
-    mean_ref[...] = mean[..., 0]
-    rstd_ref[...] = rstd[..., 0]
 
 
-def _dx_kernel(x_ref, g_ref, mean_ref, rstd_ref, dy_ref, dx_ref):
+def _dx_kernel(x_ref, g_ref, dy_ref, dx_ref, *, eps):
     x = x_ref[...].astype(jnp.float32)
     dy = dy_ref[...].astype(jnp.float32)
-    gamma = g_ref[...].astype(jnp.float32)
-    mean = mean_ref[...][..., None]
-    rstd = rstd_ref[...][..., None]
+    mean, rstd = _row_stats(x, eps)
     xhat = (x - mean) * rstd
-    wdy = dy * gamma
+    wdy = dy * g_ref[...].astype(jnp.float32)
     c1 = jnp.mean(wdy, axis=-1, keepdims=True)
     c2 = jnp.mean(wdy * xhat, axis=-1, keepdims=True)
-    dx = (wdy - c1 - xhat * c2) * rstd
-    dx_ref[...] = dx.astype(dx_ref.dtype)
-
-
+    dx_ref[...] = ((wdy - c1 - xhat * c2) * rstd).astype(dx_ref.dtype)
 
 
 def _ln_fwd(x, gamma, beta, eps):
-    orig_shape = x.shape
     d = x.shape[-1]
     x2 = x.reshape(-1, d)
     n = x2.shape[0]
     bn = rows_block(n, 256)
-    kernel = functools.partial(_fwd_kernel, eps=eps)
-    y, mean, rstd = pl.pallas_call(
-        kernel,
+    y = pl.pallas_call(
+        functools.partial(_fwd_kernel, eps=eps),
         grid=(n // bn,),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
             pl.BlockSpec((d,), lambda i: (0,)),
             pl.BlockSpec((d,), lambda i: (0,)),
         ],
-        out_specs=[
-            pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, d), x.dtype),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((bn, d), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
+        name="layer_norm_fwd",
         interpret=interpret_mode(),
     )(x2, gamma, beta)
-    return y.reshape(orig_shape), (x2, gamma, mean, rstd, orig_shape)
+    return y.reshape(x.shape), (x2, gamma, x.shape)
 
 
 def _ln_bwd(eps, res, g):
-    x2, gamma, mean, rstd, orig_shape = res
-    d = x2.shape[-1]
-    n = x2.shape[0]
+    x2, gamma, orig_shape = res
+    n, d = x2.shape
     dy2 = g.reshape(-1, d)
     bn = rows_block(n, 256)
     dx = pl.pallas_call(
-        _dx_kernel,
+        functools.partial(_dx_kernel, eps=eps),
         grid=(n // bn,),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
             pl.BlockSpec((d,), lambda i: (0,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bn, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x2.dtype),
+        name="layer_norm_bwd",
         interpret=interpret_mode(),
-    )(x2, gamma, mean, rstd, dy2)
+    )(x2, gamma, dy2)
     # parameter grads: plain XLA cross-row reductions
-    xhat = (x2.astype(jnp.float32) - mean[:, None]) * rstd[:, None]
+    xf = x2.astype(jnp.float32)
+    mean, rstd = _row_stats(xf, eps)
     dyf = dy2.astype(jnp.float32)
-    dgamma = jnp.sum(dyf * xhat, axis=0).astype(gamma.dtype)
+    dgamma = jnp.sum(dyf * (xf - mean) * rstd, axis=0).astype(gamma.dtype)
     dbeta = jnp.sum(dyf, axis=0).astype(gamma.dtype)
     return dx.reshape(orig_shape), dgamma, dbeta
 
@@ -111,22 +102,19 @@ def _layer_norm_pallas(x, gamma, beta, eps: float = 1e-5):
     return y
 
 
-def _layer_norm_fwd(x, gamma, beta, eps):
-    return _ln_fwd(x, gamma, beta, eps)
+_layer_norm_pallas.defvjp(_ln_fwd, _ln_bwd)
 
 
-_layer_norm_pallas.defvjp(_layer_norm_fwd, _ln_bwd)
+def layer_norm_reference(x, gamma, beta, eps: float = 1e-5):
+    """The XLA expression the kernel is checked against."""
+    xf = x.astype(jnp.float32)
+    mean, rstd = _row_stats(xf, eps)
+    return ((xf - mean) * rstd * gamma.astype(jnp.float32)
+            + beta.astype(jnp.float32)).astype(x.dtype)
 
 
 def layer_norm(x, gamma, beta, eps: float = 1e-5):
     """Fused layer norm over the last dim. x: [..., D]; gamma/beta: [D].
-    Row counts TPU can't tile (no block >= 8 divides) fall back to XLA."""
-    import numpy as _n
-    if rows_block(int(_n.prod(x.shape[:-1])), 256) == 0:
-        xf = x.astype(jnp.float32)
-        mean = xf.mean(-1, keepdims=True)
-        var = ((xf - mean) ** 2).mean(-1, keepdims=True)
-        y = (xf - mean) * jax.lax.rsqrt(var + eps)
-        return (y * gamma.astype(jnp.float32)
-                + beta.astype(jnp.float32)).astype(x.dtype)
+    Row counts the kernel cannot tile raise ``KernelUnsupported``."""
+    require_rows("layer_norm", x.shape, 256)
     return _layer_norm_pallas(x, gamma, beta, eps)

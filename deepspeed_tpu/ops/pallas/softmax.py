@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._utils import interpret_mode, rows_block
+from ._utils import interpret_mode, require_rows, rows_block
 
 NEG_INF = -1e30
 
@@ -60,6 +60,7 @@ def _softmax_fwd(x, causal):
         in_specs=[pl.BlockSpec((bn, s), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bn, s), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, s), x.dtype),
+        name="softmax_fwd",
         interpret=interpret_mode(),
     )(x2)
     return y.reshape(orig), (y, orig)
@@ -78,6 +79,7 @@ def _softmax_bwd(causal, res, g):
                   pl.BlockSpec((bn, s), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bn, s), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, s), dy2.dtype),
+        name="softmax_bwd",
         interpret=interpret_mode(),
     )(y, dy2)
     return (dx.reshape(orig),)
@@ -93,17 +95,20 @@ _fused_softmax_pallas.defvjp(lambda x, causal: _softmax_fwd(x, causal),
                              _softmax_bwd)
 
 
+def softmax_reference(x, causal: bool = False):
+    """The XLA expression the kernel is checked against."""
+    xf = x.astype(jnp.float32)
+    if causal:
+        s_len = x.shape[-1]
+        xf = jnp.where(jnp.tril(jnp.ones((s_len, s_len), bool)), xf, NEG_INF)
+    return jax.nn.softmax(xf, axis=-1).astype(x.dtype)
+
+
 def fused_softmax(x, causal: bool = False):
     """Softmax over the last dim with optional causal (triangular) masking.
-    For causal masking x must be [..., S, S] score matrices. Row counts TPU
-    can't tile fall back to XLA."""
-    import numpy as _n
-    if rows_block(int(_n.prod(x.shape[:-1])), 128) == 0:
-        if causal:
-            s_len = x.shape[-1]
-            tri = jnp.tril(jnp.ones((s_len, s_len), bool))
-            x = jnp.where(tri, x, -jnp.inf)
-        return jax.nn.softmax(x.astype(jnp.float32), axis=-1).astype(x.dtype)
+    For causal masking x must be [..., S, S] score matrices. Row counts the
+    kernel cannot tile raise ``KernelUnsupported``."""
+    require_rows("fused_softmax", x.shape, 128)
     return _fused_softmax_pallas(x, causal)
 
 

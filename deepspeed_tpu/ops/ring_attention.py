@@ -24,7 +24,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ..utils.jax_compat import shard_map  # check_vma/check_rep + jax-version shim
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30

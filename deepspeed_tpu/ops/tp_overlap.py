@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..utils.jax_compat import shard_map  # check_vma/check_rep version shim
+from jax import shard_map
 
 
 def overlap_supported(y, mesh: Optional[Mesh], axis_name: str = "tp") -> bool:

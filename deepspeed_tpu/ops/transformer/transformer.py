@@ -153,11 +153,18 @@ class DeepSpeedTransformerLayer(nn.Module):
             k = k.reshape(b, s, heads, hd)
             v = v.reshape(b, s, heads, hd)
             drop_attn = cfg.attn_dropout_ratio and not deterministic
-            if attention_mask is None and not drop_attn:
+            from ..pallas._utils import log_path_once
+            from ..pallas.flash_attention import (flash_attention,
+                                                  flash_refusal)
+            use_flash = attention_mask is None and not drop_attn
+            if use_flash and flash_refusal(s) is not None:
+                log_path_once("transformer attention", "xla",
+                              f"kernel gate refused: {flash_refusal(s)}")
+                use_flash = False
+            if use_flash:
                 # hot path: the fused Pallas flash kernel (key-padding
                 # masks and attention-prob dropout need the materialized
                 # probs, so those configs take the einsum path below)
-                from ..pallas.flash_attention import flash_attention
                 ctx = flash_attention(q, k, v, causal=False,
                                       sm_scale=1.0 / math.sqrt(hd))
                 ctx = ctx.astype(dt).reshape(b, s, h)

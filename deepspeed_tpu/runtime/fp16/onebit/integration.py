@@ -24,7 +24,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from ....utils.jax_compat import shard_map  # check_vma/check_rep + jax-version shim
+from jax import shard_map
 
 from . import ONEBIT_OPTIMIZERS
 from ....comm.compressed import wire_bytes_compressed, wire_bytes_dense

@@ -31,7 +31,8 @@ from typing import Any, Callable, Dict, Iterator, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from ...utils.jax_compat import pcast, shard_map  # jax-version shims
+from jax import shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ...utils.logging import log_dist

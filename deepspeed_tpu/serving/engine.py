@@ -252,22 +252,26 @@ class ServingEngine:
         # ---- fused decode megakernel ----
         # One knob flips the decode stack onto the fused fast path: the
         # Pallas decode kernel (int8 dequant inside the DMA window,
-        # in-kernel k+1 speculative verify — decode_impl "auto" resolves
-        # to it on TPU and to the partition-friendly einsum elsewhere,
-        # so CPU parity gates run the program they always did), the
-        # sort-free sampling epilogue (ops/pallas/sampling.py, swapped in
-        # below), and — when the mesh has a tp axis under a parallel-
-        # residual model — the RS/AG collective/MLP overlap
-        # (ops/tp_overlap.py). Greedy outputs are bit-identical with the
-        # knob on or off (the megakernel contract, gated by tests);
-        # temperature > 0 draws are distributionally identical but
-        # consume the rng as Gumbel noise instead of ``categorical``'s
-        # internal stream.
+        # in-kernel k+1 speculative verify) on the chip — on the CPU mesh
+        # the decode stays on the partition-friendly einsum, where Pallas
+        # would only interpret, so CPU parity gates run the program they
+        # always did — the sort-free sampling epilogue
+        # (ops/pallas/sampling.py, swapped in below), and — when the mesh
+        # has a tp axis under a parallel-residual model — the RS/AG
+        # collective/MLP overlap (ops/tp_overlap.py). The knob asks for
+        # the kernels BY NAME: shapes their gates refuse raise
+        # ``KernelUnsupported`` here, at construction
+        # (:meth:`_check_megakernel_gates`). Greedy outputs are
+        # bit-identical with the knob on or off on the CPU (the megakernel
+        # contract, gated by tests); temperature > 0 draws are
+        # distributionally identical but consume the rng as Gumbel noise
+        # instead of ``categorical``'s internal stream.
         self.megakernel = bool(megakernel)
         if self.megakernel:
+            from ..utils.platform import on_chip
             rebuild = {}
-            if getattr(cfg, "decode_impl", None) == "xla":
-                rebuild["decode_impl"] = "auto"
+            if on_chip() and getattr(cfg, "decode_impl", None) == "xla":
+                rebuild["decode_impl"] = "pallas"
             if (self.tp > 1 and getattr(cfg, "parallel_residual", False)
                     and hasattr(cfg, "tp_overlap")):
                 rebuild["tp_overlap"] = True
@@ -352,6 +356,8 @@ class ServingEngine:
                          or self.fused_prefill)
 
         self.paged = bool(paged)
+        if self.megakernel:
+            self._check_megakernel_gates(cfg, int(kv_block_size))
         if self.paged:
             from .paged_kv import PagedKVCacheManager
             # prefix reuse replays a stored first token, which is only
@@ -498,10 +504,9 @@ class ServingEngine:
         temperature_, top_k_ = self.temperature, self.top_k
         top_p_ = self.top_p
         # megakernel: every sampler call in the compiled programs routes
-        # through the fused Pallas epilogue (unsupported vocab shapes
-        # fall back to the reference INSIDE the router, so the program
-        # never forks on shape), and the speculative verifier filters
-        # with the same fused kernel
+        # through the fused Pallas epilogue (its vocab gate was checked at
+        # construction), and the speculative verifier filters with the
+        # same fused kernel
         sample_ = fused_sample_tokens if self.megakernel else sample_tokens
         spec_filter_ = fused_filter_logits if self.megakernel else None
         max_seq_ = self.max_seq_len
@@ -938,6 +943,57 @@ class ServingEngine:
                  f"kv={'paged' if self.paged else 'dense'} "
                  f"tp={self.tp} "
                  f"disaggregated={self.disaggregated}", ranks=[0])
+
+    def _check_megakernel_gates(self, cfg, kv_block_size: int) -> None:
+        """``megakernel=True`` names its kernels: refuse at construction
+        any shape the fused sampling epilogue or — on the chip, where the
+        decode runs the Pallas kernel — the decode kernel's gate refuses,
+        with the shape and the reason, instead of serving the reference
+        under a configuration that says fused."""
+        import jax.numpy as jnp
+        from ..ops.pallas._utils import refuse
+        from ..ops.pallas.decode_attention import (decode_refusal,
+                                                   paged_decode_refusal)
+        from ..ops.pallas.sampling import sampling_refusal
+        from ..utils.platform import on_chip
+        vocab = int(cfg.vocab_size)
+        reason = sampling_refusal(self.max_batch, vocab)
+        if reason is not None:
+            refuse("megakernel=True (fused sampling epilogue)",
+                   (self.max_batch, vocab), reason)
+        if not on_chip():
+            return
+        mesh = getattr(self.engine, "mesh", None)
+        n_dev = int(mesh.size) if mesh is not None else 1
+        if n_dev > 1:
+            # found on four v5e chips (PR 21): the first compile dies with
+            # "Mosaic kernels cannot be automatically partitioned. Please
+            # wrap the call in a shard_map"
+            refuse("megakernel=True", f"engine mesh of {n_dev} devices",
+                   "GSPMD cannot partition a Mosaic custom call and the "
+                   "decode and sampling kernels are not wrapped in "
+                   "shard_map; build one engine per chip on a one-device "
+                   "mesh (ROADMAP R9)")
+        if cfg.decode_impl != "pallas":
+            return
+        h, d = int(cfg.num_heads), int(cfg.d_model) // int(cfg.num_heads)
+        # query positions per decode-scan step
+        width = (self.spec_k + 1) if self.speculative else 1
+        if self.fused_prefill:
+            width = max(width, self.prefill_chunk)
+        kv_dt = jnp.int8 if self.kv_dtype == "int8" else cfg.dtype
+        if self.paged:
+            reason = paged_decode_refusal(self.max_batch, kv_block_size, h,
+                                          d, kv_dt, width)
+            shape = (f"batch={self.max_batch} kv_block_size={kv_block_size} "
+                     f"h={h} d={d} kv={jnp.dtype(kv_dt).name} s={width}")
+        else:
+            reason = decode_refusal(self.max_batch, self.max_seq_len, h, d,
+                                    cfg.dtype, width)
+            shape = (f"batch={self.max_batch} S={self.max_seq_len} h={h} "
+                     f"d={d} s={width}")
+        if reason is not None:
+            refuse("megakernel=True (Pallas decode)", shape, reason)
 
     # --------------------------------------------------------------- API
     def submit(self, prompt: Union[Request, Sequence[int], np.ndarray],

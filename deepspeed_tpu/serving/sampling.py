@@ -8,12 +8,14 @@ speculative verifier's acceptance math (rejection resamples draw from the
 SAME filtered distribution), and the megakernel epilogue all share this
 module. engine.py re-exports both names for API stability.
 
-``fused_filter_logits``/``fused_sample_tokens`` are the megakernel
-routers: they run the sort-free Pallas kernel when the shape supports it
-and fall back to the reference otherwise. Greedy draws are bit-identical
-either way (the megakernel correctness contract); temperature > 0 draws
-are distributionally identical but consume the rng as Gumbel noise
-instead of ``jax.random.categorical``'s internal stream.
+``fused_filter_logits``/``fused_sample_tokens`` are the megakernel's
+entry points to the sort-free Pallas kernel. A vocab the kernel's gate
+refuses raises ``KernelUnsupported`` (the engine checks
+``sampling_refusal`` at construction) — ``megakernel=True`` never runs the
+sort-based reference under a name that says fused. Greedy draws are
+bit-identical to the reference (the megakernel correctness contract);
+temperature > 0 draws are distributionally identical but consume the rng
+as Gumbel noise instead of ``jax.random.categorical``'s internal stream.
 """
 
 from __future__ import annotations
@@ -71,20 +73,11 @@ def sample_tokens(logits, rng, temperature: float, top_k: Optional[int],
 
 def fused_filter_logits(logits, temperature: float, top_k: Optional[int],
                         top_p: Optional[float] = None):
-    """filter_logits through the sort-free Pallas kernel when the vocab
-    shape supports it, reference otherwise. Accepts [..., V]; the kernel
-    sees rows."""
-    import jax.numpy as jnp
-    from ..ops.pallas.sampling import (sampling_supported,
-                                       threshold_filter_logits)
+    """filter_logits through the sort-free Pallas kernel. Accepts
+    [..., V]; the kernel sees rows."""
+    from ..ops.pallas.sampling import threshold_filter_logits
     shape = logits.shape
-    rows = 1
-    for dim in shape[:-1]:
-        rows *= dim
-    if not sampling_supported(rows, shape[-1]):
-        return filter_logits(logits, temperature, top_k, top_p)
-    out = threshold_filter_logits(logits.reshape(rows, shape[-1])
-                                  .astype(jnp.float32),
+    out = threshold_filter_logits(logits.reshape(-1, shape[-1]),
                                   temperature, top_k, top_p)
     return out.reshape(shape)
 
@@ -92,17 +85,13 @@ def fused_filter_logits(logits, temperature: float, top_k: Optional[int],
 def fused_sample_tokens(logits, rng, temperature: float,
                         top_k: Optional[int],
                         top_p: Optional[float] = None):
-    """sample_tokens through the fused Pallas epilogue when supported
-    (greedy stays bit-identical; temperature > 0 becomes Gumbel-max),
-    reference otherwise."""
+    """sample_tokens through the fused Pallas epilogue (greedy stays
+    bit-identical; temperature > 0 becomes Gumbel-max)."""
     import jax
     import jax.numpy as jnp
-    from ..ops.pallas.sampling import fused_sample, sampling_supported
+    from ..ops.pallas.sampling import fused_sample
     b, v = logits.shape
-    if not sampling_supported(b, v):
-        return sample_tokens(logits, rng, temperature, top_k, top_p)
     gumbel = None
     if temperature != 0.0:
         gumbel = jax.random.gumbel(rng, (b, v), jnp.float32)
-    return fused_sample(logits.astype(jnp.float32), gumbel, temperature,
-                        top_k, top_p).astype(jnp.int32)
+    return fused_sample(logits, gumbel, temperature, top_k, top_p)

@@ -33,6 +33,10 @@ from typing import Any, Dict, Optional
 
 #: Published dense peak FLOPs/s per TPU *chip* (bf16), keyed by a
 #: lowercase substring of ``device.device_kind``. Most-specific first.
+#: The ONE peaks table: bench.py reads it through :func:`table_peak_flops`
+#: and treats a device it does not list as an error. Source: Google Cloud
+#: TPU documentation, per-generation system-architecture pages ("TPU v5e":
+#: 197 TFLOP/s bf16 per chip; JAX reports that chip as ``TPU v5 lite``).
 _TPU_PEAK_BF16 = (
     ("v6", 918e12),      # Trillium
     ("v5p", 459e12),
@@ -44,6 +48,16 @@ _TPU_PEAK_BF16 = (
 )
 
 PEAK_FLOPS_ENV = "DSTPU_PEAK_FLOPS"
+
+
+def table_peak_flops(device_kind: str) -> Optional[float]:
+    """The table's bf16 peak for a ``device_kind``, or ``None`` when the
+    table does not know it. No environment override, no default."""
+    kind = device_kind.lower()
+    for sub, peak in _TPU_PEAK_BF16:
+        if sub in kind:
+            return peak
+    return None
 
 
 def peak_flops_per_device(device=None) -> Optional[float]:
@@ -62,10 +76,7 @@ def peak_flops_per_device(device=None) -> Optional[float]:
     kind = getattr(device, "device_kind", "").lower()
     if "tpu" not in kind and getattr(device, "platform", "") != "tpu":
         return None
-    for sub, peak in _TPU_PEAK_BF16:
-        if sub in kind:
-            return peak
-    return None
+    return table_peak_flops(kind)
 
 
 def compiled_cost_analysis(fn, *args, **kwargs) -> Optional[Dict[str, Any]]:

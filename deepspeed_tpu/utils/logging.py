@@ -28,16 +28,17 @@ logger = _make_logger()
 
 
 def _process_index() -> int:
-    # Avoid importing jax (and initializing the backend) just to log before
-    # distributed setup; fall back to env.
+    # Never INITIALISE a backend just to log: jax.process_index() takes the
+    # chip, and a process that only orchestrates children (the autotuner
+    # under process isolation, the launcher) must leave it free for them.
+    # Ask JAX only once something else has brought the backend up; until
+    # then the launcher's env contract answers.
     if "jax" in sys.modules:
-        import jax
-
-        try:
+        from jax._src import xla_bridge
+        if xla_bridge.backends_are_initialized():
+            import jax
             return jax.process_index()
-        except Exception:
-            pass
-    return int(os.environ.get("RANK", "0"))
+    return int(os.environ.get("PROCESS_ID", os.environ.get("RANK", "0")))
 
 
 def log_dist(message: str, ranks=None, level=logging.INFO) -> None:

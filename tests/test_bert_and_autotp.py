@@ -177,8 +177,8 @@ def test_init_inference_replace_method_auto():
 # ---------------------------------------------------------------- decode
 
 def test_fused_decode_matches_masked_einsum():
-    from deepspeed_tpu.ops.pallas.decode_attention import (_xla_decode,
-                                                           decode_attention)
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        decode_attention, masked_cache_attention)
     rng = np.random.default_rng(0)
     b, S, h, d = 2, 512, 12, 64     # h=12 exercises head padding
     q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.float32)
@@ -186,7 +186,8 @@ def test_fused_decode_matches_masked_einsum():
     cv = jnp.asarray(rng.normal(size=(b, S, h, d)), jnp.float32)
     for clen in (1, 7, 128, 300, 512):
         got = decode_attention(q, ck, cv, jnp.int32(clen))
-        want = _xla_decode(q, ck, cv, jnp.int32(clen), 1.0 / np.sqrt(d))
+        want = masked_cache_attention(q, ck, cv, jnp.int32(clen - 1),
+                                      1.0 / np.sqrt(d))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, err_msg=f"clen={clen}")
 
@@ -201,8 +202,10 @@ def test_generate_with_fused_decode():
                       jnp.int32)
     outs = {}
     for impl in ("xla", "pallas"):
+        # h*d = 128: a lane tile, so the kernel's gate accepts it (at the
+        # old d_model=64 "pallas" quietly ran the einsum; now it raises)
         cfg = GPTConfig(vocab_size=100, max_seq_len=128, num_layers=2,
-                        num_heads=4, d_model=64, d_ff=128,
+                        num_heads=4, d_model=128, d_ff=128,
                         dtype=jnp.float32, param_dtype=jnp.float32,
                         attention_impl="xla", decode_impl=impl)
         model = GPT(cfg)

@@ -128,7 +128,7 @@ FAILER = textwrap.dedent("""
 
 
 def test_launcher_two_process_convergence(tmp_path):
-    """ds_tpu-style launch of 2 processes on localhost: env relay, gloo
+    """ds_tpu-style launch of 2 processes on localhost: env forwarding, gloo
     rendezvous via COORDINATOR_ADDRESS, cross-process dp collective, loss
     converges in both ranks."""
     script = tmp_path / "trainer.py"

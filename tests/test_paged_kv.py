@@ -217,7 +217,7 @@ class TestPagedKernel:
         np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
-    def test_unsupported_shapes_fall_back(self):
+    def test_unsupported_shapes_are_refused(self):
         import jax.numpy as jnp
         from deepspeed_tpu.ops.pallas.decode_attention import (
             paged_decode_supported)

@@ -60,14 +60,30 @@ def test_flash_attention_grad_parity():
                                    rtol=1e-3, atol=1e-3)
 
 
-def test_flash_attention_fallback_odd_seq():
-    # 50 doesn't tile -> falls back to the XLA path, still correct
+def test_flash_attention_odd_seq_is_one_tile():
+    # a sequence that fits one tile runs the kernel whatever its length
     b, s, h, d = 1, 50, 2, 16
     q = jax.random.normal(jax.random.PRNGKey(0), (b, s, h, d))
     out = flash_attention(q, q, q, causal=True)
     ref = _ref_attention(q, q, q, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
+
+
+def test_kernels_asked_for_by_name_raise_outside_their_gate():
+    """No kernel entry point gives way to its XLA reference quietly: a
+    shape the gate refuses raises with the shape and the reason."""
+    from deepspeed_tpu.ops.pallas import KernelUnsupported
+    q = jnp.zeros((1, 1100, 2, 16))      # > one tile, no tile divides 1100
+    with pytest.raises(KernelUnsupported, match="1100"):
+        flash_attention(q, q, q, causal=True)
+    x = jnp.zeros((9, 32))               # 9 rows: no row block divides
+    with pytest.raises(KernelUnsupported, match="9 rows"):
+        layer_norm(x, jnp.ones(32), jnp.zeros(32))
+    with pytest.raises(KernelUnsupported, match="9 rows"):
+        fused_softmax(x)
+    with pytest.raises(KernelUnsupported, match="9 rows"):
+        bias_gelu(x, jnp.zeros(32))
 
 
 def test_layer_norm_parity():
@@ -214,22 +230,19 @@ def test_decode_attention_parity_across_fills(fill, dtype):
                                rtol=tol, atol=tol)
 
 
-def test_decode_attention_unsupported_geometry_falls_back():
-    """h*d not a multiple of 128 -> the wrapper must route to the XLA path
-    (and still be numerically right), never crash in the kernel."""
+def test_decode_attention_unsupported_geometry_raises():
+    """h*d not a multiple of 128 -> the kernel asked for by name raises
+    with the shape and the reason; it neither crashes in Mosaic nor runs
+    the einsum under the kernel's name."""
+    from deepspeed_tpu.ops.pallas import KernelUnsupported
     from deepspeed_tpu.ops.pallas.decode_attention import (
         decode_attention, pallas_decode_supported)
     b, S, h, d = 2, 256, 3, 20           # h*d = 60: not kernel-eligible
     assert not pallas_decode_supported(b, S, h, d, jnp.float32)
-    rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.standard_normal((b, 1, h, d)), jnp.float32)
-    ck = jnp.asarray(rng.standard_normal((b, S, h, d)), jnp.float32)
-    cv = jnp.asarray(rng.standard_normal((b, S, h, d)), jnp.float32)
-    n = jnp.asarray(100, jnp.int32)
-    out = decode_attention(q, ck, cv, n, scale=1.0 / np.sqrt(d))
-    ref = _decode_ref(q, ck, cv, n, 1.0 / np.sqrt(d))
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+    q = jnp.zeros((b, 1, h, d), jnp.float32)
+    ck = jnp.zeros((b, S, h, d), jnp.float32)
+    with pytest.raises(KernelUnsupported, match="h\\*d=60"):
+        decode_attention(q, ck, ck, jnp.asarray(100, jnp.int32))
 
 
 def test_decode_attention_ignores_dead_cache():
