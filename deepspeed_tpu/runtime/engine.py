@@ -44,7 +44,7 @@ from ..telemetry import core as telemetry
 from ..ops.adam import fused_adagrad, fused_adam
 from ..ops.lamb import fused_lamb
 from ..parallel import mesh as mesh_lib
-from ..utils.logging import instrument_w_trace, log_dist, logger
+from ..utils.logging import log_dist, logger
 from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 from .config import DeepSpeedConfig
 from .dataloader import DeepSpeedDataLoader, RepeatingLoader
@@ -921,7 +921,6 @@ class DeepSpeedEngine:
 
         return jax.tree.map(put, batch)
 
-    @instrument_w_trace(name="DeepSpeedEngine.train_batch")
     def train_batch(self, data_iter=None):
         """Pull GAS micro-batches and run one full optimizer step (reference
         PipelineEngine.train_batch:302 generalized to the non-pipe engine)."""

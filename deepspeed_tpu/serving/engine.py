@@ -488,6 +488,10 @@ class ServingEngine:
         # the at-most-one in-flight chunk of the double-buffered loop
         # (run()'s pipelined drain and external pump() drivers share it)
         self._pending: Optional[_InflightChunk] = None
+        # the open serve/starved_* span, while telemetry is on and a host
+        # sync has returned with nothing dispatched behind it: the chip
+        # idles until the next dispatch leaves the span (_device_fed)
+        self._starved = None
         # crash flight recorder (telemetry.flight_recorder), attached by
         # the owning ServingFrontend; engine-side records are host-only
         # deque appends — no device work, no retrace surface
@@ -1204,21 +1208,24 @@ class ServingEngine:
         overlap ``run()`` has. Call until ``has_work()`` is False AND the
         last call returned with nothing in flight to drain completely."""
         before = len(self.scheduler.finished)
-        if not self._chunked:
-            self._admit()
-            self._decode_once()
-            return self.scheduler.finished[before:]
-        if self._pending is None:
-            self._admit()
-            if self.scheduler.running:
-                self._pending = self._launch_chunk(self._host_state())
-            return self.scheduler.finished[before:]
-        nxt = None
-        if self._may_outlive_chunk():
-            nxt = self._launch_chunk(self._device_state(self._pending))
-        self._consume_chunk(self._pending)
-        self._admit()
-        self._pending = nxt
+        with telemetry.span("serve/pump"):
+            if not self._chunked:
+                self._admit()
+                self._decode_once()
+            elif self._pending is None:
+                self._admit()
+                if self.scheduler.running:
+                    self._pending = self._launch_chunk(self._host_state())
+            else:
+                nxt = None
+                if self._may_outlive_chunk():
+                    nxt = self._launch_chunk(
+                        self._device_state(self._pending))
+                self._consume_chunk(self._pending,
+                                    device_queue_empty=nxt is None)
+                self._admit()
+                self._pending = nxt
+        self._drop_starved_if_idle()
         return self.scheduler.finished[before:]
 
     @property
@@ -1240,7 +1247,9 @@ class ServingEngine:
         if not self._chunked:
             self._decode_once()
         elif self.scheduler.running:
-            self._consume_chunk(self._launch_chunk(self._host_state()))
+            self._consume_chunk(self._launch_chunk(self._host_state()),
+                                device_queue_empty=True)
+        self._drop_starved_if_idle()
         return self.scheduler.finished[before:]
 
     def run(self, prompts: Optional[Sequence] = None,
@@ -1369,6 +1378,30 @@ class ServingEngine:
         }
 
     # ---------------------------------------------------------- internals
+    # ------------------------------------------------ starved-chip spans
+    def _starve(self, name: str) -> None:
+        """A host sync has just returned and nothing is dispatched behind
+        it: from here to the next dispatch the chip has no work because
+        the host has not handed it any. ``name`` says which sync."""
+        if self._starved is None:
+            span = telemetry.span(name)
+            if span is not telemetry.NOOP_SPAN:
+                self._starved = span.__enter__()
+
+    def _device_fed(self) -> None:
+        """Called where the next program is about to be dispatched."""
+        if self._starved is not None:
+            self._starved.__exit__(None, None, None)
+            self._starved = None
+
+    def _drop_starved_if_idle(self) -> None:
+        """No request queued, running or in flight: what follows is a
+        server waiting for traffic, not a chip waiting for its host."""
+        if (self._starved is not None and self._pending is None
+                and not self.scheduler.has_work()):
+            self._starved.drop()
+            self._starved = None
+
     def _next_rng(self):
         import jax
         self._rng, sub = jax.random.split(self._rng)
@@ -1392,39 +1425,40 @@ class ServingEngine:
         its block."""
         if self.kv_tier is not None:
             self._install_promotions()
-        if self.fused_prefill:
-            # chunk-budget fill policy: running lanes drain the per-step
-            # token budget (a prompt chunk for prefilling lanes, one
-            # decode token — k+1 speculative — for the rest); admission
-            # fills what's left. The scheduler still admits one request
-            # into an otherwise-idle engine so the budget can't wedge.
-            admitted = self.scheduler.admit(
-                token_budget=max(0, self.chunk_token_budget
-                                 - self._budget_drain()),
-                lane_cost=self._lane_cost)
-        else:
-            admitted = self.scheduler.admit()
-        if not admitted:
-            return
-        if self.fused_prefill:
-            self._fused_admit(admitted)
-            if self.paged:
-                self._gauge_block_pool()
-            return
-        if not self.paged:
-            self._prefill_admit(admitted)
-            return
-        hits: List[Tuple[Request, Any]] = []
-        misses: List[Tuple[Request, Any]] = []
-        for req in admitted:
-            plan = self.kv.take_plan(req.slot)
-            (hits if plan.hit else misses).append((req, plan))
-        for req, plan in hits:
-            self._admit_prefix_hit(req, plan)
-        if misses:
-            self._prefill_admit([r for r, _ in misses],
-                                plans={r.slot: p for r, p in misses})
-        self._gauge_block_pool()
+        with telemetry.span("serve/admit"):
+            if self.fused_prefill:
+                # chunk-budget fill policy: running lanes drain the per-step
+                # token budget (a prompt chunk for prefilling lanes, one
+                # decode token — k+1 speculative — for the rest); admission
+                # fills what's left. The scheduler still admits one request
+                # into an otherwise-idle engine so the budget can't wedge.
+                admitted = self.scheduler.admit(
+                    token_budget=max(0, self.chunk_token_budget
+                                     - self._budget_drain()),
+                    lane_cost=self._lane_cost)
+            else:
+                admitted = self.scheduler.admit()
+            if not admitted:
+                return
+            if self.fused_prefill:
+                self._fused_admit(admitted)
+                if self.paged:
+                    self._gauge_block_pool()
+                return
+            if not self.paged:
+                self._prefill_admit(admitted)
+                return
+            hits: List[Tuple[Request, Any]] = []
+            misses: List[Tuple[Request, Any]] = []
+            for req in admitted:
+                plan = self.kv.take_plan(req.slot)
+                (hits if plan.hit else misses).append((req, plan))
+            for req, plan in hits:
+                self._admit_prefix_hit(req, plan)
+            if misses:
+                self._prefill_admit([r for r, _ in misses],
+                                    plans={r.slot: p for r, p in misses})
+            self._gauge_block_pool()
 
     def _admit_prefix_hit(self, req: Request, plan) -> None:
         """A cached prompt: share its full blocks, COW its tail, replay
@@ -1598,11 +1632,6 @@ class ServingEngine:
             n = len(reqs)
             prefill_fn = (self._jit_prefill_sp if use_sp
                           else self._jit_prefill)
-            ids = np.zeros((n, bucket), np.int32)
-            lens = np.empty(n, np.int32)
-            for i, r in enumerate(reqs):
-                ids[i, :r.prompt_len] = r.prompt
-                lens[i] = r.prompt_len
             shape_key = (n, bucket) if not use_sp else (n, bucket, "sp")
             if shape_key not in self._prefill_shapes:
                 # first sighting of this (batch, bucket) shape = the call
@@ -1611,42 +1640,35 @@ class ServingEngine:
                 telemetry.instant("serve/prefill_compile", n=n,
                                   bucket=bucket, sp=use_sp)
             self._prefill_shapes.add(shape_key)
-            # np.asarray(toks) below is the host sync, so the span covers
-            # dispatch + device prefill + arena insert honestly
+            # serve/prefill is HOST time from building the ids to the
+            # first tokens on the host, not the prefill's device time:
+            # its dispatch queues behind whatever is already dispatched
+            # (pump() launches the next decode chunk before it admits),
+            # and serve/prefill_wait, the np.asarray(toks) sync alone,
+            # waits for all of that. The device's own time is in the
+            # profiler's trace, under the programs' names
             pt0 = prof.clock() if prof is not None else 0.0
             with telemetry.span("serve/prefill", n=n, bucket=bucket,
-                                sp=use_sp):
-                toks, cache = prefill_fn(
-                    self._prefill_params, jnp.asarray(ids),
-                    jnp.asarray(lens), self._next_rng())
-                if self._handoff_sharding is not None:
-                    # disaggregation: the finished prompt KV leaves the
-                    # prefill slice here — a device-to-device transfer of
-                    # the batch's cache rows onto the decode slice, where
-                    # the insert scatters them through each request's
-                    # table row / slot lane
-                    import jax
-                    nbytes = sum(
-                        int(getattr(leaf, "nbytes", 0))
-                        for leaf in jax.tree.leaves(cache))
-                    # the handoff span carries the requests' journey ids
-                    # so the transfer shows up under each trace in the
-                    # merged fleet export
-                    with telemetry.span(
-                            "serve/disagg_handoff", n=n, bucket=bucket,
-                            uids=str([r.uid for r in reqs]),
-                            trace_ids=str([r.trace_id for r in reqs])):
-                        cache = jax.device_put(cache,
-                                               self._handoff_sharding)
-                    telemetry.count("serve/disagg_handoff_bytes",
-                                    float(nbytes))
-                    telemetry.count("serve/disagg_handoffs", float(n))
-                    if self.flight is not None:
-                        self.flight.record(
-                            "disagg_handoff", n=n, bytes=int(nbytes),
-                            uids=[r.uid for r in reqs])
-                self.kv.insert_batch(cache, [r.slot for r in reqs], lens)
-                toks_host = np.asarray(toks)
+                                sp=use_sp,
+                                uids=str([r.uid for r in reqs])):
+                with telemetry.span("serve/prefill_dispatch"):
+                    ids = np.zeros((n, bucket), np.int32)
+                    lens = np.empty(n, np.int32)
+                    for i, r in enumerate(reqs):
+                        ids[i, :r.prompt_len] = r.prompt
+                        lens[i] = r.prompt_len
+                    self._device_fed()
+                    toks, cache = prefill_fn(
+                        self._prefill_params, jnp.asarray(ids),
+                        jnp.asarray(lens), self._next_rng())
+                    if self._handoff_sharding is not None:
+                        cache = self._handoff(cache, reqs, bucket)
+                    self.kv.insert_batch(cache, [r.slot for r in reqs],
+                                         lens)
+                with telemetry.span("serve/prefill_wait"):
+                    toks_host = np.asarray(toks)
+                # everything dispatched before that sync has run
+                self._starve("serve/starved_after_prefill")
             if prof is not None:
                 prof.on_prefill(pt0, prof.clock(), n=n, bucket=bucket,
                                 stalled=n_decoding > 0)
@@ -1680,6 +1702,29 @@ class ServingEngine:
                 self.scheduler.record_first_token(r, first)
                 if self._chunked:
                     self._record_admit_patch(r)
+
+    def _handoff(self, cache, reqs: List[Request], bucket: int):
+        """Disaggregation: the finished prompt KV leaves the prefill
+        slice here — a device-to-device transfer of the batch's cache
+        rows onto the decode slice, where the insert scatters them
+        through each request's table row / slot lane."""
+        import jax
+        n = len(reqs)
+        nbytes = sum(int(getattr(leaf, "nbytes", 0))
+                     for leaf in jax.tree.leaves(cache))
+        # the handoff span carries the requests' journey ids so the
+        # transfer shows up under each trace in the merged fleet export
+        with telemetry.span(
+                "serve/disagg_handoff", n=n, bucket=bucket,
+                uids=str([r.uid for r in reqs]),
+                trace_ids=str([r.trace_id for r in reqs])):
+            cache = jax.device_put(cache, self._handoff_sharding)
+        telemetry.count("serve/disagg_handoff_bytes", float(nbytes))
+        telemetry.count("serve/disagg_handoffs", float(n))
+        if self.flight is not None:
+            self.flight.record("disagg_handoff", n=n, bytes=int(nbytes),
+                               uids=[r.uid for r in reqs])
+        return cache
 
     def _record_admit_patch(self, req: Request) -> None:
         slot = req.slot
@@ -1727,6 +1772,7 @@ class ServingEngine:
             positions[s] = self.kv.fill[s]
         # np.asarray(tok) is the per-token host sync — the span covers
         # dispatch + device step (the K=1 reference path's whole cost)
+        self._device_fed()
         with telemetry.span("serve/decode_step", n=len(slots)):
             tok, new_cache = self._jit_decode(
                 self._decode_params, self.kv.cache, jnp.asarray(tokens),
@@ -1734,6 +1780,7 @@ class ServingEngine:
             self.kv.update(new_cache)
             self.kv.allocator.advance(slots)
             tok_host = np.asarray(tok)
+        self._starve("serve/starved_after_chunk")
         for s in slots:
             self._last_token[s] = int(tok_host[s])
         finished = self.scheduler.step_tokens(
@@ -1818,39 +1865,45 @@ class ServingEngine:
             pf = chunk.state[i]
             i += 1
         hist = chunk.state[i] if self.speculative else None
-        if self._deact_slots:
-            telemetry.instant("serve/deact_patch",
-                              n=len(self._deact_slots))
-            if self.flight is not None:
-                self.flight.record("deact_patch",
-                                   slots=sorted(self._deact_slots))
-            idx = np.array(sorted(self._deact_slots), np.int32)
-            act = act.at[idx].set(False)
-        if self._admit_patches:
-            telemetry.instant("serve/admit_patch",
-                              n=len(self._admit_patches))
-            if self.flight is not None:
-                self.flight.record("admit_patch",
-                                   slots=sorted(self._admit_patches))
-            slots = np.array(sorted(self._admit_patches), np.int32)
-            vals = [self._admit_patches[int(s)] for s in slots]
-            tok = tok.at[slots].set(
-                np.array([v[0] for v in vals], np.int32))
-            pos = pos.at[slots].set(
-                np.array([v[1] for v in vals], np.int32))
-            rem = rem.at[slots].set(
-                np.array([v[2] for v in vals], np.int32))
-            eos = eos.at[slots].set(
-                np.array([v[3] for v in vals], np.int32))
-            act = act.at[slots].set(True)
-            vi = 4
-            if pf is not None:
-                pf = pf.at[slots].set(
-                    np.array([v[vi] for v in vals], np.int32))
-                vi += 1
-            if hist is not None:
-                hist = hist.at[slots].set(
-                    np.stack([v[vi] for v in vals]))
+        if self._deact_slots or self._admit_patches:
+            # eager scatters, one small dispatch each: host time while
+            # the previous chunk may already have ended on the device
+            with telemetry.span("serve/lane_patch",
+                                n_deact=len(self._deact_slots),
+                                n_admit=len(self._admit_patches)):
+                if self._deact_slots:
+                    telemetry.instant("serve/deact_patch",
+                                      n=len(self._deact_slots))
+                    if self.flight is not None:
+                        self.flight.record("deact_patch",
+                                           slots=sorted(self._deact_slots))
+                    idx = np.array(sorted(self._deact_slots), np.int32)
+                    act = act.at[idx].set(False)
+                if self._admit_patches:
+                    telemetry.instant("serve/admit_patch",
+                                      n=len(self._admit_patches))
+                    if self.flight is not None:
+                        self.flight.record("admit_patch",
+                                           slots=sorted(self._admit_patches))
+                    slots = np.array(sorted(self._admit_patches), np.int32)
+                    vals = [self._admit_patches[int(s)] for s in slots]
+                    tok = tok.at[slots].set(
+                        np.array([v[0] for v in vals], np.int32))
+                    pos = pos.at[slots].set(
+                        np.array([v[1] for v in vals], np.int32))
+                    rem = rem.at[slots].set(
+                        np.array([v[2] for v in vals], np.int32))
+                    eos = eos.at[slots].set(
+                        np.array([v[3] for v in vals], np.int32))
+                    act = act.at[slots].set(True)
+                    vi = 4
+                    if pf is not None:
+                        pf = pf.at[slots].set(
+                            np.array([v[vi] for v in vals], np.int32))
+                        vi += 1
+                    if hist is not None:
+                        hist = hist.at[slots].set(
+                            np.stack([v[vi] for v in vals]))
         self._deact_slots.clear()
         self._admit_patches.clear()
         out = (tok, pos, act, rem, eos)
@@ -1866,6 +1919,7 @@ class ServingEngine:
         import jax.numpy as jnp
         prof = self.profiler
         t0 = prof.clock() if prof is not None else 0.0
+        self._device_fed()
         # dispatch-only span BY DESIGN (no sync=): the chunk is meant to
         # run asynchronously; the honest device wait is measured at
         # consume time as serve/chunk_host_wait
@@ -1923,14 +1977,19 @@ class ServingEngine:
                                slot_uids=dict(inflight.slot_uids))
         return inflight
 
-    def _consume_chunk(self, chunk: _InflightChunk) -> List[Request]:
+    def _consume_chunk(self, chunk: _InflightChunk, *,
+                       device_queue_empty: bool) -> List[Request]:
         """Block on the chunk's token buffer (the ONE host sync per K
-        steps) and feed it through the scheduler."""
+        steps) and feed it through the scheduler. ``device_queue_empty``:
+        no chunk was launched ahead of this sync, so when it returns the
+        chip has nothing to run."""
         prof = self.profiler
         hw0 = prof.clock() if prof is not None else 0.0
         with telemetry.span("serve/chunk_host_wait"):
             toks = np.asarray(chunk.tokens)
             valid = np.asarray(chunk.valid)
+        if device_queue_empty:
+            self._starve("serve/starved_after_chunk")
         rt0 = prof.clock() if prof is not None else 0.0
         if self._overlap_active and chunk.wall_t0:
             # cumulative wall seconds of decode chunks served with the
@@ -2157,5 +2216,8 @@ class ServingEngine:
         """Release host-side serving resources: the KV tier's promotion
         worker and its NVMe spill files. Idempotent; engines without a
         tier have nothing to release."""
+        if self._starved is not None:
+            self._starved.drop()
+            self._starved = None
         if self.kv_tier is not None:
             self.kv_tier.close()

@@ -66,6 +66,19 @@ LOAD_SCHEMA = "dstpu-load-v1"
 SNAPSHOT_SCHEMA = "dstpu-snapshot-v1"
 
 
+def _name_os_thread(name: str) -> None:
+    """Give the calling thread its Python name at the OS too (Linux keeps
+    15 characters): the profiler labels a host thread's line by that
+    name, and Python before 3.14 leaves every thread named after the
+    process. Where /proc is not writable the line stays as it was."""
+    try:
+        with open(f"/proc/self/task/{threading.get_native_id()}/comm",
+                  "w") as f:
+            f.write(name)
+    except OSError:
+        pass
+
+
 class StreamHandle:
     """One caller's view of one request: a thread-safe incremental token
     stream plus the terminal status. Produced by
@@ -98,6 +111,7 @@ class StreamHandle:
         # driver-thread-only bookkeeping (never touched by callers)
         self._ticket: Optional[Ticket] = None
         self._pushed = 0               # tokens handed to _push so far
+        self._lane_marked = False
         self._prefill_marked = False
 
     # ----------------------------------------------------- driver side
@@ -774,6 +788,7 @@ class ServingFrontend:
 
     # ------------------------------------------------------ driver loop
     def _drive(self) -> None:
+        _name_os_thread(threading.current_thread().name)
         try:
             with telemetry.replica_label(self._telemetry_label):
                 while self._drive_once():
@@ -781,16 +796,31 @@ class ServingFrontend:
         except BaseException as e:  # noqa: BLE001 — converted to results
             self._fail_all(e)
 
-    def _drive_once(self) -> bool:
+    def _requests_waiting(self) -> bool:
         eng = self._engine
+        return bool(self._controller.pending or eng.scheduler.has_work()
+                    or eng.chunk_in_flight)
+
+    def _drive_once(self) -> bool:
         with self._wake:
             if not (self._cancel_requests or self._migrations
-                    or self._closing or self._controller.pending
-                    or eng.scheduler.has_work() or eng.chunk_in_flight):
-                self._wake.wait(self._idle_wait_s)
+                    or self._closing or self._requests_waiting()):
+                with telemetry.span("frontend/idle_wait"):
+                    self._wake.wait(self._idle_wait_s)
             cancels, self._cancel_requests = self._cancel_requests, []
             migrations, self._migrations = self._migrations, []
             closing = self._closing
+        if not (cancels or migrations or closing
+                or self._requests_waiting()):
+            self._maybe_emit()
+            return True
+        # an iteration that found work is one ``frontend/drive`` span: its
+        # time less the device waits inside it is the driver's own
+        with telemetry.span("frontend/drive"):
+            return self._drive_work(cancels, migrations, closing)
+
+    def _drive_work(self, cancels, migrations, closing: bool) -> bool:
+        eng = self._engine
         for handle in cancels:
             self._do_cancel(handle)
         for kind, payload, box in migrations:
@@ -805,7 +835,8 @@ class ServingFrontend:
                 box["error"] = f"{type(e).__name__}: {e}"
             finally:
                 box["done"].set()
-        self._feed()
+        with telemetry.span("frontend/feed"):
+            self._feed()
         if eng.scheduler.has_work() or eng.chunk_in_flight:
             tokens_before = eng.metrics.tokens_out
             inline_before = getattr(eng, "inline_prefill_tokens", 0)
@@ -830,7 +861,9 @@ class ServingFrontend:
                 telemetry.gauge("admission/ewma_tokens_per_s", float(rate))
             telemetry.gauge("frontend/queue_depth",
                             float(self._controller.pending))
-            self._deliver(finished)
+            with telemetry.span("frontend/deliver",
+                                n_finished=len(finished)):
+                self._deliver(finished)
             # the scheduler's finished list is an append-only log; the
             # frontend is its only consumer, so trim it here or a
             # long-running server grows without bound
@@ -903,9 +936,14 @@ class ServingFrontend:
         handle = handle or self._handles.get(req.uid)
         if handle is None:
             return
+        if not handle._lane_marked and req.admit_t is not None:
+            # the scheduler's stamps are on the same monotonic timebase
+            # as the frontend clock
+            self.tracing.mark(req.uid, "lane", t=req.admit_t)
+            handle._lane_marked = True
         if not handle._prefill_marked and req.first_token_t is not None:
             # prefill completion = the first sampled token's scheduler
-            # timestamp (same monotonic timebase as the frontend clock)
+            # timestamp
             self.tracing.mark(req.uid, "prefill", t=req.first_token_t)
             handle._prefill_marked = True
         n = len(req.tokens)

@@ -4,10 +4,14 @@
 this module records the *per-request* control-plane story the frontend
 owns: a span record per request
 
-    submitted -> admitted -> prefill -> first_token -> chunk[i] -> finish
+    submitted -> admitted -> lane -> prefill -> first_token -> chunk[i]
+    -> finish
 
-with derived latency stats (TTFT, TPOT, queue wait) folded into
-reservoir-backed p50/p95/p99 histograms (the same ``Reservoir`` the
+(``admitted``: the admission winner entered the scheduler's FIFO;
+``lane``: the scheduler leased it a slot; ``prefill``: the scheduler
+stamped its first sampled token; ``first_token``: that token reached the
+caller's handle) with derived latency stats (TTFT, TPOT, queue wait)
+folded into reservoir-backed p50/p95/p99 histograms (the same ``Reservoir`` the
 engine metrics use). Snapshots emit through the existing monitor fan-out
 (``(label, value, sample)`` events — CSV/TensorBoard/W&B pick them up
 unchanged) and the whole log dumps as JSON for offline analysis
@@ -18,8 +22,12 @@ Latency fields (all seconds):
                 measured from ``ServingFrontend.submit``, so it includes
                 admission queueing, unlike the engine's scheduler-side
                 TTFT)
-  queue_wait_s  submit -> prefill start (time spent waiting for
-                admission + a slot)
+  queue_wait_s  submit -> lane granted (time spent waiting for
+                admission + a slot; nothing of the prefill)
+  prefill_s     lane granted -> first streamed token: the prefill and
+                whatever it queued behind on the device (with the
+                bucketed prefill, the decode chunk already in flight).
+                ``queue_wait_s + prefill_s == ttft_s``
   tpot_s        mean time per output token after the first
                 (first_token -> finish over n_tokens - 1)
 
@@ -40,9 +48,18 @@ from ...analysis import locks
 from ..metrics import Reservoir
 from ...telemetry.core import count as _telemetry_count
 from ...telemetry.core import gauge as _telemetry_gauge
+from ...telemetry.core import record_span as _telemetry_record_span
 
 #: canonical span event names, in lifecycle order
-EVENTS = ("submitted", "admitted", "prefill", "first_token", "finish")
+EVENTS = ("submitted", "admitted", "lane", "prefill", "first_token",
+          "finish")
+
+#: a finished request's phases as telemetry spans: name, from, to. They
+#: tile submit -> finish; a phase whose ends were not both stamped (no
+#: lane ever granted, no token ever delivered) is left out
+REQUEST_SPANS = (("request/queued", "submitted", "lane"),
+                 ("request/prefill", "lane", "first_token"),
+                 ("request/decode", "first_token", "finish"))
 
 #: /tenants payload schema
 TENANTS_SCHEMA = "dstpu-tenants-v1"
@@ -170,7 +187,14 @@ class RequestTrace:
 
     @property
     def queue_wait_s(self) -> Optional[float]:
-        return self._delta("submitted", "prefill")
+        # a record without a ``lane`` mark (built by hand, or from a
+        # driver that stamps none) reads as it always did
+        return self._delta("submitted",
+                           "lane" if "lane" in self.events else "prefill")
+
+    @property
+    def prefill_s(self) -> Optional[float]:
+        return self._delta("lane", "first_token")
 
     @property
     def tpot_s(self) -> Optional[float]:
@@ -210,6 +234,7 @@ class RequestTrace:
             "chunks": [list(c) for c in self.chunks],
             "ttft_s": self.ttft_s,
             "queue_wait_s": self.queue_wait_s,
+            "prefill_s": self.prefill_s,
             "tpot_s": self.tpot_s,
             "slo_ttft_met": self.slo_ttft_met,
         }
@@ -329,6 +354,11 @@ class TraceLog:
         if trace.n_tokens:
             _telemetry_count(f"frontend/tenant_tokens|tenant={tenant}",
                              float(trace.n_tokens))
+        for name, a, b in REQUEST_SPANS:
+            if a in trace.events and b in trace.events:
+                _telemetry_record_span(name, trace.events[a],
+                                       trace.events[b], uid=trace.uid,
+                                       trace_id=trace.trace_id)
         for fn in self._listeners:
             try:
                 fn(trace)

@@ -63,6 +63,7 @@ class Request:
     slot: Optional[int] = None
     tokens: List[int] = dataclasses.field(default_factory=list)
     submit_t: Optional[float] = None
+    admit_t: Optional[float] = None        # the lane (slot) was leased
     first_token_t: Optional[float] = None
     finish_t: Optional[float] = None
 
@@ -182,6 +183,7 @@ class ContinuousBatchScheduler:
                 break
             self.queue.popleft()
             req.slot = slot
+            req.admit_t = self.clock()
             req.status = "running"
             self.running[slot] = req
             admitted.append(req)

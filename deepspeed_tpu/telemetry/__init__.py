@@ -10,7 +10,9 @@ This package is the one runtime they all feed:
   :class:`TelemetryRuntime`: ``span(name, **attrs)`` context managers
   (optionally ``sync=``-honest, same contract as ``utils/timer.py``),
   instant events, counters and gauges, recorded into a bounded ring
-  buffer. Disabled telemetry is a single flag check — the hot paths stay
+  buffer. A live span also enters a ``jax.profiler.TraceAnnotation``, so
+  it lies in the profiler's trace on the clock the device's ops are on.
+  Disabled telemetry is a single flag check — the hot paths stay
   instrumented permanently.
 * :mod:`.export` — Chrome-trace/Perfetto JSON: one thread lane per
   emitting thread, spans + instants + counter tracks, plus the bridge
@@ -40,7 +42,7 @@ stay in the millisecond range. See docs/observability.md.
 
 from .core import (NOOP_SPAN, TelemetryRuntime, configure,  # noqa: F401
                    count, current_replica, disable, enable, gauge,
-                   get_runtime, instant, replica_label, span)
+                   get_runtime, instant, record_span, replica_label, span)
 from .export import (chrome_trace, request_trace_events,  # noqa: F401
                      write_chrome_trace)
 from .summary import (emit_summary, phase_breakdown,  # noqa: F401
@@ -69,7 +71,7 @@ from .anomaly import (AnomalyDetector, AnomalySpec,  # noqa: F401
 
 __all__ = [
     "TelemetryRuntime", "get_runtime", "configure", "enable", "disable",
-    "span", "instant", "count", "gauge", "NOOP_SPAN",
+    "span", "record_span", "instant", "count", "gauge", "NOOP_SPAN",
     "replica_label", "current_replica",
     "chrome_trace", "write_chrome_trace", "request_trace_events",
     "summarize", "phase_breakdown", "emit_summary",

@@ -33,7 +33,17 @@ The ``sync=`` span argument carries the honesty contract of
 ``utils/timer.py``: JAX dispatch returns before the device finishes, so
 a span closing right after a jitted call measures dispatch only;
 ``sync=result`` blocks on the result first and the span covers real
-work. JAX is imported lazily and ONLY on that path — this module stays
+work.
+
+**One clock with the device.** A live span also enters a
+``jax.profiler.TraceAnnotation`` of the same name and attributes, so
+while a ``jax.profiler`` trace runs every span lies on its thread's line
+of the trace's host plane, on the clock the device's ops are on — an
+idle gap on the device can be read against what the host was doing. No
+switch of its own: the bridge is on exactly when telemetry is, and the
+annotation costs a flag check unless a trace is being taken.
+
+JAX is imported lazily and ONLY by a live span — this module stays
 importable by the stdlib-only ``bin/tputrace``.
 """
 
@@ -114,9 +124,11 @@ class _Span:
     """One live span; created by :meth:`TelemetryRuntime.span` only when
     the runtime is enabled. The clock starts in ``__enter__`` and stops
     in ``__exit__`` (after the optional ``sync`` block), so attribute
-    setup and lock acquisition never pollute the measured window."""
+    setup and lock acquisition never pollute the measured window. The
+    profiler's annotation of the same region is entered before the clock
+    starts and left after it stops."""
 
-    __slots__ = ("_rt", "name", "attrs", "_sync", "_t0")
+    __slots__ = ("_rt", "name", "attrs", "_sync", "_t0", "_annotation")
 
     def __init__(self, rt: "TelemetryRuntime", name: str, sync,
                  attrs: Optional[Dict[str, Any]]):
@@ -125,8 +137,13 @@ class _Span:
         self.attrs = attrs
         self._sync = sync
         self._t0 = 0.0
+        self._annotation = None
 
     def __enter__(self):
+        import jax
+        self._annotation = jax.profiler.TraceAnnotation(
+            self.name, **(self.attrs or {}))
+        self._annotation.__enter__()
         self._t0 = self._rt.clock()
         return self
 
@@ -135,8 +152,16 @@ class _Span:
             import jax
             jax.block_until_ready(self._sync)
         t1 = self._rt.clock()
+        self._annotation.__exit__(exc_type, exc, tb)
         self._rt._record_span(self.name, self._t0, t1, self.attrs)
         return False
+
+    def drop(self) -> None:
+        """Leave an entered span without recording it (what it was timing
+        turned out not to be that: ``ServingEngine``'s starved span when
+        the loop ran out of requests). The profiler cannot take an
+        annotation back: in a trace it ends here."""
+        self._annotation.__exit__(None, None, None)
 
 
 class _SpanAgg:
@@ -212,6 +237,16 @@ class TelemetryRuntime:
         if not self.enabled:
             return NOOP_SPAN
         return _Span(self, name, sync, attrs or None)
+
+    def record_span(self, name: str, t0: float, t1: float,
+                    **attrs) -> None:
+        """A span whose ends were stamped elsewhere, in seconds on the
+        runtime clock's timebase (a request's phases start on the
+        caller's thread and end on the driver's: no context manager, and
+        no profiler annotation, can bracket them). No-op while
+        disabled."""
+        if self.enabled:
+            self._record_span(name, t0, t1, attrs or None)
 
     def instant(self, name: str, **attrs) -> None:
         """A zero-duration timeline marker (Perfetto instant event)."""
@@ -350,6 +385,11 @@ def span(name: str, *, sync=None, **attrs):
     if not _default.enabled:
         return NOOP_SPAN
     return _Span(_default, name, sync, attrs or None)
+
+
+def record_span(name: str, t0: float, t1: float, **attrs) -> None:
+    if _default.enabled:
+        _default._record_span(name, t0, t1, attrs or None)
 
 
 def instant(name: str, **attrs) -> None:

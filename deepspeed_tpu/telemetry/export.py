@@ -119,7 +119,11 @@ def request_trace_events(trace_json: Dict[str, Any], *,
             events.append({"name": "request", "ph": "f", "bp": "e",
                            "cat": "request", "id": uid, "ts": us(fin),
                            "pid": pid, "tid": uid})
-        phases = (("queue_wait", "submitted", "prefill"),
+        # queue_wait ends where the request got its lane (records from
+        # before the ``lane`` mark: at the first sampled token)
+        lane = "lane" if "lane" in ev else "prefill"
+        phases = (("queue_wait", "submitted", lane),
+                  ("prefill", "lane", "prefill"),
                   ("prefill_to_first_token", "prefill", "first_token"),
                   ("stream", "first_token", "finish"))
         for pname, a, b in phases:

@@ -103,23 +103,3 @@ def see_memory_usage(message: str, force: bool = False, ranks=(0,)) -> dict:
         f"{report['host'].get('rss', 0)/2**30:.2f} GB",
         ranks=list(ranks))
     return report
-
-
-def instrument_w_trace(fn=None, name=None):
-    """Profiler range decorator (reference utils/nvtx.py instrument_w_nvtx:
-    NVTX ranges on hot functions): wraps the call in a
-    jax.profiler.TraceAnnotation so it shows up as a named span in
-    jax.profiler / tensorboard traces."""
-    import functools
-
-    def deco(f):
-        label = name or getattr(f, "__qualname__", f.__name__)
-
-        @functools.wraps(f)
-        def wrapper(*args, **kwargs):
-            import jax
-            with jax.profiler.TraceAnnotation(label):
-                return f(*args, **kwargs)
-        return wrapper
-
-    return deco(fn) if fn is not None else deco

@@ -91,3 +91,17 @@ def _reset_mesh():
     from deepspeed_tpu.parallel import mesh as mesh_lib
     yield
     mesh_lib.reset_global_mesh()
+
+
+@pytest.fixture
+def telemetry_on():
+    """The process-wide telemetry runtime (the one the serve loop's and
+    the driver's spans go to), enabled and clean; restored after."""
+    from deepspeed_tpu.telemetry import core as tel
+    rt = tel.get_runtime()
+    was_enabled = rt.enabled
+    rt.clear()
+    rt.enable()
+    yield rt
+    rt.clear()
+    rt.enabled = was_enabled

@@ -229,6 +229,68 @@ class TestTraceLog:
         assert log.counters["rejected:rate_limited"] == 1
         assert log.to_json()["requests"][0]["status"] == "rejected"
 
+    def test_queue_wait_ends_at_the_lane_and_prefill_runs_from_it(self):
+        """submit -> lane is the wait for a lane; lane -> first token is
+        the prefill and what it queued behind; together they are TTFT."""
+        clock = FakeClock()
+        log = TraceLog(clock=clock)
+        log.start(1, prompt_len=4, max_new_tokens=8)
+        log.mark(1, "submitted")
+        clock.advance(0.5)
+        log.mark(1, "admitted")
+        clock.advance(2.0)
+        log.mark(1, "lane")                # the scheduler leased a slot
+        clock.advance(0.25)
+        log.mark(1, "prefill")             # first token sampled
+        clock.advance(0.05)
+        log.chunk(1, 1)                    # ... and delivered: first_token
+        clock.advance(1.0)
+        log.chunk(1, 7)
+        trace = log.finish(1, "done")
+        assert trace.queue_wait_s == pytest.approx(2.5)
+        assert trace.prefill_s == pytest.approx(0.3)
+        assert trace.ttft_s == pytest.approx(
+            trace.queue_wait_s + trace.prefill_s)
+        assert trace.to_dict()["prefill_s"] == pytest.approx(0.3)
+        assert log.histograms["queue_wait_s"].percentile(50) == \
+            pytest.approx(2.5)
+
+    def test_a_trace_without_a_lane_mark_reads_as_before(self):
+        clock = FakeClock()
+        log = TraceLog(clock=clock)
+        log.start(1)
+        log.mark(1, "submitted")
+        clock.advance(1.0)
+        log.mark(1, "prefill")
+        log.chunk(1, 2)
+        trace = log.finish(1, "done")
+        assert trace.queue_wait_s == pytest.approx(1.0)
+        assert trace.prefill_s is None
+
+    def test_finish_records_the_requests_phases_as_telemetry_spans(
+            self, telemetry_on):
+        clock = FakeClock(100.0)
+        log = TraceLog(clock=clock)
+        log.start(7, trace_id="abc")
+        log.mark(7, "submitted")
+        clock.advance(2.0)
+        log.mark(7, "lane")
+        clock.advance(0.5)
+        log.chunk(7, 1)
+        clock.advance(3.0)
+        log.finish(7, "done")
+        log.record_rejected(8, "rate_limited")   # no lane, no token
+        spans = {e[1]: e for e in telemetry_on.events() if e[0] == "X"}
+        assert set(spans) == {"request/queued", "request/prefill",
+                              "request/decode"}
+        durs = {n: e[3] / 1e6 for n, e in spans.items()}
+        assert durs == pytest.approx({"request/queued": 2.0,
+                                      "request/prefill": 0.5,
+                                      "request/decode": 3.0})
+        assert spans["request/queued"][2] == pytest.approx(100.0e6)
+        for e in spans.values():
+            assert e[5] == {"uid": 7, "trace_id": "abc"}
+
     def test_keep_last_bounds_records_not_counters(self):
         log = TraceLog(clock=FakeClock(), keep_last=2)
         for uid in range(5):
@@ -361,6 +423,134 @@ class TestEngineCancelAndPump:
         while a.status != "done":
             serving.pump()
         assert serving.scheduler.n_cancelled == 1
+
+
+def _xspans(rt, *names):
+    return [e for e in rt.events() if e[0] == "X" and e[1] in names]
+
+
+class TestDriverSpans:
+    """The driver loop, the serve loop under it and each request's phases
+    as telemetry spans."""
+
+    def _serve(self, tiny_engine, n=5):
+        rng = np.random.default_rng(5)
+        vocab = tiny_engine.module.cfg.vocab_size
+        fe = ServingFrontend(_serving(tiny_engine))
+        try:
+            handles = [fe.submit(rng.integers(0, vocab, (k,)), max_new_tokens=m)
+                       for k, m in zip([3, 7, 5, 9, 4][:n],
+                                       [9, 21, 6, 14, 11])]
+            for h in handles:
+                assert h.result(timeout=60) == "done"
+            time.sleep(0.05)               # the driver goes idle
+        finally:
+            fe.close()
+        return fe, handles
+
+    def test_drive_minus_its_device_waits_is_the_drivers_own_time(
+            self, tiny_engine, telemetry_on):
+        self._serve(tiny_engine)
+        drives = _xspans(telemetry_on, "frontend/drive")
+        waits = _xspans(telemetry_on, "serve/chunk_host_wait",
+                        "serve/prefill_wait")
+        assert drives and waits
+        # every device wait happens inside an iteration of the driver
+        for w in waits:
+            assert any(d[2] <= w[2] and w[2] + w[3] <= d[2] + d[3]
+                       for d in drives), w[1]
+        own = sum(d[3] for d in drives) - sum(w[3] for w in waits)
+        assert own >= 0.0
+        for name in ("frontend/feed", "frontend/deliver", "serve/pump"):
+            for e in _xspans(telemetry_on, name):
+                assert any(d[2] <= e[2] and e[2] + e[3] <= d[2] + d[3]
+                           for d in drives), name
+        names = telemetry_on.thread_names()
+        assert {names[d[4]] for d in drives} == {"serving-frontend-driver"}
+
+    def test_an_idle_server_is_not_starved_by_its_host(
+            self, tiny_engine, telemetry_on):
+        fe = ServingFrontend(_serving(tiny_engine))
+        try:
+            h = fe.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=6)
+            assert h.result(timeout=60) == "done"
+            time.sleep(0.05)
+            starved = len(_xspans(telemetry_on,
+                                  "serve/starved_after_prefill",
+                                  "serve/starved_after_chunk"))
+            drives = len(_xspans(telemetry_on, "frontend/drive"))
+            idles = len(_xspans(telemetry_on, "frontend/idle_wait"))
+            time.sleep(0.1)                # idle: nothing to serve
+            assert fe._engine._starved is None
+            assert len(_xspans(telemetry_on,
+                               "serve/starved_after_prefill",
+                               "serve/starved_after_chunk")) == starved
+            assert len(_xspans(telemetry_on, "frontend/drive")) == drives
+            assert len(_xspans(telemetry_on,
+                               "frontend/idle_wait")) > idles
+        finally:
+            fe.close()
+
+    def test_request_phases_tile_submit_to_finish_under_its_uid(
+            self, tiny_engine, telemetry_on):
+        fe, handles = self._serve(tiny_engine)
+        records = {r["uid"]: r for r in fe.tracing.to_json()["requests"]}
+        phases = _xspans(telemetry_on, "request/queued", "request/prefill",
+                         "request/decode")
+        for h in handles:
+            ev = records[h.uid]["events"]
+            mine = sorted((e for e in phases if e[5]["uid"] == h.uid),
+                          key=lambda e: e[2])
+            assert [e[1] for e in mine] == [
+                "request/queued", "request/prefill", "request/decode"]
+            assert {e[5]["trace_id"] for e in mine} == {h.trace_id}
+            assert sum(e[3] for e in mine) / 1e6 == pytest.approx(
+                ev["finish"] - ev["submitted"], abs=1e-6)
+            for a, b in zip(mine, mine[1:]):       # no gap, no overlap
+                assert a[2] + a[3] == pytest.approx(b[2], abs=1e-3)
+
+    def test_the_lane_mark_is_the_schedulers_admit_stamp(self, tiny_engine):
+        fe, handles = self._serve(tiny_engine)
+        records = {r["uid"]: r for r in fe.tracing.to_json()["requests"]}
+        waited = 0
+        for h in handles:
+            rec, req = records[h.uid], h._request
+            ev = rec["events"]
+            assert ev["lane"] == req.admit_t
+            assert ev["prefill"] == req.first_token_t
+            assert (ev["submitted"] <= ev["admitted"] <= ev["lane"]
+                    <= ev["prefill"] <= ev["first_token"] <= ev["finish"])
+            assert rec["queue_wait_s"] == pytest.approx(
+                ev["lane"] - ev["submitted"])
+            assert rec["prefill_s"] == pytest.approx(
+                ev["first_token"] - ev["lane"])
+            waited += rec["queue_wait_s"] > rec["prefill_s"]
+        # five requests on two lanes: the later ones wait for a lane far
+        # longer than their prefill takes — the wait is no longer the TTFT
+        assert waited >= 1
+
+    def test_the_driver_thread_is_named_for_the_profilers_trace(
+            self, tiny_engine):
+        """The profiler labels a host thread's line by its OS name; Linux
+        keeps 15 characters of it."""
+        fe = ServingFrontend(_serving(tiny_engine))
+        try:
+            path = f"/proc/self/task/{fe._thread.native_id}/comm"
+            want = "serving-frontend-driver"[:15]
+            # the thread names itself as its first act: give it a moment
+            deadline = time.monotonic() + 5.0
+            while True:
+                try:
+                    with open(path) as f:
+                        name = f.read().strip()
+                except OSError:
+                    pytest.skip("no /proc thread names here")
+                if name == want or time.monotonic() > deadline:
+                    break
+                time.sleep(0.01)
+            assert name == want
+        finally:
+            fe.close()
 
 
 class TestServingFrontend:

@@ -89,6 +89,20 @@ class TestScheduler:
         assert sched.queue_depth == 2
         assert all(r.status == "running" for r in admitted)
 
+    def test_admit_stamps_when_the_lane_was_leased(self):
+        sched, _, clock = _sched(max_batch=1)
+        a, b = (Request(prompt=[1, 2], max_new_tokens=1) for _ in range(2))
+        clock.advance(1.0)
+        sched.submit(a)
+        sched.submit(b)
+        clock.advance(2.0)
+        assert sched.admit() == [a]
+        assert (a.submit_t, a.admit_t) == (1.0, 3.0)
+        assert b.admit_t is None                 # still queued: no lane
+        clock.advance(4.0)
+        sched.record_first_token(a, 5)           # a retires, its lane frees
+        assert sched.admit() == [b] and b.admit_t == 7.0
+
     def test_queue_full_rejection(self):
         sched, _, _ = _sched(max_batch=1, max_queue=2)
         accepted = [sched.submit(Request(prompt=[1], max_new_tokens=4))
@@ -425,6 +439,134 @@ class TestServingEngine:
             assert f"{label}.csv" in files
         rows = (out / "serving_tokens_per_s.csv").read_text().strip()
         assert len(rows.splitlines()) >= 2            # header + >=1 sample
+
+
+def _spans(rt, *names):
+    """(name, start_us, end_us) of the ring's spans called ``names``."""
+    return [(e[1], e[2], e[2] + e[3]) for e in rt.events()
+            if e[0] == "X" and e[1] in names]
+
+
+def _inside(inner, outers):
+    return any(o[1] <= inner[1] and inner[2] <= o[2] for o in outers)
+
+
+STARVED = ("serve/starved_after_prefill", "serve/starved_after_chunk")
+
+
+class TestServeLoopSpans:
+    """The serve loop's boundaries as telemetry spans (and, through them,
+    as annotations in the profiler's trace)."""
+
+    def _run(self, tiny_engine, decode_chunk, n_requests=5):
+        rng = np.random.default_rng(3)
+        vocab = tiny_engine.module.cfg.vocab_size
+        prompts = [rng.integers(0, vocab, (n,)).astype(np.int32)
+                   for n in [3, 7, 5, 9, 4][:n_requests]]
+        serving = ServingEngine(engine=tiny_engine, max_batch=2,
+                                max_prompt_len=16, max_queue=8,
+                                decode_chunk=decode_chunk)
+        # answers of different lengths: lanes retire and are refilled
+        # while the other lane's chunk is in flight (the patched path)
+        results = [serving.submit(p, max_new_tokens=n)
+                   for p, n in zip(prompts, [9, 21, 6, 14, 11])]
+        serving.run()
+        assert all(r.status == "done" for r in results)
+        return serving
+
+    @pytest.mark.parametrize("decode_chunk,waits", [
+        (4, ("serve/chunk_host_wait", "serve/prefill_wait")),
+        (1, ("serve/decode_step", "serve/prefill_wait"))])
+    def test_starved_spans_never_overlap_a_device_wait(
+            self, tiny_engine, telemetry_on, decode_chunk, waits):
+        serving = self._run(tiny_engine, decode_chunk)
+        starved = _spans(telemetry_on, *STARVED)
+        blocked = _spans(telemetry_on, *waits)
+        assert {n for n, _, _ in starved} == set(STARVED)
+        assert {n for n, _, _ in blocked} == set(waits)
+        for _, s0, s1 in starved:
+            assert s1 >= s0
+            for name, w0, w1 in blocked:
+                assert s1 <= w0 or w1 <= s0, (name, (s0, s1), (w0, w1))
+        # drained: the one left open after the last sync was dropped, not
+        # recorded as the host starving the chip
+        assert serving._starved is None
+
+    def test_none_is_left_open_at_close(self, tiny_engine, telemetry_on):
+        serving = ServingEngine(engine=tiny_engine, max_batch=2,
+                                max_prompt_len=16, max_queue=8,
+                                decode_chunk=4)
+        serving.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=30)
+        serving.step()           # prefill, one chunk, its sync: work left
+        assert serving.scheduler.has_work()
+        assert serving._starved is not None
+        recorded = len(_spans(telemetry_on, *STARVED))
+        serving.close()
+        assert serving._starved is None
+        assert len(_spans(telemetry_on, *STARVED)) == recorded
+
+    def test_spans_nest_pump_admit_prefill_and_its_two_halves(
+            self, tiny_engine, telemetry_on):
+        self._run(tiny_engine, 4)
+        pumps = _spans(telemetry_on, "serve/pump")
+        admits = _spans(telemetry_on, "serve/admit")
+        prefills = _spans(telemetry_on, "serve/prefill")
+        halves = _spans(telemetry_on, "serve/prefill_dispatch",
+                        "serve/prefill_wait")
+        assert pumps and prefills and len(halves) == 2 * len(prefills)
+        assert all(_inside(a, pumps) for a in admits)
+        assert all(_inside(p, admits) for p in prefills)
+        assert all(_inside(h, prefills) for h in halves)
+        # the prefill span names the requests it serves
+        attrs = [e[5] for e in telemetry_on.events()
+                 if e[0] == "X" and e[1] == "serve/prefill"]
+        assert all(a["uids"].startswith("[") for a in attrs)
+        # lane patches: a span only where there was something to patch,
+        # with the two instants that were there before inside it
+        patches = _spans(telemetry_on, "serve/lane_patch")
+        marks = [(e[1], e[2], e[2]) for e in telemetry_on.events()
+                 if e[0] == "i" and e[1] in ("serve/admit_patch",
+                                             "serve/deact_patch")]
+        assert patches and marks
+        assert all(_inside(m, patches) for m in marks)
+
+    def test_telemetry_off_opens_nothing(self, tiny_engine):
+        from deepspeed_tpu.telemetry import core as tel
+        rt = tel.get_runtime()
+        assert not rt.enabled
+        before = len(rt.events())
+        serving = self._run(tiny_engine, 4, n_requests=3)
+        assert serving._starved is None and len(rt.events()) == before
+
+    def test_program_names_the_benchmark_reads_by(self, tiny_engine):
+        """chipbench finds the serving programs in a device trace by their
+        XLA module names (readers.py::decode_step_ms by ``decode_chunk``,
+        prefill_ms_per_ktok.chat.py by ``jit_prefill``, PERF.md section 5
+        by ``_insert_batch``): a rename has to fail here, not a reader on
+        the chip."""
+        import re
+        serving = ServingEngine(engine=tiny_engine, max_batch=2,
+                                max_prompt_len=16, max_queue=8,
+                                decode_chunk=4)
+        modules = set()
+
+        def spy(owner, attr):
+            jitted = getattr(owner, attr)
+
+            def call(*args):
+                text = jitted.lower(*args).as_text()
+                modules.add(re.search(r"module @(\S+)", text).group(1))
+                return jitted(*args)
+            setattr(owner, attr, call)
+
+        spy(serving, "_jit_prefill")
+        spy(serving, "_jit_decode_chunk")
+        spy(serving.kv, "_insert_batch")
+        serving.run([np.arange(1, 6, dtype=np.int32)], max_new_tokens=6)
+        assert len(modules) == 3, modules
+        for needle in ("decode_chunk", "jit_prefill", "_insert_batch"):
+            assert any(needle in name for name in modules), (needle,
+                                                             modules)
 
 
 class TestBucketedPrefill:

@@ -149,6 +149,121 @@ class TestDisabledPath:
         assert default_runtime.counter_totals()["c"] == 1.0
 
 
+# ------------------------------------------------------- profiler bridge
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: logs what it is given."""
+
+    log = []
+
+    def __init__(self, name, **kwargs):
+        self.name = name
+        type(self).log.append(("init", name, kwargs))
+
+    def __enter__(self):
+        type(self).log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        type(self).log.append(("exit", self.name))
+        return False
+
+
+@pytest.fixture
+def fake_annotation(monkeypatch):
+    import jax
+    _FakeAnnotation.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    return _FakeAnnotation.log
+
+
+class TestProfilerBridge:
+    def test_enabled_span_enters_and_leaves_a_profiler_annotation(
+            self, fake_annotation):
+        clock = FakeClock(5.0)
+        rt = tel.TelemetryRuntime(enabled=True, clock=clock)
+        with rt.span("serve/prefill", n=2, uids="[7, 8]"):
+            assert fake_annotation == [
+                ("init", "serve/prefill", {"n": 2, "uids": "[7, 8]"}),
+                ("enter", "serve/prefill")]
+            clock.advance(0.25)
+        assert fake_annotation[-1] == ("exit", "serve/prefill")
+        assert len(fake_annotation) == 3
+        # and the runtime's own record is what it was
+        st = rt.span_stats()["serve/prefill"]
+        assert st["count"] == 1 and st["total_s"] == pytest.approx(0.25)
+
+    def test_nested_spans_nest_their_annotations(self, fake_annotation):
+        rt = tel.TelemetryRuntime(enabled=True)
+        with rt.span("outer"):
+            with rt.span("inner"):
+                pass
+        assert [e[:2] for e in fake_annotation if e[0] != "init"] == [
+            ("enter", "outer"), ("enter", "inner"),
+            ("exit", "inner"), ("exit", "outer")]
+
+    def test_disabled_span_is_the_noop_and_touches_no_annotation(
+            self, fake_annotation):
+        rt = tel.TelemetryRuntime(enabled=False)
+        span = rt.span("serve/prefill", n=2)
+        assert span is tel.NOOP_SPAN
+        with span:
+            pass
+        assert fake_annotation == []
+
+    def test_dropped_span_leaves_its_annotation_and_records_nothing(
+            self, fake_annotation):
+        rt = tel.TelemetryRuntime(enabled=True)
+        span = rt.span("serve/starved_after_chunk").__enter__()
+        span.drop()
+        assert fake_annotation[-1] == ("exit", "serve/starved_after_chunk")
+        assert rt.events() == [] and rt.span_stats() == {}
+
+    def test_record_span_goes_through_the_span_record(self):
+        rt = tel.TelemetryRuntime(enabled=True)
+        rt.record_span("request/queued", 10.0, 10.5, uid=3, trace_id="t")
+        (kind, name, ts, dur, _tid, attrs), = rt.events()
+        assert (kind, name) == ("X", "request/queued")
+        assert ts == pytest.approx(10.0e6) and dur == pytest.approx(0.5e6)
+        assert attrs == {"uid": 3, "trace_id": "t"}
+        assert rt.span_stats()["request/queued"]["total_s"] == \
+            pytest.approx(0.5)
+        rt.disable()
+        rt.record_span("request/queued", 11.0, 12.0, uid=4)
+        assert rt.span_stats()["request/queued"]["count"] == 1
+
+    def test_module_record_span_follows_the_default_flag(
+            self, default_runtime):
+        tel.record_span("request/decode", 1.0, 3.0, uid=1)
+        default_runtime.disable()
+        tel.record_span("request/decode", 1.0, 3.0, uid=2)
+        st = default_runtime.span_stats()["request/decode"]
+        assert st["count"] == 1 and st["total_s"] == pytest.approx(2.0)
+
+    def test_import_pulls_in_no_jax(self):
+        """bin/tputrace and bin/tracelint import the package the way this
+        does (no deepspeed_tpu/__init__) and must stay stdlib-only: the
+        bridge imports JAX inside a live span, never at import."""
+        import os
+        import subprocess
+        import sys
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        code = (
+            "import sys, types\n"
+            f"root = {root!r}\n"
+            "sys.path.insert(0, root)\n"
+            "pkg = types.ModuleType('deepspeed_tpu')\n"
+            "pkg.__path__ = [root + '/deepspeed_tpu']\n"
+            "sys.modules['deepspeed_tpu'] = pkg\n"
+            "import deepspeed_tpu.telemetry as t\n"
+            "with t.span('x'):\n"
+            "    pass\n"
+            "t.record_span('y', 0.0, 1.0)\n"
+            "assert 'jax' not in sys.modules, 'telemetry imported jax'\n")
+        r = subprocess.run([sys.executable, "-c", code],
+                           capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr[-2000:]
+
+
 # ------------------------------------------------------------ concurrency
 class TestConcurrentEmit:
     N_THREADS = 6
@@ -424,6 +539,16 @@ class TestRequestTraceBridge:
         assert [e["id"] for e in by_ph["f"]] == [7]
         assert len([e for e in by_ph["i"]
                     if e["name"].startswith("chunk(")]) == 2
+
+    def test_request_lane_queue_wait_ends_where_the_lane_was_granted(self):
+        rec = {"uid": 3, "status": "done", "events": {
+            "submitted": 1.0, "lane": 3.0, "prefill": 3.5,
+            "first_token": 3.6, "finish": 5.0}}
+        spans = {e["name"]: e for e in request_trace_events(
+            {"requests": [rec]}) if e["ph"] == "X"}
+        assert spans["queue_wait"]["dur"] == pytest.approx(2.0e6)
+        assert spans["prefill"]["ts"] == pytest.approx(3.0e6)
+        assert spans["prefill"]["dur"] == pytest.approx(0.5e6)
 
     def test_export_chrome_merges_both_pids(self, tmp_path):
         log = _traced_request_log()
