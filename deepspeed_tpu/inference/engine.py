@@ -286,7 +286,10 @@ class InferenceEngine:
                     body, (token, cache, pos, rng, done),
                     None, length=max_new_tokens - 1)
                 return jnp.moveaxis(toks, 0, 1)        # [b, steps]
-            # donate the cache: XLA updates the KV arena in place
+            # donate the cache: the KV arena is updated in place — the
+            # model's layer loop carries a cache that is passed in and
+            # writes each layer's token with one dynamic_update_slice at
+            # (layer, 0, cache_index) (models/gpt.py::_kv_write)
             self._jit_decode[key] = jax.jit(gen, donate_argnums=(1,))
         gen_fn = self._jit_decode[key]
 

@@ -13,6 +13,12 @@ the engine milestones (BASELINE.json configs: GPT-2 125M -> GPT-NeoX 20B ->
     ``dp`` gives per-layer all-gather/release for free inside ``lax.scan``,
     and remat per scan step is the activation-checkpointing analogue
     (reference runtime/activation_checkpointing/checkpointing.py:493).
+    The KV ``cache`` collection is stacked the same way ([L, B, S, h, d]
+    leaves). A call that CREATES the cache (prefill) scans over it like the
+    params; a call that is HANDED one (every decode step) carries the
+    stacked leaves through the loop instead and each layer writes its
+    tokens at its own index in place — scanning over a cache that came in
+    costs three passes over the whole arena a step (``GPT.__call__``).
   * Tensor parallelism comes from sharding rules on param paths (see
     runtime/sharding.py), not from model surgery: q/k/v and up-projection
     kernels shard their output dim over ``tp``; out/down projections shard
@@ -38,55 +44,84 @@ from ..utils.logging import logger
 _sp_drop_warned = set()
 
 
-def _kv_write(cache, kv, cur):
-    """Write this step's k/v into the cache at sequence offset ``cur``.
+def _layer_rows(stacked, layer):
+    """One layer's view of a cache leaf. ``layer`` None: the leaf IS the
+    layer's (the cache is being created by this call, or the model does not
+    scan its layers). Otherwise the leaf is the layer-stacked ``[L, ...]``
+    array that the layer loop carries, and the layer's rows are read from it
+    where they lie."""
+    if layer is None:
+        return stacked
+    return jax.lax.dynamic_index_in_dim(stacked, layer, 0, keepdims=False)
+
+
+def _set_layer_rows(stacked, layer, rows):
+    """Counterpart of :func:`_layer_rows` for the small per-layer leaves
+    (``cache_index``): the new value of the leaf with this layer's rows
+    replaced."""
+    if layer is None:
+        return rows
+    return jax.lax.dynamic_update_index_in_dim(stacked, rows, layer, 0)
+
+
+def _kv_write(cache, kv, cur, layer=None):
+    """Write this step's k/v ``[b, s, ...]`` into the cache at sequence
+    offset ``cur``. ``layer`` None: ``cache`` is one layer's ``[b, S, ...]``.
+    ``layer`` a (traced) index: ``cache`` is the layer-stacked
+    ``[L, b, S, ...]`` leaf carried by the layer loop and the write lands at
+    ``(layer, row, pos)`` IN PLACE — the update touches ``b*s`` positions,
+    never a layer's worth of rows, so a donated arena stays where it lies.
+
     ``cur`` scalar: the whole batch sits at one fill (single-stream
     generate) — one dynamic_update_slice. ``cur`` [b]: every row has its
     own fill (slotted continuous-batching decode, serving/engine.py) — a
-    vmapped per-row update. A per-row offset >= the cache extent is the
-    MASKED-LANE sentinel: that row's write is dropped entirely (the fused
-    multi-step serving decode pins retired lanes at ``max_seq_len`` so a
-    dead lane never dirties KV rows a later occupant of the slot could
-    attend before overwriting them)."""
+    per-position scatter. A per-row offset >= the cache extent is the
+    MASKED-LANE sentinel: that row's write is dropped entirely, in every
+    layer (the fused multi-step serving decode pins retired lanes at
+    ``max_seq_len`` so a dead lane never dirties KV rows a later occupant
+    of the slot could attend before overwriting them)."""
+    lead = () if layer is None else (layer,)
     if jnp.ndim(cur) == 0:
-        start = (0, cur) + (0,) * (cache.ndim - 2)
+        if layer is not None:
+            kv = kv[None]
+        start = lead + (0, cur) + (0,) * (cache.ndim - len(lead) - 2)
         return jax.lax.dynamic_update_slice(cache, kv, start)
-
-    def row(c, x, p):
-        # per-position scatter, NOT dynamic_update_slice: dus CLAMPS its
-        # start index, so a multi-token write near the row end (or at the
-        # sentinel) would silently land on the last s positions instead of
-        # dropping — mode="drop" discards exactly the out-of-range
-        # positions and is bit-identical to dus for in-range writes
-        idx = p + jnp.arange(x.shape[0], dtype=jnp.int32)
-        return c.at[idx].set(x, mode="drop")
-
-    return jax.vmap(row)(cache, kv, cur)
+    # per-position scatter, NOT dynamic_update_slice: dus CLAMPS its start
+    # index, so a multi-token write near the row end (or at the sentinel)
+    # would silently land on the last s positions instead of dropping —
+    # mode="drop" discards exactly the out-of-range positions and is
+    # bit-identical to dus for in-range writes
+    b, s = kv.shape[:2]
+    rows = jnp.arange(b, dtype=jnp.int32)[:, None]
+    pos = cur[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
+    return cache.at[lead + (rows, pos)].set(kv, mode="drop")
 
 
-def _kv_write_paged(pool, kv, block_tables, cur):
+def _kv_write_paged(pool, kv, block_tables, cur, layer=None):
     """Paged counterpart of :func:`_kv_write`: scatter ``s`` tokens' k/v
     through each row's block table. ``pool`` [nb, bs, h*d] is the shared
-    block pool, ``kv`` [b, s, h*d] this step's flattened k or v,
+    block pool — or, with ``layer`` given, the layer-stacked
+    [L, nb, bs, h*d] pool the layer loop carries, written in place at that
+    layer's blocks. ``kv`` [b, s, h*d] this step's flattened k or v,
     ``block_tables`` [b, T], ``cur`` [b] per-row write positions. The
-    masked-lane sentinel (``cur >= T*bs == max_seq_len``) routes to the
-    out-of-range flat index ``nb*bs`` and drops — same contract as the
-    dense path, but through the scatter's ``mode="drop"`` instead of a
-    per-row select. Table entries past a row's reservation are padded
-    with the ``num_blocks`` sentinel (paged_kv.padded_table), so a
-    speculative position beyond the leased blocks also routes to
-    ``nb*bs`` and drops instead of dirtying block 0."""
-    nb, bs, hd = pool.shape
+    masked-lane sentinel (``cur >= T*bs == max_seq_len``) routes to an
+    out-of-range flat index and drops — same contract as the dense path.
+    Table entries past a row's reservation are padded with the
+    ``num_blocks`` sentinel (paged_kv.padded_table), so a speculative
+    position beyond the leased blocks also drops instead of dirtying
+    block 0 (or, stacked, the next layer's)."""
+    nb, bs, hd = pool.shape[-3:]
     b, T = block_tables.shape
     s = kv.shape[1]
     cur = jnp.broadcast_to(jnp.asarray(cur, jnp.int32), (b,))
     pos = cur[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]   # [b, s]
     blk = jnp.take_along_axis(
         block_tables, jnp.clip(pos // bs, 0, T - 1), axis=1)       # [b, s]
+    base = 0 if layer is None else layer * (nb * bs)
     flat = jnp.where((pos < T * bs) & (blk < nb),
-                     blk * bs + pos % bs, nb * bs)
-    return pool.reshape(nb * bs, hd).at[flat.reshape(-1)].set(
-        kv.reshape(b * s, hd), mode="drop").reshape(nb, bs, hd)
+                     base + blk * bs + pos % bs, pool.size // hd)
+    return pool.reshape(-1, hd).at[flat.reshape(-1)].set(
+        kv.reshape(b * s, hd), mode="drop").reshape(pool.shape)
 
 
 def _sp_constraint(x, spec_parts):
@@ -434,11 +469,13 @@ class SelfAttention(nn.Module):
     window: Optional[int] = None    # local-attention window (GPT-Neo style)
 
     @nn.compact
-    def __call__(self, x, positions, deterministic=True):
+    def __call__(self, x, positions, deterministic=True, layer=None):
         """Training/prefill path (full sequence) OR single-token decode when
         a ``cache`` variable collection is mutable (flax autoregressive
         cache idiom — the TPU analogue of the reference inference kernel's
-        KV-cache arena, csrc/transformer/inference/includes/context.h)."""
+        KV-cache arena, csrc/transformer/inference/includes/context.h).
+        ``layer``: this block's index in the layer-stacked cache leaves,
+        given when the layer loop carries the cache (GPT.__call__)."""
         cfg = self.cfg
         qkv = nn.Dense(3 * cfg.d_model, use_bias=True, dtype=cfg.dtype,
                        param_dtype=cfg.param_dtype, name="qkv")(x)
@@ -454,7 +491,7 @@ class SelfAttention(nn.Module):
         decode = self.has_variable("cache", "cached_key") or \
             (not self.is_initializing() and self.is_mutable_collection("cache"))
         if decode:
-            out = self._decode_attention(q, k, v, positions)
+            out = self._decode_attention(q, k, v, layer)
         else:
             impl = cfg.attention_impl
             if cfg.sequence_parallel and cfg.cp_impl == "ring":
@@ -501,13 +538,22 @@ class SelfAttention(nn.Module):
         return nn.Dense(cfg.d_model, use_bias=True, dtype=cfg.dtype,
                         param_dtype=cfg.param_dtype, name="out_proj")(out)
 
-    def _decode_attention(self, q, k, v, positions):
+    def _decode_attention(self, q, k, v, layer=None):
         """KV-cache attention (reference ``softmax_context`` kernel with
         cache append, inference/csrc/softmax.cu): writes this step's k/v at
         ``cache_index`` and attends over the filled prefix. Under the
         Pallas decode impl the cache lives FLAT [b, S, h*d]: XLA lane-pads
         a trailing d=64 dim (to 128), so a rank-4 cache would pay a
         full-cache relayout copy on every kernel call.
+
+        ``layer`` None: the ``cache`` leaves are this layer's own
+        ([b, S, ...]; the cache is being created, or the layers are not
+        scanned). ``layer`` an index: the leaves are the layer-stacked
+        [L, b, S, ...] arrays that the layer loop carries; the write lands
+        at ``(layer, row, pos)`` in place (:func:`_kv_write`) and attention
+        reads the layer's rows where they lie (:func:`_layer_rows`), so a
+        decode step produces no array of a layer's cache size. The
+        masked-lane sentinel drops the write in every layer either way.
 
         ``cache_index`` may be a scalar (every row at the same fill — the
         single-stream generate path) or a [b] vector (per-slot fills — the
@@ -537,7 +583,7 @@ class SelfAttention(nn.Module):
             # paged block-pool cache (serving/paged_kv.py): the engine
             # injected per-slot block tables, so reads and writes route
             # through them instead of slot rows
-            return self._paged_decode_attention(q, k, v)
+            return self._paged_decode_attention(q, k, v, layer)
         from ..ops.pallas import _utils as kernels
         from ..ops.pallas.decode_attention import decode_refusal
         int8 = cfg.kv_cache_dtype == "int8"
@@ -559,7 +605,9 @@ class SelfAttention(nn.Module):
                  else 1.0 / math.sqrt(d))
         idx = self.variable("cache", "cache_index",
                             lambda: jnp.zeros((), jnp.int32))
-        cur = idx.value
+        cur = _layer_rows(idx.value, layer)
+        rows = partial(_layer_rows, layer=layer)
+        write = partial(_kv_write, cur=cur, layer=layer)
         ksc = vsc = None
         if int8:
             from ..ops.quantizer import quantize_kv
@@ -569,22 +617,22 @@ class SelfAttention(nn.Module):
                                 (b, cfg.max_seq_len, 1), jnp.float32)
             kq, ks = quantize_kv(k.reshape(b, s, h * d))
             vq, vs = quantize_kv(v.reshape(b, s, h * d))
-            ksc.value = _kv_write(ksc.value, ks, cur)
-            vsc.value = _kv_write(vsc.value, vs, cur)
+            ksc.value = write(ksc.value, ks)
+            vsc.value = write(vsc.value, vs)
         if use_flat:
             ck = self.variable("cache", "cached_key", jnp.zeros,
                                (b, cfg.max_seq_len, h * d), kv_dt)
             cv = self.variable("cache", "cached_value", jnp.zeros,
                                (b, cfg.max_seq_len, h * d), kv_dt)
             if int8:
-                ck.value = _kv_write(ck.value, kq, cur)
-                cv.value = _kv_write(cv.value, vq, cur)
+                ck.value = write(ck.value, kq)
+                cv.value = write(cv.value, vq)
             else:
-                ck.value = _kv_write(
-                    ck.value, k.astype(cfg.dtype).reshape(b, s, h * d), cur)
-                cv.value = _kv_write(
-                    cv.value, v.astype(cfg.dtype).reshape(b, s, h * d), cur)
-            idx.value = cur + s
+                ck.value = write(
+                    ck.value, k.astype(cfg.dtype).reshape(b, s, h * d))
+                cv.value = write(
+                    cv.value, v.astype(cfg.dtype).reshape(b, s, h * d))
+            idx.value = _set_layer_rows(idx.value, layer, cur + s)
             from ..ops.pallas.decode_attention import (MAX_SPEC_S,
                                                        decode_attention)
             if s == 1 or (s <= MAX_SPEC_S and not cfg.sequence_parallel):
@@ -594,17 +642,20 @@ class SelfAttention(nn.Module):
                 # is the k+1 speculative-verify shape, handled in-kernel
                 # by the s-position qmat, so the spec hot loop never
                 # materializes a dequantized f32 cache view
+                # (a carried cache hands the kernel its layer's slice)
                 return decode_attention(
-                    q, ck.value, cv.value, cur + s, scale=scale,
-                    k_scale=ksc.value[..., 0] if int8 else None,
-                    v_scale=vsc.value[..., 0] if int8 else None)
+                    q, rows(ck.value), rows(cv.value), cur + s, scale=scale,
+                    k_scale=rows(ksc.value)[..., 0] if int8 else None,
+                    v_scale=rows(vsc.value)[..., 0] if int8 else None)
             # prefill: one relayout of the cache view per call
             if int8:
                 from ..ops.quantizer import dequantize_kv
-                kf = dequantize_kv(ck.value, ksc.value, cfg.dtype)
-                vf = dequantize_kv(cv.value, vsc.value, cfg.dtype)
+                kf = dequantize_kv(rows(ck.value), rows(ksc.value),
+                                   cfg.dtype)
+                vf = dequantize_kv(rows(cv.value), rows(vsc.value),
+                                   cfg.dtype)
             else:
-                kf, vf = ck.value, cv.value
+                kf, vf = rows(ck.value), rows(cv.value)
             ck4 = kf.reshape(b, cfg.max_seq_len, h, d)
             cv4 = vf.reshape(b, cfg.max_seq_len, h, d)
             return self._cache_einsum(q, ck4, cv4, cur, s, scale)
@@ -613,23 +664,27 @@ class SelfAttention(nn.Module):
         cv = self.variable("cache", "cached_value", jnp.zeros,
                            (b, cfg.max_seq_len, h, d), kv_dt)
         if int8:
-            ck.value = _kv_write(ck.value, kq.reshape(b, s, h, d), cur)
-            cv.value = _kv_write(cv.value, vq.reshape(b, s, h, d), cur)
+            ck.value = write(ck.value, kq.reshape(b, s, h, d))
+            cv.value = write(cv.value, vq.reshape(b, s, h, d))
         else:
-            ck.value = _kv_write(ck.value, k.astype(cfg.dtype), cur)
-            cv.value = _kv_write(cv.value, v.astype(cfg.dtype), cur)
-        idx.value = cur + s
+            ck.value = write(ck.value, k.astype(cfg.dtype))
+            cv.value = write(cv.value, v.astype(cfg.dtype))
+        idx.value = _set_layer_rows(idx.value, layer, cur + s)
         if int8:
             from ..ops.quantizer import dequantize_kv
-            kf = dequantize_kv(ck.value, ksc.value[..., None], cfg.dtype)
-            vf = dequantize_kv(cv.value, vsc.value[..., None], cfg.dtype)
+            kf = dequantize_kv(rows(ck.value), rows(ksc.value)[..., None],
+                               cfg.dtype)
+            vf = dequantize_kv(rows(cv.value), rows(vsc.value)[..., None],
+                               cfg.dtype)
         else:
-            kf, vf = ck.value, cv.value
+            kf, vf = rows(ck.value), rows(cv.value)
         return self._cache_einsum(q, kf, vf, cur, s, scale)
 
-    def _paged_decode_attention(self, q, k, v):
+    def _paged_decode_attention(self, q, k, v, layer=None):
         """Block-table decode (vLLM PagedAttention shape): the cache is a
-        flat block pool [nb, bs, h*d] shared by every slot; this slot's
+        flat block pool [nb, bs, h*d] shared by every slot (layer-stacked
+        [L, nb, bs, h*d] and written in place when the layer loop carries
+        it, as in :meth:`_decode_attention`); this slot's
         blocks are named by its ``block_tables`` row. Writes scatter
         through the table (:func:`_kv_write_paged`); attention gathers
         through it (ops/pallas/decode_attention.paged_decode_attention —
@@ -652,10 +707,10 @@ class SelfAttention(nn.Module):
         idx = self.variable("cache", "cache_index")
         ck = self.variable("cache", "cached_key")
         cv = self.variable("cache", "cached_value")
-        bt = self.get_variable("cache", "block_tables")
+        bt = _layer_rows(self.get_variable("cache", "block_tables"), layer)
         from ..ops.pallas import _utils as kernels
         from ..ops.pallas.decode_attention import paged_decode_refusal
-        refusal = paged_decode_refusal(b, ck.value.shape[1], h, d,
+        refusal = paged_decode_refusal(b, ck.value.shape[-2], h, d,
                                        ck.value.dtype, s) \
             or _decode_mesh_refusal()
         impl = cfg.decode_impl
@@ -665,7 +720,9 @@ class SelfAttention(nn.Module):
         if impl == "auto":
             impl = ("pallas" if kernels.auto_path("paged_decode_attention",
                                                   refusal) else "xla")
-        cur = idx.value                       # [b] per-slot write positions
+        cur = _layer_rows(idx.value, layer)   # [b] per-slot write positions
+        write = partial(_kv_write_paged, block_tables=bt, cur=cur,
+                        layer=layer)
         ksc = vsc = None
         if int8:
             from ..ops.quantizer import quantize_kv
@@ -673,22 +730,20 @@ class SelfAttention(nn.Module):
             vsc = self.variable("cache", "value_scale")
             kq, ks = quantize_kv(k.reshape(b, s, h * d))
             vq, vs = quantize_kv(v.reshape(b, s, h * d))
-            ck.value = _kv_write_paged(ck.value, kq, bt, cur)
-            cv.value = _kv_write_paged(cv.value, vq, bt, cur)
-            ksc.value = _kv_write_paged(ksc.value, ks, bt, cur)
-            vsc.value = _kv_write_paged(vsc.value, vs, bt, cur)
+            ck.value = write(ck.value, kq)
+            cv.value = write(cv.value, vq)
+            ksc.value = write(ksc.value, ks)
+            vsc.value = write(vsc.value, vs)
         else:
             dt = ck.value.dtype
-            ck.value = _kv_write_paged(
-                ck.value, k.astype(dt).reshape(b, s, h * d), bt, cur)
-            cv.value = _kv_write_paged(
-                cv.value, v.astype(dt).reshape(b, s, h * d), bt, cur)
-        idx.value = cur + s
+            ck.value = write(ck.value, k.astype(dt).reshape(b, s, h * d))
+            cv.value = write(cv.value, v.astype(dt).reshape(b, s, h * d))
+        idx.value = _set_layer_rows(idx.value, layer, cur + s)
         from ..ops.pallas.decode_attention import paged_decode_attention
         return paged_decode_attention(
             q, ck.value, cv.value, bt, cur + s, scale=scale, impl=impl,
             k_scale=ksc.value[..., 0] if int8 else None,
-            v_scale=vsc.value[..., 0] if int8 else None)
+            v_scale=vsc.value[..., 0] if int8 else None, layer=layer)
 
     def _cache_einsum(self, q, ck, cv, cur, s, scale):
         from ..ops.pallas.decode_attention import masked_cache_attention
@@ -720,7 +775,9 @@ class Block(nn.Module):
     structure is what makes ZeRO-3 gather/release and per-layer remat
     idiomatic on TPU. ``l_aux`` is the MoE load-balancing loss (0 for dense
     blocks), summed over layers by GPT. ``layer_idx`` is set only on the
-    non-scanned path (heterogeneous layers, e.g. GPT-Neo local windows)."""
+    non-scanned path (heterogeneous layers, e.g. GPT-Neo local windows);
+    ``cache_layer`` is the scanned path's traced layer index, given when
+    the layer loop carries a layer-stacked cache (GPT.__call__)."""
     cfg: GPTConfig
     layer_idx: Optional[int] = None
 
@@ -742,7 +799,7 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, deterministic=True, layer_frac=None,
-                 pld_theta=None):
+                 pld_theta=None, cache_layer=None):
         cfg = self.cfg
         if cfg.partition_activations and x.ndim == 3:
             x = tp_shard_sequence(x)
@@ -760,7 +817,7 @@ class Block(nn.Module):
         if cfg.parallel_residual:
             # NeoX: x + attn(ln1(x)) + ffn(ln2(x))
             ffn_out, l_aux = self._ffn(cfg, ln2(x), deterministic)
-            attn_out = attn(ln1(x), positions, deterministic)
+            attn_out = attn(ln1(x), positions, deterministic, cache_layer)
             if (cfg.tp_overlap and not self.is_initializing()
                     and self.is_mutable_collection("cache")):
                 # decode only: pin the attn branch hidden-sharded so its
@@ -769,7 +826,7 @@ class Block(nn.Module):
                 attn_out = defer_attn_allreduce(attn_out)
             out = x + attn_out + ffn_out
         else:
-            h = x + attn(ln1(x), positions, deterministic)
+            h = x + attn(ln1(x), positions, deterministic, cache_layer)
             ffn_out, l_aux = self._ffn(cfg, ln2(h), deterministic)
             out = h + ffn_out
         if pld_theta is not None:
@@ -859,9 +916,27 @@ class GPT(nn.Module):
                 (jnp.arange(1, cfg.num_layers + 1, dtype=jnp.float32)
                  / cfg.num_layers), pld_theta)
             extra_axes = () if pld_theta is None else (0, nn.broadcast)
+            # The cache, where there is one. CREATED by this call (prefill,
+            # the arena's eval_shape): scanned like the params, each layer's
+            # leaves come out stacked [L, ...] and nothing is copied twice.
+            # PASSED IN (every decode, speculative-verify and fused-prefill
+            # step): the loop CARRIES the stacked leaves and each layer
+            # writes its tokens at its own index in place. Scanning over a
+            # cache that came in would slice every layer's rows out of the
+            # arena, restack them into a fresh array and copy that back
+            # into the caller's carry: three passes over the whole arena a
+            # step (PERF.md, PR 25).
+            cache_axes, cache_carry = {"cache": 0}, False
+            if "blocks" in self.variables.get("cache", {}):
+                cache_axes, cache_carry = {}, "cache"
+                extra_in = (extra_in or (None, None)) + (
+                    jnp.arange(cfg.num_layers, dtype=jnp.int32),)
+                extra_axes = (extra_axes or (nn.broadcast, nn.broadcast)) \
+                    + (0,)
             ScannedBlock = nn.scan(
                 block,
-                variable_axes={"params": 0, "cache": 0},
+                variable_axes={"params": 0, **cache_axes},
+                variable_carry=cache_carry,
                 split_rngs={"params": True, "dropout": True, "gating": True,
                             "pld": True},
                 in_axes=(nn.broadcast, nn.broadcast) + extra_axes,

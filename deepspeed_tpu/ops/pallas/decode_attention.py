@@ -468,21 +468,27 @@ def paged_decode_supported(b: int, block_size: int, h: int, d: int,
     return paged_decode_refusal(b, block_size, h, d, dtype, s) is None
 
 
-def paged_gather_kv(pool: jnp.ndarray,
-                    block_tables: jnp.ndarray) -> jnp.ndarray:
+def paged_gather_kv(pool: jnp.ndarray, block_tables: jnp.ndarray,
+                    layer=None) -> jnp.ndarray:
     """Reference gather: pool [nb, bs, h*d] through block_tables [b, T]
     -> [b, T*bs, h*d]. Position p of row i reads flat pool index
     ``block_tables[i, p//bs]*bs + p%bs``; table entries past a row's
     reservation point at whatever block they name (zeros-padded tables
     read block 0) — those positions sit past the row's fill and are
-    masked by the caller, so garbage is gathered but never attended."""
-    nb, bs, hd = pool.shape
+    masked by the caller, so garbage is gathered but never attended.
+    With ``layer`` the pool is layer-stacked [L, nb, bs, h*d] and the
+    gather reads that layer's blocks where they lie (no slice of the
+    layer's pool is made)."""
+    nb, bs, hd = pool.shape[-3:]
     b, T = block_tables.shape
     p = jnp.arange(T * bs)
     blk = jnp.take(block_tables, p // bs, axis=1)            # [b, S]
-    flat = blk * bs + (p % bs)[None, :]
-    return jnp.take(pool.reshape(nb * bs, hd), flat, axis=0,
-                    mode="clip")
+    # a sentinel-padded entry is clipped INSIDE the layer, as the unstacked
+    # gather clips it, so stacked and unstacked read the same rows
+    flat = jnp.minimum(blk * bs + (p % bs)[None, :], nb * bs - 1)
+    if layer is not None:
+        flat = layer * (nb * bs) + flat
+    return jnp.take(pool.reshape(-1, hd), flat, axis=0, mode="clip")
 
 
 def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
@@ -490,14 +496,18 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            cache_len, scale: Optional[float] = None,
                            impl: str = "xla",
                            k_scale: Optional[jnp.ndarray] = None,
-                           v_scale: Optional[jnp.ndarray] = None
-                           ) -> jnp.ndarray:
+                           v_scale: Optional[jnp.ndarray] = None,
+                           layer=None) -> jnp.ndarray:
     """Decode attention over a PAGED cache. q: [b, s_q, h, d] (s_q > 1 is
     the speculative-verify shape); k_pool/v_pool: [nb, bs, h*d] block
     pools; block_tables: [b, T]; cache_len: valid positions per row
     (including this call's tokens, already written) — scalar or [b],
     sentinel entries past T*bs are clamped. ``k_scale``/``v_scale``
     [nb, bs] f32 mark int8 pools (per-position dequant multipliers).
+    ``layer``: the pools (and scales) are layer-stacked [L, nb, bs, ...]
+    and this call attends over that layer's blocks — the reference path
+    gathers them out of the stack directly, the kernel takes its layer's
+    slice.
 
     ``impl="xla"`` (the reference) gathers the pool through the table
     and calls the SAME masked einsum as the dense decode path — gathered
@@ -508,7 +518,13 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     O(cache_len) per token — and raises ``KernelUnsupported`` at a shape
     :func:`paged_decode_refusal` refuses."""
     b, s_q, h, d = q.shape
-    nb, bs, hd = k_pool.shape
+    if layer is not None and impl == "pallas":
+        k_pool, v_pool, k_scale, v_scale = (
+            None if a is None else
+            jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            for a in (k_pool, v_pool, k_scale, v_scale))
+        layer = None
+    nb, bs, hd = k_pool.shape[-3:]
     T = block_tables.shape[1]
     S = T * bs
     clen = jnp.minimum(
@@ -572,14 +588,14 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
             interpret=interpret_mode(),
         )(*operands)
         return _slice_block_diagonal(out, s_q, h, d)
-    kflat = paged_gather_kv(k_pool, block_tables)
-    vflat = paged_gather_kv(v_pool, block_tables)
+    kflat = paged_gather_kv(k_pool, block_tables, layer)
+    vflat = paged_gather_kv(v_pool, block_tables, layer)
     if quantized:
         from ..quantizer import dequantize_kv
         ks = paged_gather_kv(k_scale[..., None].astype(jnp.float32),
-                             block_tables)
+                             block_tables, layer)
         vs = paged_gather_kv(v_scale[..., None].astype(jnp.float32),
-                             block_tables)
+                             block_tables, layer)
         kflat = dequantize_kv(kflat, ks, q.dtype)
         vflat = dequantize_kv(vflat, vs, q.dtype)
     kf = kflat.reshape(b, S, h, d)

@@ -890,7 +890,13 @@ class ServingEngine:
             self._jit_prefill_sp = jax.jit(prefill_sp)
         else:
             self._jit_prefill_sp = None
-        # donate the arena: XLA updates every slot's KV rows in place
+        # donate the arena: every slot's KV rows are updated in place. The
+        # donation alone does not do that — the model does: a cache that is
+        # passed in is CARRIED by its layer loop (models/gpt.py), each layer
+        # scatters its tokens into the stacked leaves at (layer, lane, pos),
+        # and the chunk's scan carries the same buffers from step to step,
+        # so the programs below alias the arena and hold no copy of it
+        # (tests/test_decode_arena_in_place.py)
         self._jit_decode = jax.jit(decode, donate_argnums=(1,))
         # distinct function name => distinct TraceAuditor budget: every
         # fused / spec / int8 / paged combination is a different compiled
@@ -1272,20 +1278,12 @@ class ServingEngine:
                                 self.kv.occupancy, force=True)
         return submitted
 
-    def estimate_chunk_cost(self) -> Optional[Dict[str, Any]]:
-        """XLA cost analysis of one decode-chunk program invocation, for
-        MFU reporting (telemetry.mfu). Lowers ``_jit_decode_chunk`` with
-        abstract ``ShapeDtypeStruct`` args — no device buffers touched —
-        but pays ONE extra XLA compile, so benches call this strictly
-        AFTER their timed/audited passes (the pinned decode retrace
-        budget stays exact; see docs/observability.md).
-
-        XLA counts the chunk's ``lax.scan`` body once, not K times, so
-        ``flops_per_chunk`` scales the program count by K — an estimate,
-        flagged as such in the result. Returns None when the backend
-        reports no costs."""
+    def _abstract_chunk_args(self) -> list:
+        """The chunk program's arguments as ``ShapeDtypeStruct``s (params,
+        arena, lane state, the variant's extras, rng): what the analyses
+        below — and the structural test of the in-place arena — lower
+        ``_jit_decode_chunk`` with, touching no device buffer."""
         import jax
-        from ..telemetry import mfu as _mfu
 
         def abst(x):
             return jax.ShapeDtypeStruct(np.shape(x), x.dtype)
@@ -1304,10 +1302,27 @@ class ServingEngine:
             chunk_args.append(
                 jax.ShapeDtypeStruct((B, self.max_seq_len), np.int32))
         chunk_args.append(abst(self._rng))
+        return chunk_args
+
+    def estimate_chunk_cost(self) -> Optional[Dict[str, Any]]:
+        """XLA cost analysis of one decode-chunk program invocation, for
+        MFU reporting (telemetry.mfu). Lowers ``_jit_decode_chunk`` with
+        abstract ``ShapeDtypeStruct`` args — no device buffers touched —
+        but pays ONE extra XLA compile, so benches call this strictly
+        AFTER their timed/audited passes (the pinned decode retrace
+        budget stays exact; see docs/observability.md).
+
+        XLA counts the chunk's ``lax.scan`` body once, not K times, so
+        ``flops_per_chunk`` scales the program count by K — an estimate,
+        flagged as such in the result. Returns None when the backend
+        reports no costs."""
+        from ..telemetry import mfu as _mfu
+
         ca = _mfu.compiled_cost_analysis(
-            self._jit_decode_chunk, *chunk_args)
+            self._jit_decode_chunk, *self._abstract_chunk_args())
         if ca is None:
             return None
+        B = self.max_batch
         K = self.decode_chunk
         # each spec step scores spec_k + 1 positions in the one target
         # forward, so the per-position flop denominator scales with k+1
@@ -1347,19 +1362,8 @@ class ServingEngine:
         cache = jax.tree.map(abst, self.kv.cache)
         rng = abst(self._rng)
         if self._chunked:
-            chunk_args = [params, cache, i32, i32,
-                          jax.ShapeDtypeStruct((B,), bool), i32, i32]
-            if self.fused_prefill:
-                chunk_args.append(i32)    # pf_rem
-                chunk_args.append(jax.ShapeDtypeStruct(
-                    (self.decode_chunk, B, self.prefill_chunk),
-                    np.int32))
-            if self.speculative:
-                chunk_args.append(
-                    jax.ShapeDtypeStruct((B, self.max_seq_len), np.int32))
-            chunk_args.append(rng)
             decode = _mem.compiled_memory_analysis(
-                self._jit_decode_chunk, *chunk_args)
+                self._jit_decode_chunk, *self._abstract_chunk_args())
         else:
             decode = _mem.compiled_memory_analysis(
                 self._jit_decode, params, cache, i32, i32, rng)

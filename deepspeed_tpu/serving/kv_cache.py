@@ -10,6 +10,14 @@ variable-sized blocks (dynamic shapes XLA would recompile on), every
 request leases one fixed ``[max_seq, ...]`` slot row, and slot reuse is a
 single fused ``dynamic_update_slice`` per cache leaf.
 
+The leaves are layer-stacked (``blocks/attn/cached_key|cached_value
+[L, B, S, h, d]``, ``cache_index [L, B]`` under ``scan_layers``). The decode
+programs donate this tree and get the same buffers back: the model's layer
+loop carries a cache that is passed in and writes one token per lane at
+``(layer, lane, pos)`` in place (``models/gpt.py::_kv_write``), so a step
+never slices, restacks or copies the arena. Only ``insert``/``insert_batch``
+below move a row's worth of KV, once per admission.
+
 Two layers, deliberately separable:
   * :class:`SlotAllocator` — pure host-side accounting (free list, per-slot
     fill lengths, occupancy). No JAX. Unit-testable at CPU speed.
