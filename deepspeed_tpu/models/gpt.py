@@ -211,14 +211,14 @@ class GPTConfig:
     d_ff: int = 3072
     rotary: bool = False             # False: learned positions (GPT-2)
     rotary_pct: float = 1.0
+    rotary_base: float = 10000.0
+    block: Any = None   # None: this file's block; a models/mla.py LatentBlockConfig
     parallel_residual: bool = False  # True for NeoX
-    # Decode-time tp collective/MLP overlap (ops/tp_overlap.py): pin the
-    # attention-branch output hidden-sharded so GSPMD decomposes its
-    # post-projection all-reduce into reduce-scatter + all-gather with the
-    # independent parallel-residual MLP gemm between them. Parallel-
-    # residual only (the sequential block has nothing to hide behind);
-    # inert on meshes without a tp axis. The serving engine's megakernel
-    # mode flips this on when tp > 1.
+    # Decode-time tp collective/MLP overlap (ops/tp_overlap.py): the attention
+    # branch's output is pinned hidden-sharded so that GSPMD splits its
+    # all-reduce into reduce-scatter + all-gather around the independent
+    # parallel-residual MLP gemm. Parallel-residual only; inert without a tp
+    # axis. The serving engine's megakernel mode flips it on when tp > 1.
     tp_overlap: bool = False
     tie_embeddings: bool = True
     dtype: Any = jnp.bfloat16        # compute dtype
@@ -360,11 +360,11 @@ def gpt_moe_1_3b(num_experts=128, **kw):
 # Building blocks
 # --------------------------------------------------------------------------
 
-def rotary_embedding(x: jnp.ndarray, positions: jnp.ndarray, rotary_dim: int):
+def rotary_embedding(x, positions, rotary_dim: int, base: float = 10000.0):
     """Apply rotary position embedding to [..., S, H, D] over first rotary_dim."""
     d = rotary_dim
     x_rot, x_pass = x[..., :d], x[..., d:]
-    freqs = 1.0 / (10000 ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    freqs = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     angles = positions[..., None].astype(jnp.float32) * freqs  # [.., S, d/2]
     cos = jnp.cos(angles)[..., None, :]
     sin = jnp.sin(angles)[..., None, :]
@@ -485,8 +485,8 @@ class SelfAttention(nn.Module):
         q, k, v = q.reshape(shp), k.reshape(shp), v.reshape(shp)
         if cfg.rotary:
             rd = int(cfg.rotary_pct * cfg.head_dim)
-            q = rotary_embedding(q, positions, rd)
-            k = rotary_embedding(k, positions, rd)
+            q = rotary_embedding(q, positions, rd, cfg.rotary_base)
+            k = rotary_embedding(k, positions, rd, cfg.rotary_base)
 
         decode = self.has_variable("cache", "cached_key") or \
             (not self.is_initializing() and self.is_mutable_collection("cache"))
@@ -908,7 +908,7 @@ class GPT(nn.Module):
         if cfg.attn_windows is not None and cfg.scan_layers:
             raise ValueError("attn_windows (heterogeneous layers) requires "
                              "scan_layers=False")
-        if cfg.scan_layers:
+        if cfg.scan_layers and cfg.block is None:
             # pld_theta (when given) rides as a broadcast arg with a scanned
             # per-layer depth fraction, so the SAME "blocks" params serve
             # both plain and layer-drop training
@@ -947,7 +947,7 @@ class GPT(nn.Module):
             x, aux = ScannedBlock(cfg, name="blocks")(
                 x, positions, deterministic, *extra_in)
             moe_aux = jnp.sum(aux) if cfg.moe else jnp.zeros((), jnp.float32)
-        else:
+        elif cfg.block is None:
             moe_aux = jnp.zeros((), jnp.float32)
             for i in range(cfg.num_layers):
                 extra = {} if pld_theta is None else {
@@ -957,9 +957,17 @@ class GPT(nn.Module):
                                name=f"block_{i}")(x, positions, deterministic,
                                                   **extra)
                 moe_aux = moe_aux + aux
+        else:
+            # dense prefix, then scanned expert layers, over ONE
+            # layer-stacked latent cache leaf (models/mla.py)
+            from .mla import LatentStack, RMSNorm
+            x, expert_choice = LatentStack(cfg, name="blocks")(x, positions)
 
-        x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
-                         param_dtype=cfg.param_dtype, name="ln_f")(x)
+        if cfg.block is None:
+            x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
+                             param_dtype=cfg.param_dtype, name="ln_f")(x)
+        else:
+            x = RMSNorm(cfg, name="ln_f")(x)
         if cfg.tie_embeddings:
             logits = embed.attend(x)
         else:
@@ -967,7 +975,24 @@ class GPT(nn.Module):
                               param_dtype=cfg.param_dtype, name="lm_head")(x)
         if cfg.moe:
             return logits, cfg.moe_aux_loss_coef * moe_aux
+        if cfg.block is not None and expert_choice is not None:
+            # [expert layers, b, s, k] ids of the experts each token chose,
+            # of the PUBLISHED router width: what the serving programs count
+            # (moe/grouped.py::routing_counters) and the reference check reads
+            return logits, {"expert_choice": expert_choice}
         return logits
+
+    @nn.nowrap
+    def routing_counters(self, routed, live):
+        """What a call's expert layers routed, as scalars a serving program
+        sums on the device: ``routed`` is the dict a model with expert layers
+        returns beside its logits, ``live [b, s]`` the tokens that count
+        (moe/grouped.py::routing_counters)."""
+        from ..moe.grouped import routing_counters
+        block = self.cfg.block
+        return routing_counters(routed["expert_choice"], live,
+                                expert_offset=block.expert_offset,
+                                experts_held=block.experts_held)
 
 
 def lm_loss_fn(logits, batch):

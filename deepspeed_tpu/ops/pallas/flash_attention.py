@@ -91,6 +91,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
     b, s, h, d = q.shape
+    dv = v.shape[-1]    # may differ from q's and k's (latent attention)
     # kernel layout [B, H, S, D]
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -107,11 +108,11 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, dv),
                          lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, dv),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             # stats carry a trailing singleton lane dim: TPU lowering needs
             # the last two block dims divisible by (8, 128) or equal to the
@@ -120,13 +121,13 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, s, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         name="flash_attention_fwd",
         interpret=interpret_mode(),
@@ -218,6 +219,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd(causal, scale, block_q, block_k, res, g):
     qt, kt, vt, out, lse = res
     b, h, s, d = qt.shape
+    if vt.shape[-1] != d:
+        refuse("flash_attention backward", qt.shape,
+               f"v's head size {vt.shape[-1]} differs from q's {d}: only "
+               f"the forward kernel takes that")
     dot = g.transpose(0, 2, 1, 3)                          # [B,H,S,D]
     delta = jnp.sum(dot.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)                # [B,H,S,1]
@@ -343,7 +348,10 @@ def flash_refusal(s: int, block_q: int = 1024,
 def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: int = 1024, block_k: int = 1024):
-    """Fused attention. q, k, v: [B, S, H, D] -> [B, S, H, D].
+    """Fused attention. q, k, v: [B, S, H, D] -> [B, S, H, D]. The forward
+    also takes a ``v`` of another head size ``[B, S, H, Dv]`` (latent
+    attention: q.k over 192, v 128) and returns ``[B, S, H, Dv]``; the
+    backward kernels do not, and a gradient through such a call refuses.
 
     Default 1024-wide tiles: at GPT-2 125M shapes (b 8, s 1024, 12 heads of
     64, bf16) every tile from 128 to 1024 compiles on a v5e and the 1024

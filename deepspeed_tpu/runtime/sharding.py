@@ -40,6 +40,8 @@ _EXPERT_PAT = re.compile(r"(^|/)experts(/|$)")
 # in the cache collection (cache_index cursors, int8 scale leaves, block
 # tables) is tiny control state and stays replicated.
 _KV_PAYLOAD_PAT = re.compile(r"(cached_key|cached_value)")
+# models/mla.py: [c | k_rope] of a position, ONE row for all heads
+_KV_LATENT_PAT = re.compile(r"(^|/)latent$")
 
 
 def path_str(path) -> str:
@@ -92,6 +94,11 @@ def kv_spec(path: str, shape: Tuple[int, ...], tp: int,
     non-divisible dim falls back to replication rather than erroring."""
     ndim = len(shape)
     spec: list = [None] * ndim
+    if tp > 1 and _KV_LATENT_PAT.search(path):
+        raise ValueError(
+            f"cache leaf {path!r} {shape} is a latent row that every head "
+            f"reads whole: its {shape[-1]} values are not heads and do not "
+            f"shard over tp={tp}; serve this model data-parallel (tp=1)")
     if tp <= 1 or not _KV_PAYLOAD_PAT.search(path) or ndim < 2:
         return P(*spec)
     last = shape[-1]

@@ -102,6 +102,9 @@ class _InflightChunk:
     # unconditional perf_counter stamp at launch: the collective-overlap
     # gauge accumulates launch->retire wall seconds from it
     wall_t0: float = 0.0
+    # the chunk's routing counters, summed on the device over its steps
+    # (a model with expert layers; fetched with the tokens, no sync of its own)
+    routing: Any = None
 
 
 def _load_tuned_config(tuned_config) -> Dict[str, Any]:
@@ -399,9 +402,7 @@ class ServingEngine:
         self._decode_params = engine.params
         self._prefill_params = engine.params
         self._handoff_sharding = None       # set in disaggregated mode
-        head_dim = None
-        if getattr(cfg, "num_heads", None):
-            head_dim = int(cfg.d_model) // int(cfg.num_heads)
+        head_dim = self.kv.head_dim(getattr(cfg, "num_heads", None))
         self.disaggregated = bool(disaggregate_prefill)
         if self.disaggregated:
             from jax.sharding import NamedSharding, PartitionSpec
@@ -520,16 +521,33 @@ class ServingEngine:
         K = self.decode_chunk
         C_ = self.prefill_chunk
 
+        # a model with expert layers hands out the experts its tokens chose
+        # beside the logits and says how to count them; the prefill and
+        # chunk programs sum the counters on the device over the tokens that
+        # are somebody's (not a prompt's padding, not an idle lane) and
+        # return them beside the tokens, a third output that the other
+        # models' programs do not have
+        count_routing = getattr(module, "routing_counters", None)
+
+        def logits_and_routing(out, live):
+            if not isinstance(out, tuple):
+                return out, None
+            if count_routing is None or not isinstance(out[1], dict):
+                return out[0], None
+            return out[0], count_routing(out[1], live)
+
         def prefill(params, ids, true_lens, rng):
             pm = mat(params)
             positions = jnp.arange(ids.shape[1])[None, :]
-            logits, vc = module.apply({"params": pm}, ids,
-                                      positions=positions, mutable=["cache"])
-            if isinstance(logits, tuple):
-                logits = logits[0]
+            out, vc = module.apply({"params": pm}, ids,
+                                   positions=positions, mutable=["cache"])
+            logits, routing = logits_and_routing(
+                out, positions < true_lens[:, None])
             last = jnp.take_along_axis(
                 logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]  # [n,V]
             tok = sample_(last, rng, temperature_, top_k_, top_p_)
+            if routing is not None:
+                return tok, vc["cache"], routing
             return tok, vc["cache"]
 
         # sequence-parallel (Ulysses) prefill for very long prompts: the
@@ -598,11 +616,10 @@ class ServingEngine:
                 write_pos = jnp.where(act, pos,
                                       jnp.int32(max_seq_))  # masked lanes
                 c = _with_write_index(c, write_pos)
-                logits, vc = module.apply(
+                out, vc = module.apply(
                     {"params": pm, "cache": c}, tok[:, None],
                     positions=pos[:, None], mutable=["cache"])
-                if isinstance(logits, tuple):
-                    logits = logits[0]
+                logits, routing = logits_and_routing(out, act[:, None])
                 key, sub = jax.random.split(key)
                 nxt = sample_(logits[:, -1], sub,
                               temperature_, top_k_, top_p_)
@@ -614,13 +631,18 @@ class ServingEngine:
                     act, jnp.logical_and(rem > 0,
                                          jnp.logical_not(hit_eos)))
                 pos = jnp.where(emitted, pos + 1, pos)
-                return (vc["cache"], nxt, pos, act, rem, key), (nxt, emitted)
+                return ((vc["cache"], nxt, pos, act, rem, key),
+                        (nxt, emitted, routing))
 
-            (c, tok_f, pos_f, act_f, rem_f, _), (toks, valid) = jax.lax.scan(
-                body, (cache, tokens, positions, active, remaining, rng),
-                None, length=K)
-            return (jnp.moveaxis(toks, 0, 1), jnp.moveaxis(valid, 0, 1),
-                    c, tok_f, pos_f, act_f, rem_f)
+            (c, tok_f, pos_f, act_f, rem_f, _), (toks, valid, routing) = \
+                jax.lax.scan(
+                    body, (cache, tokens, positions, active, remaining, rng),
+                    None, length=K)
+            out = (jnp.moveaxis(toks, 0, 1), jnp.moveaxis(valid, 0, 1),
+                   c, tok_f, pos_f, act_f, rem_f)
+            if routing is not None:
+                out += (jax.tree.map(lambda x: jnp.sum(x, axis=0), routing),)
+            return out
 
         def decode_chunk_spec_fn(params, cache, tokens, positions, active,
                                  eos, remaining, hist, rng):
@@ -930,6 +952,18 @@ class ServingEngine:
                         else decode_chunk_fn)
         chunk_fn.__name__ = variant
         self._jit_decode_chunk = jax.jit(chunk_fn, donate_argnums=(1,))
+
+        # the host's corrections to the lane state a chunk carries
+        # (_device_state): lanes retired go inactive, lanes admitted take
+        # their state, every other lane keeps what the device computed
+        def lane_patch(tok, pos, act, rem, eos, deact, admit, vals):
+            return (jnp.where(admit, vals[0], tok),
+                    jnp.where(admit, vals[1], pos),
+                    (act & ~deact) | admit,
+                    jnp.where(admit, vals[2], rem),
+                    jnp.where(admit, vals[3], eos))
+
+        self._jit_lane_patch = jax.jit(lane_patch)
         # arena-size gauges at init: the KV footprint is fixed for the
         # engine's lifetime, headroom varies (re-gauged per chunk)
         arena = self.kv.arena_report()
@@ -986,7 +1020,7 @@ class ServingEngine:
                    "mesh (ROADMAP R9)")
         if cfg.decode_impl != "pallas":
             return
-        h, d = int(cfg.num_heads), int(cfg.d_model) // int(cfg.num_heads)
+        h, d = int(cfg.num_heads), self.kv.head_dim(cfg.num_heads)
         # query positions per decode-scan step
         width = (self.spec_k + 1) if self.speculative else 1
         if self.fused_prefill:
@@ -1406,6 +1440,16 @@ class ServingEngine:
             self._starved.drop()
             self._starved = None
 
+    def _count_routing(self, kind: str, routing) -> None:
+        """``routing``: [] or [the counters a program summed on the device]
+        (moe/grouped.py::routing_counters). Its tokens were fetched just
+        before, so the program has ended and this waits for nothing."""
+        import jax
+        for name, value in jax.device_get(routing[0] if routing else {}
+                                          ).items():
+            self.metrics.on_routing(kind, name, float(value))
+            telemetry.count(f"serve/moe_{kind}_{name}", float(value))
+
     def _next_rng(self):
         import jax
         self._rng, sub = jax.random.split(self._rng)
@@ -1662,7 +1706,7 @@ class ServingEngine:
                         ids[i, :r.prompt_len] = r.prompt
                         lens[i] = r.prompt_len
                     self._device_fed()
-                    toks, cache = prefill_fn(
+                    toks, cache, *routing = prefill_fn(
                         self._prefill_params, jnp.asarray(ids),
                         jnp.asarray(lens), self._next_rng())
                     if self._handoff_sharding is not None:
@@ -1671,6 +1715,7 @@ class ServingEngine:
                                          lens)
                 with telemetry.span("serve/prefill_wait"):
                     toks_host = np.asarray(toks)
+                    self._count_routing("prefill", routing)
                 # everything dispatched before that sync has run
                 self._starve("serve/starved_after_prefill")
             if prof is not None:
@@ -1870,44 +1915,49 @@ class ServingEngine:
             i += 1
         hist = chunk.state[i] if self.speculative else None
         if self._deact_slots or self._admit_patches:
-            # eager scatters, one small dispatch each: host time while
-            # the previous chunk may already have ended on the device
+            # ONE program of one shape whatever the number of lanes patched:
+            # masks and values over all max_batch lanes, built on the host.
+            # (A scatter per vector at the patched lanes' indices was one
+            # small program per COUNT of lanes, max_batch of each to warm,
+            # and six dispatches a patch.) Host time while the previous
+            # chunk may already have ended on the device.
             with telemetry.span("serve/lane_patch",
                                 n_deact=len(self._deact_slots),
                                 n_admit=len(self._admit_patches)):
+                deact = np.zeros(self.max_batch, bool)
+                admit = np.zeros(self.max_batch, bool)
+                vals = np.zeros((4, self.max_batch), np.int32)
                 if self._deact_slots:
                     telemetry.instant("serve/deact_patch",
                                       n=len(self._deact_slots))
                     if self.flight is not None:
                         self.flight.record("deact_patch",
                                            slots=sorted(self._deact_slots))
-                    idx = np.array(sorted(self._deact_slots), np.int32)
-                    act = act.at[idx].set(False)
+                    deact[sorted(self._deact_slots)] = True
                 if self._admit_patches:
                     telemetry.instant("serve/admit_patch",
                                       n=len(self._admit_patches))
                     if self.flight is not None:
                         self.flight.record("admit_patch",
                                            slots=sorted(self._admit_patches))
-                    slots = np.array(sorted(self._admit_patches), np.int32)
-                    vals = [self._admit_patches[int(s)] for s in slots]
-                    tok = tok.at[slots].set(
-                        np.array([v[0] for v in vals], np.int32))
-                    pos = pos.at[slots].set(
-                        np.array([v[1] for v in vals], np.int32))
-                    rem = rem.at[slots].set(
-                        np.array([v[2] for v in vals], np.int32))
-                    eos = eos.at[slots].set(
-                        np.array([v[3] for v in vals], np.int32))
-                    act = act.at[slots].set(True)
+                    slots = sorted(self._admit_patches)
+                    patches = [self._admit_patches[s] for s in slots]
+                    admit[slots] = True
+                    vals[:, slots] = np.array([v[:4] for v in patches]).T
+                tok, pos, act, rem, eos = self._jit_lane_patch(
+                    tok, pos, act, rem, eos, deact, admit, vals)
+                if self._admit_patches:
+                    # the fused and speculative programs' extra state: the
+                    # admitted rows alone (a history row is max_seq_len wide)
+                    idx = np.array(slots, np.int32)
                     vi = 4
                     if pf is not None:
-                        pf = pf.at[slots].set(
-                            np.array([v[vi] for v in vals], np.int32))
+                        pf = pf.at[idx].set(
+                            np.array([v[vi] for v in patches], np.int32))
                         vi += 1
                     if hist is not None:
-                        hist = hist.at[slots].set(
-                            np.stack([v[vi] for v in vals]))
+                        hist = hist.at[idx].set(
+                            np.stack([v[vi] for v in patches]))
         self._deact_slots.clear()
         self._admit_patches.clear()
         out = (tok, pos, act, rem, eos)
@@ -1927,6 +1977,7 @@ class ServingEngine:
         # dispatch-only span BY DESIGN (no sync=): the chunk is meant to
         # run asynchronously; the honest device wait is measured at
         # consume time as serve/chunk_host_wait
+        routing = []    # the plain chunk program of an expert model only
         with telemetry.span("serve/chunk_launch", k=self.decode_chunk):
             if self.fused_prefill:
                 state = tuple(jnp.asarray(a) for a in state)
@@ -1961,8 +2012,8 @@ class ServingEngine:
             else:
                 tokens, positions, active, remaining, eos = (
                     jnp.asarray(a) for a in state)
-                toks, valid, new_cache, tok_f, pos_f, act_f, rem_f = \
-                    self._jit_decode_chunk(
+                (toks, valid, new_cache, tok_f, pos_f, act_f, rem_f,
+                 *routing) = self._jit_decode_chunk(
                         self._decode_params, self.kv.cache, tokens,
                         positions, active, eos, remaining,
                         self._next_rng())
@@ -1971,7 +2022,7 @@ class ServingEngine:
         inflight = _InflightChunk(
             slot_uids={s: r.uid for s, r in self.scheduler.running.items()},
             tokens=toks, valid=valid, state=carry,
-            wall_t0=time.perf_counter())
+            wall_t0=time.perf_counter(), routing=routing)
         if prof is not None:
             t1 = prof.clock()
             inflight.launch_t = t1
@@ -1992,6 +2043,7 @@ class ServingEngine:
         with telemetry.span("serve/chunk_host_wait"):
             toks = np.asarray(chunk.tokens)
             valid = np.asarray(chunk.valid)
+            self._count_routing("decode", chunk.routing)
         if device_queue_empty:
             self._starve("serve/starved_after_chunk")
         rt0 = prof.clock() if prof is not None else 0.0

@@ -35,6 +35,25 @@ from typing import Any, List, Optional
 import numpy as np
 
 
+def declared_head_dim(cache_shapes, seq_axis: int,
+                      num_heads: Optional[int]) -> Optional[int]:
+    """The head size of the keys and values a model's ``cache`` collection
+    declares (its ``eval_shape``; ``seq_axis`` = where the positions lie):
+    the last dim of a ``[.., S, h, d]`` leaf, or a flat ``[.., S, h*d]``
+    ``cached_key``'s width over ``num_heads``. None where no leaf has heads
+    (a latent leaf ``[.., S, r]`` serves every head with one row): what
+    shards or tiles by heads then has nothing to go by, and says so."""
+    import jax
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache_shapes)[0]:
+        tail = leaf.shape[seq_axis + 1:]
+        if len(tail) == 2:
+            return int(tail[1])
+        if (len(tail) == 1 and num_heads
+                and "cached_key" in jax.tree_util.keystr(path)):
+            return int(tail[0]) // int(num_heads)
+    return None
+
+
 class SlotAllocator:
     """Host-side slot accounting: a fixed pool of ``max_batch`` cache rows,
     each leased to at most one in-flight request, with per-slot fill
@@ -132,6 +151,8 @@ class SlotKVCacheManager:
             partial(model.apply, mutable=["cache"]),
             {"params": params}, ids, positions=pos)
         cache_shapes = shapes[1]["cache"]
+        self.head_dim = partial(declared_head_dim, cache_shapes,
+                                slot_axis + 1)
 
         def build(path, leaf):
             if "cache_index" in jax.tree_util.keystr(path):
@@ -216,22 +237,25 @@ class SlotKVCacheManager:
         import numpy as _np
         kv_bytes = 0
         index_bytes = 0
-        int8_payload = 0            # quantized cached_key/value bytes
+        int8_payload = 0            # quantized payload bytes
         scale_bytes = 0             # per-token f32 dequant multipliers
-        for path, leaf in jax.tree_util.tree_flatten_with_path(
-                self.cache)[0]:
-            nbytes = getattr(leaf, "nbytes", None)
-            if nbytes is None:
+        # by what a leaf IS, whatever the model calls it: a leaf without a
+        # sequence axis is a cursor; every other leaf is paid per position
+        # (keys and values, a latent row, ...); beside an int8 payload a
+        # float leaf of one value a position is its scale
+        leaves = [x for x in jax.tree.leaves(self.cache)
+                  if getattr(x, "nbytes", None) is not None]
+        quantized = any(x.dtype == _np.int8 for x in leaves)
+        for leaf in leaves:
+            if leaf.ndim <= self._slot_axis + 1:
+                index_bytes += int(leaf.nbytes)
                 continue
-            key = jax.tree_util.keystr(path)
-            if "cache_index" in key:
-                index_bytes += int(nbytes)
-            else:
-                kv_bytes += int(nbytes)
-                if "scale" in key:
-                    scale_bytes += int(nbytes)
-                elif leaf.dtype == _np.int8:
-                    int8_payload += int(nbytes)
+            kv_bytes += int(leaf.nbytes)
+            if leaf.dtype == _np.int8:
+                int8_payload += int(leaf.nbytes)
+            elif quantized and int(_np.prod(
+                    leaf.shape[self._slot_axis + 2:])) == 1:
+                scale_bytes += int(leaf.nbytes)
         # what the SAME payload would cost in the model's fp dtype (scale
         # leaves don't exist in fp mode): saved = fp-equivalent - actual
         kv_bytes_fp = (kv_bytes - int8_payload - scale_bytes

@@ -116,6 +116,9 @@ class ServingMetrics:
         self.decode_steps = 0
         self.requests_done = 0
         self.rejected = 0
+        # what the expert layers routed, "<prefill|decode>_<counter>" ->
+        # the sum over the programs' steps (moe/grouped.py COUNTERS)
+        self.routing: Dict[str, float] = {}
         self._ttft_sum = 0.0
         self._ttft_n = 0
         self.ttft_reservoir = Reservoir()
@@ -159,6 +162,12 @@ class ServingMetrics:
         self.prefill_prompt_tokens += int(prompt_tokens)
         self.prefill_padded_tokens += int(n_prompts) * int(bucket_len)
         self.prefill_programs = int(n_programs)
+
+    def on_routing(self, kind: str, name: str, value: float) -> None:
+        """A counter of what the expert layers routed in one prefill or
+        decode program (``kind``), summed on the device over its steps."""
+        key = f"{kind}_{name}"
+        self.routing[key] = self.routing.get(key, 0.0) + value
 
     def on_prefix(self, hit: bool) -> None:
         """One paged admission resolved against the prefix cache."""

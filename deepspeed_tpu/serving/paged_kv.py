@@ -57,6 +57,8 @@ from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .kv_cache import declared_head_dim
+
 
 class BlockAllocator:
     """Refcounted fixed-size block pool with an LRU free list.
@@ -539,6 +541,8 @@ class PagedKVCacheManager:
             partial(model.apply, mutable=["cache"]),
             {"params": params}, ids, positions=pos)
         cache_shapes = shapes[1]["cache"]
+        self.head_dim = partial(declared_head_dim, cache_shapes,
+                                slot_axis + 1)
 
         nb, bs, ax = self.num_blocks, self.block_size, self._slot_axis
 

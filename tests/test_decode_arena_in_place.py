@@ -24,15 +24,18 @@ L, B, S, H, D, V = 3, 3, 16, 2, 8, 32
 
 
 # ------------------------------------------------------------- structural
-@pytest.mark.parametrize("mode", ["dense", "int8", "paged"])
+@pytest.mark.parametrize("mode", ["dense", "int8", "paged", "latent"])
 def test_chunk_program_keeps_the_arena_in_place(mode):
     """FLOAT32 on purpose: the CPU compiler turns a bf16 arena into float32
     whole, which buries the signal. 12 layers so that an int8 layer's
     dequantized float32 view (4/L of its arena, inherent to the read) stays
-    well under the threshold too."""
+    well under the threshold too. ``latent``: the second block's ONE
+    layer-stacked latent leaf (models/mla.py), a dense layer and eleven
+    expert layers writing into it."""
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.models.gpt import GPT, GPTConfig
+    from deepspeed_tpu.models.mla import LatentBlockConfig
     from deepspeed_tpu.serving import ServingEngine
     from deepspeed_tpu.telemetry.memory import compiled_memory_analysis
 
@@ -40,11 +43,18 @@ def test_chunk_program_keeps_the_arena_in_place(mode):
                     num_heads=4, d_model=128, d_ff=256, dtype=jnp.float32,
                     param_dtype=jnp.float32, rotary=True,
                     parallel_residual=True)
+    if mode == "latent":
+        cfg = dataclasses.replace(
+            cfg, parallel_residual=False, tie_embeddings=False,
+            block=LatentBlockConfig(
+                q_lora_rank=48, kv_lora_rank=96, qk_nope_head_dim=32,
+                qk_rope_head_dim=32, v_head_dim=32, n_routed_experts=16,
+                experts_per_token=2, moe_d_ff=64, experts_held=4))
     model = GPT(cfg)
     params = model.init(jax.random.PRNGKey(0),
                         jnp.zeros((1, 4), jnp.int32))["params"]
     kw = {"dense": {}, "int8": {"kv_dtype": "int8"},
-          "paged": {"paged": True}}[mode]
+          "paged": {"paged": True}, "latent": {}}[mode]
     eng = ServingEngine(model, model_parameters=params, dtype=jnp.float32,
                         max_batch=4, decode_chunk=8, **kw)
     arena = sum(x.nbytes for x in jax.tree.leaves(eng.kv.cache))
