@@ -10,8 +10,8 @@ family's TP vocabulary (``qkv``/``out_proj``/``up_proj``/``down_proj``/
 ``wte``), so the mesh sharding rules (runtime/sharding.py) — column-split
 qkv+up, row-split out+down with the psum inserted by GSPMD — apply to BERT
 with zero new code. Post-LayerNorm residuals per the original architecture;
-encoder blocks ride one ``nn.scan`` like GPT (ZeRO-3 gather/release and
-remat per layer for free).
+encoder blocks ride one ``nn.scan`` like GPT (under ZeRO-3 ``dp`` shards a
+dim inside the layer, runtime/sharding.py, so a scan step gathers one layer).
 """
 
 from __future__ import annotations

@@ -8,10 +8,10 @@ the engine milestones (BASELINE.json configs: GPT-2 125M -> GPT-NeoX 20B ->
   * Plain flax.linen with einsum attention; the hot ops (attention, layernorm)
     route through ``deepspeed_tpu.ops`` so Pallas kernels can slot in.
   * ``scan_layers=True`` stacks the transformer blocks into one scanned
-    layer with stacked params [L, ...] — this is the structure that makes
-    ZeRO-3 idiomatic on TPU: sharding the stacked leading-dim-L params over
-    ``dp`` gives per-layer all-gather/release for free inside ``lax.scan``,
-    and remat per scan step is the activation-checkpointing analogue
+    layer with stacked params [L, ...]. Under ZeRO-3 ``dp`` shards a dim
+    INSIDE the layer (never L: runtime/sharding.py) and the scan body
+    gathers its own layer's slice where it reads it (``_layer``), so one
+    layer is live at a time; remat per scan step is the checkpointing analogue
     (reference runtime/activation_checkpointing/checkpointing.py:493).
     The KV ``cache`` collection is stacked the same way ([L, B, S, h, d]
     leaves). A call that CREATES the cache (prefill) scans over it like the
@@ -902,7 +902,7 @@ class GPT(nn.Module):
             # deterministic stays STATIC through remat: MoE gating and
             # dropout branch on it in Python (tracing it breaks, and a
             # traced train/eval flag would bake both branches anyway)
-            block = nn.remat(Block, prevent_cse=False, policy=policy,
+            block = nn.remat(_layer(cfg), prevent_cse=False, policy=policy,
                              static_argnums=(3,))   # arg 0 is the module
 
         if cfg.attn_windows is not None and cfg.scan_layers:
@@ -993,6 +993,16 @@ class GPT(nn.Module):
         return routing_counters(routed["expert_choice"], live,
                                 expert_offset=block.expert_offset,
                                 experts_held=block.experts_held)
+
+
+def _layer(cfg):
+    """The block class the remat wraps. Under scan-over-layers it carries
+    the ZeRO-3 trainer's statement of the per-layer gather (a no-op class
+    transform outside such a trace; runtime/sharding.py)."""
+    if not cfg.scan_layers:
+        return Block
+    from ..runtime.sharding import gathered_where_used
+    return gathered_where_used(Block)
 
 
 def lm_loss_fn(logits, batch):

@@ -494,95 +494,122 @@ class DeepSpeedEngine:
         }
         self._init_opt_state()
 
-    def _check_zero3_working_set(self, params):
-        """Honor ``stage3_max_live_parameters`` (reference zero/config.py:
-        max live params the coordinator may keep gathered,
-        partitioned_param_coordinator.py:240-356). In this design the live
-        set is bounded structurally — scan-over-layers gathers one layer
-        slice at a time and releases it — so compliance is automatic
-        whenever it is achievable at all. What CAN violate the cap is its
-        floor: persisted (sub-threshold, replicated) params plus the largest
-        single tensor that must be fully materialized for its matmul. If the
-        user explicitly set a cap below that floor, no schedule could honor
-        it; reject loudly rather than nod (an unwired knob must not no-op)."""
+    def zero3_gather_plan(self, params=None):
+        """What a stage-3 step gathers, read off the specs the program is
+        built from (``params``: any tree with the model's shapes; default
+        the master). Per dp-sharded leaf: the dim ``dp`` lies on, whether a
+        scan over layers slices the leaf (``sharding._scan_dims``), and the
+        compute-dtype bytes a chip holds of it while it is in use.
+
+        * ``stacked_on_layer_axis`` — scanned leaves with ``dp`` on the dim
+          the scan slices. Each iteration then all-gathers the WHOLE stack;
+          the rules never place ``dp`` there, so this reads 0.
+        * ``stacked_inside_layer`` / ``outside_scan`` — leaves gathered one
+          layer a scan step / once a pass (``lm_head``, the final norm).
+        * ``layers``, ``layer_gather_bytes`` — scan length, and the bytes one
+          layer's gather brings to a chip: the (dp-1)/dp of the layer's
+          gathered leaves that the chip does not own.
+        * ``gather_bytes_per_micro_step`` — received per chip in the two
+          loops over the layers, forward and backward (the recomputed
+          forward reads the layer its backward iteration gathered), plus
+          the leaves outside the scan in each direction.
+        * ``persisted`` / ``largest_gathered`` — elements held replicated
+          at all times, and the largest single gathered unit beside them
+          (their sum is what ``stage3_max_live_parameters`` is held to).
+        Embedding tables with ``dp`` on the vocabulary dim are never
+        gathered: the lookup partitions by its indices."""
         if self.zero_stage < 3:
+            return None
+        if params is None:
+            params = self.state["master"]
+        from .sharding import _scan_dims, path_str
+        mesh_sizes = dict(self.mesh.shape)
+        itemsize = jnp.dtype(self.compute_dtype).itemsize
+        flat, _ = jax.tree_util.tree_flatten_with_path(params)
+        specs = jax.tree.leaves(self.rules.param_specs(params),
+                                is_leaf=lambda x: isinstance(x, P))
+        plan = {"stacked_on_layer_axis": 0, "stacked_inside_layer": 0,
+                "outside_scan": 0, "layers": 0, "persisted": 0}
+        layer_numel = outside_numel = largest = 0
+        for (pth, p), spec in zip(flat, specs):
+            path, shape = path_str(pth), tuple(int(d) for d in p.shape)
+            scanned = _scan_dims(path)
+            gathered = "dp" in tuple(spec) and \
+                not self.rules._is_embed_table(path, shape)
+            held = 1   # shards that stay apart while the leaf is in use
+            for entry in spec:
+                for a in ((entry,) if isinstance(entry, str)
+                          else (entry or ())):
+                    if a != "dp" or not gathered:
+                        held *= mesh_sizes.get(a, 1)
+            numel = -(-int(np.prod(shape, dtype=np.int64)) // held)
+            if not gathered:
+                plan["persisted"] += numel
+            elif not scanned:
+                plan["outside_scan"] += 1
+                outside_numel += numel
+                largest = max(largest, numel)
+            elif tuple(spec).index("dp") < scanned:
+                plan["stacked_on_layer_axis"] += 1
+                largest = max(largest, numel)    # the whole stack is live
+            else:
+                plan["stacked_inside_layer"] += 1
+                plan["layers"] = max(plan["layers"], shape[0])
+                layer_numel += numel // shape[0]
+                largest = max(largest, -(-numel // shape[0]))
+        received = (self.rules.dp - 1) / self.rules.dp * itemsize
+        plan["layer_gather_bytes"] = int(layer_numel * received)
+        plan["gather_bytes_per_micro_step"] = int(
+            2 * (plan["layers"] * layer_numel + outside_numel) * received)
+        plan["largest_gathered"] = largest
+        return plan
+
+    def _check_zero3_working_set(self, params):
+        """Say what the stage-3 program gathers (one log line and
+        ``train/zero3_*`` gauges from ``zero3_gather_plan``) and honor
+        ``stage3_max_live_parameters`` (reference zero/config.py: max live
+        params the coordinator may keep gathered,
+        partitioned_param_coordinator.py:240-356). The live set is bounded
+        by the program's structure: ``dp`` lies inside the layer on every
+        scanned leaf and the scan body gathers its own layer's slice, so
+        one layer is live at a time (whole-stack gathers would show as
+        ``stacked_on_layer_axis``). What CAN violate the cap is its floor:
+        persisted (sub-threshold, replicated) params plus the largest
+        single unit that must be materialized for its matmul. If the user
+        explicitly set a cap below that floor, no schedule could honor it;
+        reject loudly rather than nod (an unwired knob must not no-op)."""
+        plan = self.zero3_gather_plan(params)
+        if plan is None:
             return
+        log_dist(
+            f"ZeRO-3 gathers: {plan['stacked_inside_layer']} scanned leaves "
+            f"one layer a step ({plan['layer_gather_bytes']:,} B a layer a "
+            f"chip, {plan['layers']} layers), {plan['outside_scan']} leaves "
+            f"outside the scan, {plan['stacked_on_layer_axis']} scanned "
+            f"leaves on the layer axis; "
+            f"{plan['gather_bytes_per_micro_step']:,} B a micro-step a chip",
+            ranks=[0])
+        for k in ("stacked_on_layer_axis", "stacked_inside_layer",
+                  "outside_scan", "layer_gather_bytes",
+                  "gather_bytes_per_micro_step"):
+            telemetry.gauge(f"train/zero3_{k}", float(plan[k]))
         zraw = self.config._raw.get("zero_optimization", {})
-        explicitly_set = ("max_live_parameters" in zraw
-                          or "stage3_max_live_parameters" in zraw)
-        if not explicitly_set:
+        if "max_live_parameters" not in zraw \
+                and "stage3_max_live_parameters" not in zraw:
             return
         cap = self.config.zero_config.max_live_parameters
-        specs = self.rules.param_specs(params)
-
-        def axes_of(spec):
-            out = []
-            for entry in spec:
-                out.extend((entry,) if isinstance(entry, str)
-                           else (entry or ()))
-            return out
-
-        # per-chip live elements when the leaf is in use: the dp gather is
-        # undone, but tp/ep sharding remains; a scan-stacked [L, ...] leaf
-        # materializes one layer slice per scan step, not the whole stack
-        mcfg = getattr(self.module, "cfg", None)
-        scan_len = getattr(mcfg, "num_layers", None) \
-            if getattr(mcfg, "scan_layers", False) else None
-        mesh_sizes = dict(self.mesh.shape)
-
-        def dp_gathered(path, spec, p):
-            # embedding tables with dp on the vocab dim (plain or nested
-            # with tp) are never gathered at use — the lookup partitions by
-            # its indices (_stage3_embed_spec); everything else with a
-            # top-level dp axis is all-gathered for its matmul
-            from .sharding import ShardingRules as _SR
-            if _SR._is_embed_table(path, tuple(p.shape)):
-                return False
-            return any(entry == "dp" for entry in spec
-                       if isinstance(entry, str))
-
-        def numel_of(p):
-            n = 1
-            for d in p.shape:
-                n *= int(d)
-            return n
-
-        def live_numel(path, spec, p):
-            n = numel_of(p)
-            shards = 1
-            for a in axes_of(spec):
-                if a != "dp" or not dp_gathered(path, spec, p):
-                    shards *= mesh_sizes.get(a, 1)
-            n = -(-n // shards)
-            # only dp-sharded stacked leaves gather one slice per scan step;
-            # persisted (replicated) stacks are fully resident at all times
-            if scan_len and dp_gathered(path, spec, p) and "blocks" in path \
-                    and p.shape[0] == scan_len:
-                n = -(-n // scan_len)
-            return n
-
-        flat, _ = jax.tree_util.tree_flatten_with_path(params)
-        spec_leaves = jax.tree.leaves(specs,
-                                      is_leaf=lambda x: isinstance(x, P))
-        from .sharding import path_str
-        rows = [(path_str(pth), spec, p)
-                for (pth, p), spec in zip(flat, spec_leaves)]
-        persistent = sum(live_numel(pth, spec, p) for pth, spec, p in rows
-                         if not dp_gathered(pth, spec, p))
-        largest = max((live_numel(pth, spec, p) for pth, spec, p in rows
-                       if dp_gathered(pth, spec, p)), default=0)
-        floor = persistent + largest
+        floor = plan["persisted"] + plan["largest_gathered"]
         if cap < floor:
             raise ValueError(
                 f"stage3_max_live_parameters={cap:,} is below the working-"
-                f"set floor of this model: {persistent:,} persisted params "
-                f"(under param_persistence_threshold="
+                f"set floor of this model: {plan['persisted']:,} persisted "
+                f"params (under param_persistence_threshold="
                 f"{self.rules.param_persistence_threshold:,}) + "
-                f"{largest:,} for the largest single tensor. The scan-over-"
-                f"layers program already keeps the live set at its "
-                f"structural minimum; raise the cap to at least {floor:,}, "
-                f"lower param_persistence_threshold, or shard the model "
-                f"further (tp/pp)")
+                f"{plan['largest_gathered']:,} for the largest single "
+                f"tensor. The scan-over-layers program already keeps the "
+                f"live set at its structural minimum; raise the cap to at "
+                f"least {floor:,}, lower param_persistence_threshold, or "
+                f"shard the model further (tp/pp)")
 
     def _init_opt_state(self):
         # Build a throwaway transformation just for init (lr constant — state
@@ -685,8 +712,9 @@ class DeepSpeedEngine:
             for k, v in (model_kwargs or {}).items():
                 if var_kw or k in names:
                     kwargs[k] = v
-            return self.module.apply({"params": params}, inputs, rngs=rngs,
-                                     **kwargs)
+            with self.rules.stating_layer_gathers():
+                return self.module.apply({"params": params}, inputs,
+                                         rngs=rngs, **kwargs)
         return self.module(params, batch, rng)
 
     def _loss_of(self, params, batch, rng, train=True, model_kwargs=None):

@@ -15,6 +15,19 @@ Stage semantics (ZeRO paper / reference zero/config.py):
   stage 2: + grads reduce-scattered over dp (grad spec = sharded).
   stage 3: + parameters sharded over dp; all-gathered per use.
 
+Where ``dp`` lies on a leaf. A model that scans over its layers stacks each
+block leaf ``[L, ...]`` and the scan slices dim 0 at a traced index. ``dp``
+therefore never takes that dim (``_scan_dims``): it takes the first free
+dim INSIDE the layer, the same one for the compute copy, the gradient and
+the master, so a scan step's slice of a leaf is itself dp-sharded. The
+model states the gather where the layer is used (``gathered_where_used``:
+the slice is constrained to its dp-replicated spec in the scan body): one
+layer's weights are all-gathered per iteration and die with it, and its
+gradient leaves through a reduce-scatter into the accumulator's shard. With ``dp`` on dim 0 each chip would own L/dp
+whole layers, and a slice at a traced index along a sharded dim makes the
+partitioner all-gather the WHOLE stack inside every iteration (PERF.md,
+PR 27: 65 % of a ZeRO-3 step over four chips).
+
 Tensor parallelism: Megatron-style column/row split keyed on parameter path
 (the reference only *consumes* an mpu for training and produces TP via
 module_inject for inference, replace_module.py:502; here TP is first-class).
@@ -22,7 +35,9 @@ module_inject for inference, replace_module.py:502; here TP is first-class).
 
 from __future__ import annotations
 
+import contextlib
 import re
+import threading
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -42,6 +57,9 @@ _EXPERT_PAT = re.compile(r"(^|/)experts(/|$)")
 _KV_PAYLOAD_PAT = re.compile(r"(cached_key|cached_value)")
 # models/mla.py: [c | k_rope] of a position, ONE row for all heads
 _KV_LATENT_PAT = re.compile(r"(^|/)latent$")
+# The module a model scans over its layers (models/gpt.py, models/bert.py:
+# ``nn.scan(...)(cfg, name="blocks")``); unscanned layers are ``block_<i>``.
+_SCANNED_PAT = re.compile(r"(^|/)blocks(/|$)")
 
 
 def path_str(path) -> str:
@@ -124,17 +142,60 @@ def kv_shardings(cache, mesh: Mesh, head_dim: Optional[int] = None):
     return jax.tree_util.tree_map_with_path(leaf, cache)
 
 
-def _add_axis(spec: P, shape: Tuple[int, ...], axis_name: str, axis_size: int) -> P:
-    """Extend `spec` by sharding the first free, divisible dim over
-    `axis_name`; no-op if nothing fits (tensor stays replicated over it)."""
+def _scan_dims(path: str) -> int:
+    """How many leading dims of the leaf at ``path`` a scan over layers
+    slices: 1 under the scanned ``blocks`` module, else 0."""
+    return 1 if _SCANNED_PAT.search(path) else 0
+
+
+def _add_axis(spec: P, shape: Tuple[int, ...], axis_name: str, axis_size: int,
+              first_dim: int = 0) -> P:
+    """Extend `spec` by sharding the first free, divisible dim from
+    `first_dim` on over `axis_name`; no-op if nothing fits (tensor stays
+    replicated over it)."""
     if axis_size <= 1:
         return spec
     parts = list(spec) + [None] * (len(shape) - len(spec))
-    for i, d in enumerate(shape):
+    for i in range(first_dim, len(shape)):
+        d = shape[i]
         if parts[i] is None and d % axis_size == 0 and d >= axis_size:
             parts[i] = axis_name
             return P(*parts)
     return P(*parts)
+
+
+def _constrained_forward(x, sharding):
+    """``x`` held to ``sharding`` on the way in; its cotangent passes as it
+    comes. (``with_sharding_constraint`` alone transposes to the SAME
+    constraint: a gathered weight's gradient would be all-reduced to every
+    chip and sliced after the loop, where the accumulator's dp shard makes
+    it a reduce-scatter.)"""
+    @jax.custom_vjp
+    def use(x):
+        return jax.lax.with_sharding_constraint(x, sharding)
+    use.defvjp(lambda x: (use(x), None), lambda _, g: (g,))
+    return use(x)
+
+
+# ``.rules``: the rules of the ZeRO-3 trainer whose model this thread is
+# tracing, for the model's hook (``gathered_where_used``); unset elsewhere.
+_tracing = threading.local()
+
+
+def gathered_where_used(block):
+    """Hook for a model that scans over its layers: wrap the block class
+    the scan runs so that, when a ZeRO-3 trainer traces the model, each
+    iteration's slice of the stacked params is constrained to its
+    ``dp``-replicated spec before the block reads it. Wrap INSIDE the
+    block's remat: the gathered layer is then gathered again for the
+    recomputed forward and dies with the iteration; outside it every
+    gathered layer would be saved for the backward pass. Returns ``block``
+    itself anywhere else (init, inference, stages 0-2, ``dp`` of 1)."""
+    rules = getattr(_tracing, "rules", None)
+    if rules is None:
+        return block
+    import flax.linen as nn
+    return nn.map_variables(block, "params", trans_in_fn=rules.gather_layer)
 
 
 class ShardingRules:
@@ -192,12 +253,18 @@ class ShardingRules:
                 if self._is_embed_table(path, shape):
                     spec = self._stage3_embed_spec(path, shape, spec)
                 else:
-                    spec = _add_axis(spec, shape, "dp", self.dp)
+                    spec = self._add_dp(path, shape, spec)
             # else: persisted — replicated over dp, no per-layer gather.
             # (Stacked [L, ...] leaves compare their full stacked size, the
             # conservative direction: a leaf persists only when the whole
             # stack is small. Master/opt state stays dp-sharded either way.)
         return spec
+
+    def _add_dp(self, path: str, shape: Tuple[int, ...], spec: P) -> P:
+        """``dp`` on the first free, divisible dim inside the layer: never
+        on a dim a scan over layers slices (module docstring)."""
+        return _add_axis(spec, shape, "dp", self.dp,
+                         first_dim=_scan_dims(path))
 
     @staticmethod
     def _is_embed_table(path: str, shape: Tuple[int, ...]) -> bool:
@@ -237,7 +304,7 @@ class ShardingRules:
         """fp32 master copy / optimizer moments: sharded from stage 1 on."""
         spec = self._base_spec(path, shape, expert_dim)
         if self.stage >= 1:
-            spec = _add_axis(spec, shape, "dp", self.dp)
+            spec = self._add_dp(path, shape, spec)
         return spec
 
     def grad_spec(self, path: str, shape: Tuple[int, ...],
@@ -246,8 +313,36 @@ class ShardingRules:
         output to the sharded spec turns the dp psum into psum_scatter)."""
         spec = self._base_spec(path, shape, expert_dim)
         if self.stage >= 2:
-            spec = _add_axis(spec, shape, "dp", self.dp)
+            spec = self._add_dp(path, shape, spec)
         return spec
+
+    # -- the gather, stated where a scanned layer is used -------------------
+    @contextlib.contextmanager
+    def stating_layer_gathers(self):
+        """While a stage-3 trainer traces its model: ``gathered_where_used``
+        blocks constrain their layer's params by these rules. ZeRO-3's
+        contract is "the layer's weights are gathered, the batch stays
+        home"; left to the declaration alone the partitioner may keep a
+        contraction-sharded weight where it lies, gather the batch and
+        all-reduce activations instead."""
+        if self.stage < 3 or self.dp <= 1:
+            yield
+            return
+        prev = getattr(_tracing, "rules", None)
+        _tracing.rules = self
+        try:
+            yield
+        finally:
+            _tracing.rules = prev
+
+    def gather_layer(self, layer_vars):
+        """One scan step's slice of the stacked block params (a flax
+        variables dict, paths relative to the block), each leaf constrained
+        to its spec with ``dp`` gathered and ``tp``/``ep`` kept."""
+        def leaf(path, x):
+            spec = self._base_spec(path_str(path), tuple(x.shape))
+            return _constrained_forward(x, NamedSharding(self.mesh, spec))
+        return jax.tree_util.tree_map_with_path(leaf, layer_vars)
 
     # -- tree-level helpers -------------------------------------------------
     @staticmethod

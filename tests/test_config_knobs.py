@@ -37,21 +37,22 @@ def _tiny(seed=0, **cfg_kw):
 def test_param_persistence_threshold_keeps_small_leaves_replicated():
     mesh = _mesh(dp=8)
     rules = ShardingRules(mesh, zero_stage=3, param_persistence_threshold=1000)
-    bias = rules.param_spec("blocks/attn/qkv/bias", (96,))
-    kernel = rules.param_spec("blocks/mlp/up_proj/kernel", (256, 1024))
+    # leaves of the scanned stack as the model has them: [L, ...]
+    bias = rules.param_spec("blocks/attn/qkv/bias", (2, 96))
+    kernel = rules.param_spec("blocks/mlp/up_proj/kernel", (2, 256, 1024))
     assert all(a != "dp" for a in bias), \
         f"sub-threshold leaf should persist (stay replicated), got {bias}"
     assert "dp" in tuple(kernel), \
         f"above-threshold leaf should shard over dp, got {kernel}"
     # master/opt state shards over dp regardless of persistence
-    mbias = rules.master_spec("blocks/attn/qkv/bias", (96,))
+    mbias = rules.master_spec("blocks/attn/qkv/bias", (2, 96))
     assert "dp" in tuple(mbias)
 
 
 def test_param_persistence_threshold_zero_shards_everything():
     mesh = _mesh(dp=8)
     rules = ShardingRules(mesh, zero_stage=3, param_persistence_threshold=0)
-    bias = rules.param_spec("blocks/attn/qkv/bias", (96,))
+    bias = rules.param_spec("blocks/attn/qkv/bias", (2, 96))
     assert "dp" in tuple(bias)
 
 
