@@ -1,7 +1,8 @@
 #!/usr/bin/env bash
-# Serving smoke: tiny-model serving benchmark comparing the per-token
-# decode loop (decode_chunk=1) against the fused K-step loop
-# (decode_chunk=8), asserting bit-identical greedy outputs between them,
+# Serving smoke: tiny-model serving benchmark comparing the serve loop
+# at a chunk of one decode step (decode_chunk=1, one host sync per
+# token) against chunks of eight (decode_chunk=8), asserting
+# bit-identical greedy outputs between them,
 # plus the --paged A/B (block-pool KV vs dense arena, bit-identical
 # greedy asserted; pinned paged retrace budget), the shared-prefix
 # workload (N requests, one common prompt: prefill executed exactly
@@ -13,7 +14,7 @@
 # tokens/s on the repetitive workload; acceptance rate reported), and
 # the default-on fused chunked-prefill A/B (prompts consumed in-scan:
 # bit-identical greedy dense AND paged, pinned fused retrace budgets,
-# zero attributed prefill stall), and the --tiered case (a workload
+# no wait on a prefill program), and the --tiered case (a workload
 # whose aggregate context is 10x the HBM block pool: cold prefixes
 # demote to host DRAM/NVMe and promote back on re-serve — bit-identical
 # greedy vs an all-HBM reference, >= 0.8x its throughput, demote/promote

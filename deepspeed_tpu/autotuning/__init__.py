@@ -8,13 +8,8 @@ from .memory import (activation_memory_per_chip, chip_memory_bytes,
                      estimate_zero_model_states_mem_needs,
                      max_micro_batch_for_budget,
                      model_states_memory_per_chip)
-from .serving_tuner import (METRIC_TOKENS_PER_S, ServingCapacityTuner,
-                            ServingTuningSpace, TUNED_SCHEMA,
-                            tune_serving_capacity)
 
 __all__ = ["Autotuner", "TuningSpace", "Experiment", "METRIC_THROUGHPUT",
            "METRIC_LATENCY", "model_states_memory_per_chip",
            "activation_memory_per_chip", "max_micro_batch_for_budget",
-           "estimate_zero_model_states_mem_needs", "chip_memory_bytes",
-           "ServingCapacityTuner", "ServingTuningSpace",
-           "tune_serving_capacity", "METRIC_TOKENS_PER_S", "TUNED_SCHEMA"]
+           "estimate_zero_model_states_mem_needs", "chip_memory_bytes"]

@@ -360,14 +360,6 @@ def run_bench(n_requests: int = 8, max_new_tokens: int = 32,
     for eng in replicas:                 # charge compiles before the
         eng.run(list(prompts),          # frontend takes ownership
                 max_new_tokens=max_new_tokens)
-    # one chunk profiler per replica (the hot-path hooks are
-    # single-writer; sharing one instance across two driver threads
-    # would misattribute launches) — the committed block reports the
-    # busiest replica's attribution
-    from ..telemetry.profiler import ChunkProfiler, validate_report
-    profs = [ChunkProfiler() for _ in replicas]
-    for eng, prof in zip(replicas, profs):
-        eng.profiler = prof
     router = FleetRouter(replicas)
     try:
         handles = [router.submit(p, max_new_tokens=max_new_tokens,
@@ -385,17 +377,6 @@ def run_bench(n_requests: int = 8, max_new_tokens: int = 32,
         tenants = router.tenants_report()
     finally:
         router.close(timeout=60)
-    profile_rep = max((p.profile_report() for p in profs),
-                      key=lambda r: r["n_chunks"])
-    problems = validate_report(profile_rep)
-    if problems:
-        raise RuntimeError(
-            f"fleet profile report failed validation: {problems}")
-    if not profile_rep["attribution_ok"]:
-        raise RuntimeError(
-            "fleet chunk attribution does not sum to wall: "
-            f"{profile_rep['attribution_error_frac']:.3f} error fraction")
-    result["profile"] = profile_rep
     merged = tenants["tenants"]
     if not {"tenant-a", "tenant-b"} <= set(merged):
         raise RuntimeError(

@@ -56,7 +56,7 @@ def _fused_mixed_case(tpot_gate: float = 2.0, ttft_hold_s: float = 0.25,
     (prompt >> prefill chunk) arrive mid-stream. With bucketed prefill
     every long-prompt admission launches a separate wide prefill program
     that preempts the next decode chunk — the in-flight decoders' inter-
-    token gaps spike (``prefill.stall_s`` > 0, p99 TPOT blows up). With
+    token gaps spike (``serve/prefill_wait`` > 0, p99 TPOT blows up). With
     ``fused_prefill=True`` the same prompts are consumed as in-scan
     chunks under the chunk token budget, so decode lanes keep emitting
     every scan step and the stall never exists.
@@ -65,17 +65,17 @@ def _fused_mixed_case(tpot_gate: float = 2.0, ttft_hold_s: float = 0.25,
       * greedy token streams bit-identical between the two modes;
       * fused p99 TPOT over the short (interactive) class is at least
         ``tpot_gate``x better than bucketed;
-      * the fused profile attributes zero ``prefill.stall_s`` while the
-        bucketed reference attributes a strictly positive stall (the
-        contrast the regression specs pin);
+      * the fused drive records no ``serve/prefill_wait`` span while the
+        bucketed reference waits a strictly positive time in them;
       * fused short-class TTFT p99 stays under ``ttft_hold_s`` — the
         chunked prompt path must not starve time-to-first-token.
     """
     import jax.numpy as jnp
     import deepspeed_tpu as ds
+    from .. import telemetry
     from ..serving import ServingEngine
     from ..serving.scheduler import Request
-    from ..telemetry.profiler import ChunkProfiler
+    from ..telemetry.summary import phase_breakdown
 
     # Geometry locked by CPU A/B prototyping: the fused chunk cost is
     # invariant to prefill load while the bucketed stall scales with the
@@ -149,9 +149,15 @@ def _fused_mixed_case(tpot_gate: float = 2.0, ttft_hold_s: float = 0.25,
         serving.run(warm, max_new_tokens=4)
         serving.run(warm, max_new_tokens=4)
         drive(serving)        # absorb the drive-pattern arena retraces
-        prof = ChunkProfiler()
-        serving.profiler = prof
+        rt = telemetry.get_runtime()
+        stats_before = rt.span_stats()
+        inline_before = serving.inline_prefill_tokens
         reqs, deliveries = drive(serving)
+        # the measured drive's host wait on prefill programs, and the
+        # prompt tokens it consumed in-scan
+        stall_s = phase_breakdown(stats_before, rt.span_stats()).get(
+            "serve/prefill_wait", {}).get("total_s", 0.0)
+        inline = serving.inline_prefill_tokens - inline_before
         # TPOT over the interactive class: gaps between consecutive
         # token deliveries of each short request
         gaps = []
@@ -161,13 +167,12 @@ def _fused_mixed_case(tpot_gate: float = 2.0, ttft_hold_s: float = 0.25,
             dl = deliveries[r.uid]
             for (t0, n0), (t1, n1) in zip(dl, dl[1:]):
                 gaps.append((t1 - t0) / max(1, n1 - n0))
-        rep = prof.profile_report()
         ttft = {kind: [r.ttft_s for r, k in reqs if k == kind]
                 for kind in ("short", "long")}
-        return reqs, gaps, rep, ttft
+        return reqs, gaps, stall_s, inline, ttft
 
-    b_reqs, b_gaps, b_rep, b_ttft = run_side(fused=False)
-    f_reqs, f_gaps, f_rep, f_ttft = run_side(fused=True)
+    b_reqs, b_gaps, bucketed_stall, _, b_ttft = run_side(fused=False)
+    f_reqs, f_gaps, fused_stall, f_inline, f_ttft = run_side(fused=True)
 
     for (rb, _), (rf, _) in zip(b_reqs, f_reqs):
         if not np.array_equal(rb.output_ids, rf.output_ids):
@@ -181,17 +186,15 @@ def _fused_mixed_case(tpot_gate: float = 2.0, ttft_hold_s: float = 0.25,
             f"fused p99 TPOT improvement {improvement:.2f}x under the "
             f"mixed long-prompt workload is below the {tpot_gate}x gate "
             f"(bucketed {p99_b * 1e3:.2f}ms, fused {p99_f * 1e3:.2f}ms)")
-    fused_stall = f_rep["prefill"]["stall_s"]
-    bucketed_stall = b_rep["prefill"]["stall_s"]
     if fused_stall > 1e-6:
         raise RuntimeError(
-            f"fused profile attributed prefill stall {fused_stall:.4f}s "
+            f"fused drive waited {fused_stall:.4f}s on prefill programs "
             "— in-scan prompt chunks must never preempt decode launches")
     if bucketed_stall <= 0.0:
         raise RuntimeError(
-            "bucketed reference attributed no prefill stall — the mixed "
+            "bucketed reference never waited on a prefill — the mixed "
             "workload lost the contrast this case exists to measure")
-    if f_rep["prefill"]["inline_tokens"] <= 0:
+    if f_inline <= 0:
         raise RuntimeError("fused run consumed no in-scan prompt tokens")
     f_short_ttft = _percentile(f_ttft["short"], 99)
     b_short_ttft = _percentile(b_ttft["short"], 99)
@@ -223,11 +226,8 @@ def _fused_mixed_case(tpot_gate: float = 2.0, ttft_hold_s: float = 0.25,
             "fused": round(_percentile(f_ttft["long"], 99), 4)},
         "ttft_p99_ratio": round(f_short_ttft / b_short_ttft, 3),
         "ttft_hold_s": ttft_hold_s,
-        "inline_prefill_tokens": int(f_rep["prefill"]["inline_tokens"]),
+        "inline_prefill_tokens": int(f_inline),
         "bucketed_stall_s": round(bucketed_stall, 4),
-        # the fused profiler report — regression specs pin
-        # profile.prefill.stall_s ~ 0 here
-        "profile": _round_tree(f_rep),
     }
 
 
@@ -246,7 +246,6 @@ def run_bench(n_requests: int = 48, overload_factor: float = 4.0,
     from .. import telemetry
     from ..telemetry.exposition import MetricsServer, parse_prometheus_text
     from ..telemetry.mfu import mfu_report
-    from ..telemetry.profiler import ChunkProfiler, validate_report
     from ..telemetry.slo import SLOEngine, default_slos
     from ..telemetry.summary import phase_breakdown
     from ..serving import ServingEngine
@@ -273,26 +272,10 @@ def run_bench(n_requests: int = 48, overload_factor: float = 4.0,
                               max_queue=max(len(prompts), 8))
     reference.run(list(prompts), max_new_tokens=max_new_tokens)  # warm
     reference.run(list(prompts), max_new_tokens=max_new_tokens)
-    # steady-state decode window: the tight pump loop with no frontend
-    # delivery machinery between chunks — this is where the <15% bubble
-    # budget must hold (the overload window legitimately idles between
-    # open-loop arrivals)
-    steady_prof = ChunkProfiler()
-    reference.profiler = steady_prof
     t0 = time.perf_counter()
     ref_results = reference.run(list(prompts),
                                 max_new_tokens=max_new_tokens)
     cal_dt = time.perf_counter() - t0
-    steady = steady_prof.profile_report()
-    if not steady["attribution_ok"]:
-        raise RuntimeError(
-            "steady-state chunk attribution does not sum to wall: "
-            f"{steady['attribution_error_frac']:.3f} error fraction")
-    steady_bubble = steady["bubble_fraction"]
-    if steady_bubble >= 0.15:
-        raise RuntimeError(
-            f"steady-state decode bubble fraction {steady_bubble:.3f} "
-            ">= 0.15 — the chunked loop is leaving the device idle")
     cal_tokens = sum(len(r.tokens) for r in ref_results)
     capacity_tps = cal_tokens / cal_dt
     capacity_rps = capacity_tps / max_new_tokens
@@ -313,11 +296,6 @@ def run_bench(n_requests: int = 48, overload_factor: float = 4.0,
     for k in range(1, max_batch + 1):
         fe_engine.run(list(prompts[:k]), max_new_tokens=max_new_tokens)
     fe_engine.run(list(prompts), max_new_tokens=max_new_tokens)
-    # chunk-timeline profiler: attached after warmup so compile time never
-    # pollutes the attribution; cleared at the overload boundary so the
-    # committed profile block covers exactly the overload window
-    profiler = ChunkProfiler()
-    fe_engine.profiler = profiler
     frontend = ServingFrontend(
         fe_engine,
         admission=AdmissionConfig(max_pending=n_requests + 8),
@@ -356,11 +334,6 @@ def run_bench(n_requests: int = 48, overload_factor: float = 4.0,
     parity = True
     # the parity pass also warmed the frontend's throughput estimator, so
     # the overload phase sheds against a measured rate from step one
-    parity_rep = profiler.profile_report()
-    if parity_rep["n_chunks"] and not parity_rep["attribution_ok"]:
-        raise RuntimeError(
-            "parity-window chunk attribution does not sum to wall: "
-            f"{parity_rep['attribution_error_frac']:.3f} error fraction")
 
     # ---- phase 3: open-loop overload with mixed priorities -------------
     # low-priority deadline: roughly the unloaded service time of a few
@@ -370,7 +343,6 @@ def run_bench(n_requests: int = 48, overload_factor: float = 4.0,
     n_high = 0
     load_handles = []
     stats_before = telemetry.get_runtime().span_stats()
-    profiler.clear()        # overload-phase-only attribution from here
     t_start = time.perf_counter()
     for i in range(n_requests):
         # open loop: the i-th arrival is scheduled at t_start + i*interval
@@ -509,27 +481,6 @@ def run_bench(n_requests: int = 48, overload_factor: float = 4.0,
         mfu["scan_body_counted_once"] = cost["scan_body_counted_once"]
     # HBM accounting: same after-the-audit placement as cost analysis
     hbm = fe_engine.estimate_hbm()
-    # overload-window chunk attribution. The mixed long-prompt arrival
-    # process admits prefills while decode batches are live, so the
-    # decode-behind-prefill stall (ROADMAP item 4) must show up here.
-    profile_rep = profiler.profile_report()
-    problems = validate_report(profile_rep)
-    if problems:
-        raise RuntimeError(f"profile report failed validation: {problems}")
-    if not profile_rep["attribution_ok"]:
-        raise RuntimeError(
-            "overload chunk attribution does not sum to wall: "
-            f"{profile_rep['attribution_error_frac']:.3f} error fraction")
-    if profile_rep["prefill"]["stall_s"] <= 0.0:
-        raise RuntimeError(
-            "no decode-blocking prefill stall was attributed under the "
-            "mixed overload workload — the stall accounting regressed")
-    profile_rep["steady_state"] = {
-        "bubble_fraction": round(steady_bubble, 4),
-        "attribution_ok": steady["attribution_ok"],
-        "n_chunks": steady["n_chunks"],
-    }
-    profile_rep["stalled_prefills_seen"] = 1.0
     if trace_out:
         # one Perfetto file: engine/driver thread lanes + per-request
         # frontend lanes with submit->finish flow arrows
@@ -601,9 +552,6 @@ def run_bench(n_requests: int = 48, overload_factor: float = 4.0,
         "hbm": _round_tree(hbm) if hbm else None,
         "metrics_scrape": metrics_scrape,
         "slo": slo_block,
-        # chunk-timeline attribution (overload window + steady-state
-        # summary); `bin/tputrace profile` consumes this block directly
-        "profile": _round_tree(profile_rep),
         # fused chunked prefill vs bucketed under mixed long prompts
         # (ROADMAP item 4 acceptance: p99 TPOT >= 2x, stall ~ 0)
         "fused_mixed": fused_block,

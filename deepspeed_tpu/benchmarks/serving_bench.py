@@ -1,21 +1,21 @@
-"""Serving benchmark: fused-chunk decode vs per-token loop (vs sequential).
+"""Serving benchmark: K-step decode chunks vs chunks of one (vs sequential).
 
 Measures aggregate decode throughput for N concurrent mixed-length
 requests served three ways over the SAME model and parameters:
 
   * sequential — N back-to-back ``InferenceEngine.generate`` calls (the
     pre-serving request-level path: one stream owns the chip at a time);
-  * per-token  — a ``ServingEngine`` with ``decode_chunk=1``: continuous
-    batching, but one device dispatch + one host sync per token;
+  * per-token  — a ``ServingEngine`` with ``decode_chunk=1``: the same
+    scan and the same double-buffered loop at a chunk of one step, so
+    one device dispatch + one host sync per token;
   * chunked    — the same engine config with ``decode_chunk=K`` (default
-    8): the device-resident ``lax.scan`` loop, one host sync per K
-    tokens, double-buffered chunk launches.
+    8): one host sync per K tokens.
 
 All sides run once untimed first (so every lazily-compiled program —
 prefill buckets included — is charged to warmup, not the clock), then
 once timed. Greedy decoding is asserted BIT-IDENTICAL between the
-per-token and chunked serving runs — the chunk loop is an execution
-strategy, not a model change. Serving metrics stream through the CSV
+per-token and chunked serving runs — K is an execution strategy, not a
+model change. Serving metrics stream through the CSV
 monitor writer during the run (tokens/s, TTFT, queue depth, occupancy,
 prefill padding waste), so the emitted files double as the smoke check
 that the monitor path works end to end.
@@ -205,11 +205,11 @@ def _speculative_case(engine, n_requests: int = 8, prompt_len: int = 16,
     """Speculative-decoding A/B on a REPETITIVE-TEXT workload (a short
     motif tiled through every prompt — the prompt-lookup drafter's home
     turf; greedy decode then continues the cycle, so drafts keep
-    matching). The baseline is the per-token loop (``decode_chunk=1``:
-    one host sync AND one target forward per token) — exactly the cost
+    matching). The baseline is the non-spec scan at ``decode_chunk=1``
+    (one host sync AND one target forward per token) — exactly the cost
     speculation amortizes, since one spec step scores k+1 positions in
     ONE forward and emits the whole accepted prefix per sync. Greedy
-    parity is asserted three ways: spec vs the per-token loop, vs the
+    parity is asserted three ways: spec vs that baseline, vs the
     non-spec K-step chunk loop, and (paged pool) vs the dense arena —
     all bit-identical, so speculation is an execution strategy, not a
     model change. The spec chunk programs carry their own pinned
@@ -410,13 +410,13 @@ def _fused_case(engine, prompts, max_new_tokens: int, max_batch: int,
       * greedy outputs bit-identical to the bucketed chunked engine;
       * the fused scan program's compile count matches its pinned budget
         (dense and, with ``--paged``, the paged fused variant);
-      * the profiled run attributes ZERO ``prefill.stall_s`` (there is
-        no prefill program to preempt decode) while consuming every
-        prompt token in-scan (``inline_tokens`` == sum of prompt lens).
+      * the timed pass records NO ``serve/prefill_wait`` span (there is
+        no prefill program to preempt decode) and every pass consumes
+        every prompt token in-scan (``inline_prefill_tokens`` == sum of
+        prompt lens).
     """
     from ..analysis import TraceAuditor
     from ..serving import ServingEngine
-    from ..telemetry.profiler import ChunkProfiler
 
     inline_expected = sum(len(p) for p in prompts)
 
@@ -435,43 +435,39 @@ def _fused_case(engine, prompts, max_new_tokens: int, max_batch: int,
                                   max_queue=max(len(prompts), 8),
                                   fused_prefill=True,
                                   prefill_chunk=prefill_chunk, **kw)
-            fz_results, fz_dt, fz_tokens, _ = _timed_serving_run(
+            fz_results, fz_dt, fz_tokens, fz_phases = _timed_serving_run(
                 fused, prompts, max_new_tokens)
-            # profiled pass INSIDE the audited region: attaching the
-            # profiler is host-side bookkeeping and must not retrace
-            prof = ChunkProfiler()
-            fused.profiler = prof
-            prof_results = fused.run(list(prompts),
-                                     max_new_tokens=max_new_tokens)
         compiles = auditor.compiles(variant)
         if compiles != budget:
             raise RuntimeError(
                 f"{variant} compiled {compiles}x, expected exactly "
                 f"{budget} — prompt-chunk state is leaking shape/type "
                 "variation into the fused scan program")
-        for res in (fz_results, prof_results):
-            if not all(np.array_equal(a.output_ids, b.output_ids)
-                       for a, b in zip(ck_results, res)):
-                raise RuntimeError(
-                    "greedy outputs diverged between bucketed prefill "
-                    f"and fused chunked prefill (paged={paged}) — the "
-                    "fused path must be bit-identical")
-        rep = prof.profile_report()
-        if rep["prefill"]["stall_s"] > 1e-6:
+        if not all(np.array_equal(a.output_ids, b.output_ids)
+                   for a, b in zip(ck_results, fz_results)):
             raise RuntimeError(
-                f"fused profile attributed {rep['prefill']['stall_s']}s "
-                "of prefill stall — fused mode has no prefill program "
-                "to preempt decode launches")
-        if rep["prefill"]["inline_tokens"] != inline_expected:
+                "greedy outputs diverged between bucketed prefill "
+                f"and fused chunked prefill (paged={paged}) — the "
+                "fused path must be bit-identical")
+        stall_s = fz_phases.get("serve/prefill_wait",
+                                {}).get("total_s", 0.0)
+        if stall_s > 1e-6:
             raise RuntimeError(
-                f"fused run consumed {rep['prefill']['inline_tokens']} "
-                f"prompt tokens in-scan, expected {inline_expected}")
-        return fz_dt, fz_tokens / fz_dt, compiles, budget, rep
+                f"fused run waited {stall_s}s on a prefill program — "
+                "fused mode has no prefill program to preempt decode "
+                "launches")
+        # _timed_serving_run makes three passes over the same prompts
+        if fused.inline_prefill_tokens != 3 * inline_expected:
+            raise RuntimeError(
+                f"fused engine consumed {fused.inline_prefill_tokens} "
+                f"prompt tokens in-scan over three passes, expected "
+                f"{3 * inline_expected}")
+        return fz_dt, fz_tokens / fz_dt, compiles, budget, stall_s
 
-    fz_dt, fz_tps, compiles, budget, rep = one_side(paged=False)
+    fz_dt, fz_tps, compiles, budget, stall_s = one_side(paged=False)
     paged_block = None
     if with_paged:
-        pg_dt, pg_tps, pg_compiles, pg_budget, pg_rep = one_side(
+        pg_dt, pg_tps, pg_compiles, pg_budget, pg_stall_s = one_side(
             paged=True)
         paged_block = {
             "greedy_parity": True,
@@ -479,7 +475,7 @@ def _fused_case(engine, prompts, max_new_tokens: int, max_batch: int,
             "fused_paged_tokens_per_s": round(pg_tps, 2),
             "decode_chunk_compiles": pg_compiles,
             "decode_chunk_budget": pg_budget,
-            "prefill_stall_s": round(pg_rep["prefill"]["stall_s"], 6),
+            "prefill_stall_s": round(pg_stall_s, 6),
         }
     return {
         "greedy_parity": True,
@@ -489,9 +485,8 @@ def _fused_case(engine, prompts, max_new_tokens: int, max_batch: int,
         "prefill_chunk": prefill_chunk,
         "decode_chunk_compiles": compiles,
         "decode_chunk_budget": budget,
-        "inline_prefill_tokens": int(rep["prefill"]["inline_tokens"]),
-        "prefill_stall_s": round(rep["prefill"]["stall_s"], 6),
-        "prefill_inline_s": round(rep["prefill"]["inline_s"], 6),
+        "inline_prefill_tokens": inline_expected,
+        "prefill_stall_s": round(stall_s, 6),
         "paged": paged_block,
     }
 
@@ -793,7 +788,9 @@ def run_bench(n_requests: int = 8, max_new_tokens: int = 32,
         seq_dt = time.perf_counter() - t0
         seq_tps = total_tokens / seq_dt
 
-    # ---- continuous batching, per-token loop (decode_chunk=1) ----------
+    # ---- continuous batching, a chunk of one step (decode_chunk=1) -----
+    # before the audited region: at K=1 this engine too compiles a
+    # program named decode_chunk_fn
     per_token = ServingEngine(engine=engine, max_batch=max_batch,
                               max_prompt_len=prompt_len, decode_chunk=1,
                               max_queue=max(n_requests, 8))

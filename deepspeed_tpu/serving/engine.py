@@ -17,9 +17,6 @@ into a DEVICE-PACED serve loop. The compiled model programs:
            covering the batch's longest prompt, n <= max_batch; compiled
            lazily per (n, P) pair so a burst of short prompts stops
            paying ``max_prompt_len`` of padded compute
-  decode   (params, arena, tok[B], pos[B], rng) -> (tok[B], arena)
-           the PR-1 per-token loop, kept behind ``decode_chunk=1`` as the
-           bit-parity reference
   decode_chunk
            (params, arena, tok[B], pos[B], act[B], eos[B], rem[B], rng)
            -> (toks[B, K], valid[B, K], arena, carry...)
@@ -29,7 +26,8 @@ into a DEVICE-PACED serve loop. The compiled model programs:
            their write index at ``max_seq_len`` (models/gpt.py drops the
            write) so a dead lane never dirties KV rows. The host syncs
            ONCE per chunk and hands the token buffer to the scheduler in
-           one ``step_tokens_chunk`` call.
+           one ``step_tokens_chunk`` call. K = 1 is a chunk of one step
+           through the same scan (one host sync per token).
 
 (plus the trivial non-model insert programs that move prefilled caches
 into arena slot rows). ``run()`` additionally double-buffers: the next
@@ -44,7 +42,6 @@ rivals the model's step time.
 from __future__ import annotations
 
 import dataclasses
-import os
 import time
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple, Union
 
@@ -96,47 +93,12 @@ class _InflightChunk:
     valid: Any           # [B, K] device (lane was live entering the step)
     state: Tuple         # (tok[B], pos[B], act[B], rem[B], eos[B]) device,
     #                      + hist[B, S] in speculative mode
-    # dispatch-complete stamp (profiler clock); 0.0 when no profiler is
-    # attached — the chunk timeline lane anchors device spans on it
-    launch_t: float = 0.0
     # unconditional perf_counter stamp at launch: the collective-overlap
     # gauge accumulates launch->retire wall seconds from it
     wall_t0: float = 0.0
     # the chunk's routing counters, summed on the device over its steps
     # (a model with expert layers; fetched with the tokens, no sync of its own)
     routing: Any = None
-
-
-def _load_tuned_config(tuned_config) -> Dict[str, Any]:
-    """Normalize a ``tuned_config=`` argument into a flat knob dict.
-
-    Accepts the serving capacity tuner's Pareto JSON document (a path
-    or the loaded dict — the best point's config is used), a bare
-    ``{"config": {...}}`` point, or a flat knob dict. ``block_size``
-    (the tuner's axis name) aliases ``kv_block_size``."""
-    import json
-    doc = tuned_config
-    if isinstance(doc, (str, os.PathLike)):
-        with open(doc) as f:
-            doc = json.load(f)
-    if not isinstance(doc, dict):
-        raise ValueError(f"tuned_config must be a dict or a JSON path, "
-                         f"got {type(tuned_config).__name__}")
-    schema = doc.get("schema")
-    if schema is not None and schema != "dstpu-tuned-v1":
-        raise ValueError(f"unsupported tuned_config schema {schema!r} "
-                         f"(want dstpu-tuned-v1)")
-    if "best" in doc:
-        doc = doc["best"]
-    elif "pareto" in doc:
-        pts = doc["pareto"]
-        if not pts:
-            raise ValueError("tuned_config has an empty Pareto frontier")
-        doc = max(pts, key=lambda p: p.get("tokens_per_s", 0.0))
-    cfg = dict(doc.get("config", doc))
-    if "block_size" in cfg and "kv_block_size" not in cfg:
-        cfg["kv_block_size"] = cfg.pop("block_size")
-    return cfg
 
 
 class ServingEngine:
@@ -152,10 +114,11 @@ class ServingEngine:
         results[0].output_ids      # prompt + generated tokens
 
     ``decode_chunk`` is the number of decode steps fused into one device
-    program invocation (K). ``decode_chunk=1`` is the PR-1 per-token loop
-    (one host sync per token); greedy outputs are bit-identical across
-    all K. Deadlines are only observed at chunk boundaries — a request
-    may overrun its deadline by up to K-1 tokens of device work.
+    program invocation (K). ``decode_chunk=1`` is a chunk of one step
+    through the same scan (one host sync per token); greedy outputs are
+    bit-identical across all K. Deadlines are only observed at chunk
+    boundaries — a request may overrun its deadline by up to K-1 tokens
+    of device work.
     """
 
     def __init__(self, model=None, model_parameters=None, *,
@@ -192,32 +155,9 @@ class ServingEngine:
                  tier_dram_bytes: int = 256 << 20,
                  tier_nvme_bytes: Optional[int] = None,
                  tier_spill_dir: Optional[str] = None,
-                 tuned_config=None,
                  **inference_kwargs):
         import jax
         import jax.numpy as jnp
-
-        # ---- autotuned defaults (autotuning/serving_tuner.py) ----
-        # A Pareto-frontier JSON (path or dict) supplies tuned values
-        # for the capacity knobs; an explicitly passed non-default
-        # argument always wins over the tuned value.
-        self.tuned_config = None
-        if tuned_config is not None:
-            tuned = _load_tuned_config(tuned_config)
-            self.tuned_config = tuned
-            _sig = {"decode_chunk": 8, "spec_k": 4, "kv_block_size": 16,
-                    "prefill_chunk": 16, "tier_dram_bytes": 256 << 20}
-            ns = locals()
-            # a null tuned value means "axis off" (e.g. the untiered
-            # Pareto corner's tier_dram_bytes) — keep the default
-            picked = {k: tuned[k] for k in _sig
-                      if tuned.get(k) is not None and ns[k] == _sig[k]}
-            decode_chunk = picked.get("decode_chunk", decode_chunk)
-            spec_k = picked.get("spec_k", spec_k)
-            kv_block_size = picked.get("kv_block_size", kv_block_size)
-            prefill_chunk = picked.get("prefill_chunk", prefill_chunk)
-            tier_dram_bytes = picked.get("tier_dram_bytes",
-                                         tier_dram_bytes)
 
         if engine is None:
             from ..inference.engine import InferenceEngine
@@ -352,11 +292,6 @@ class ServingEngine:
         else:
             self.drafter = None
             self.spec_k = 0
-        # speculative decode always runs the chunked scan program (the
-        # verify forward is a multi-token apply; K=1 is a length-1 scan);
-        # fused prefill lives inside that scan, so it forces it too
-        self._chunked = (self.decode_chunk > 1 or self.speculative
-                         or self.fused_prefill)
 
         self.paged = bool(paged)
         if self.megakernel:
@@ -497,12 +432,6 @@ class ServingEngine:
         # the owning ServingFrontend; engine-side records are host-only
         # deque appends — no device work, no retrace surface
         self.flight = None
-        # chunk-timeline profiler (telemetry.profiler.ChunkProfiler),
-        # attached externally the same way; every hook site is guarded by
-        # a None check so the detached cost is one attribute load, and
-        # the hooks themselves are perf_counter stamps + deque appends —
-        # no device work, no retrace surface
-        self.profiler = None
 
         mat = engine._materialize
         module = self.module
@@ -577,22 +506,6 @@ class ServingEngine:
             last = jnp.take_along_axis(
                 logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]
             tok = sample_(last, rng, temperature_, top_k_, top_p_)
-            return tok, vc["cache"]
-
-        def decode(params, cache, tokens, positions, rng):
-            pm = mat(params)
-            # pin the write cursor exactly like the chunk program: idle
-            # lanes carry the max_seq sentinel (positions from
-            # _decode_once), so a paged lane's stale block table can never
-            # route a speculative write into a re-leased block
-            cache = _with_write_index(cache, positions)
-            logits, vc = module.apply(
-                {"params": pm, "cache": cache}, tokens[:, None],
-                positions=positions[:, None], mutable=["cache"])
-            if isinstance(logits, tuple):
-                logits = logits[0]
-            tok = sample_(logits[:, -1], rng, temperature_, top_k_,
-                          top_p_)
             return tok, vc["cache"]
 
         def _with_write_index(cache, write_pos):
@@ -912,14 +825,6 @@ class ServingEngine:
             self._jit_prefill_sp = jax.jit(prefill_sp)
         else:
             self._jit_prefill_sp = None
-        # donate the arena: every slot's KV rows are updated in place. The
-        # donation alone does not do that — the model does: a cache that is
-        # passed in is CARRIED by its layer loop (models/gpt.py), each layer
-        # scatters its tokens into the stacked leaves at (layer, lane, pos),
-        # and the chunk's scan carries the same buffers from step to step,
-        # so the programs below alias the arena and hold no copy of it
-        # (tests/test_decode_arena_in_place.py)
-        self._jit_decode = jax.jit(decode, donate_argnums=(1,))
         # distinct function name => distinct TraceAuditor budget: every
         # fused / spec / int8 / paged combination is a different compiled
         # program family whose retrace count is pinned separately
@@ -951,6 +856,13 @@ class ServingEngine:
             chunk_fn = (decode_chunk_spec_fn if self.speculative
                         else decode_chunk_fn)
         chunk_fn.__name__ = variant
+        # donate the arena: every slot's KV rows are updated in place. The
+        # donation alone does not do that — the model does: a cache that is
+        # passed in is CARRIED by its layer loop (models/gpt.py), each layer
+        # scatters its tokens into the stacked leaves at (layer, lane, pos),
+        # and the chunk's scan carries the same buffers from step to step,
+        # so the chunk program aliases the arena and holds no copy of it
+        # (tests/test_decode_arena_in_place.py)
         self._jit_decode_chunk = jax.jit(chunk_fn, donate_argnums=(1,))
 
         # the host's corrections to the lane state a chunk carries
@@ -1213,22 +1125,21 @@ class ServingEngine:
         req.tokens = tokens
         self.scheduler.running[slot] = req
         self._last_token[slot] = tokens[-1]
-        if self._chunked:
-            if self.fused_prefill:
-                self._clear_pf_slot(slot)
-            rem = min(max_new - len(tokens),
-                      self.kv.allocator.remaining(slot))
-            eos = -1 if req.eos_token_id is None else int(req.eos_token_id)
-            # admit-style patch, but the lane resumes at the migrated
-            # cursor (pos = fill, not prompt_len): the carried last
-            # token's KV row is written by the lane's first step here
-            patch = (tokens[-1], fill, rem, eos)
-            if self.fused_prefill:
-                patch = patch + (0,)        # pf_rem: fully prefilled
-            if self.speculative:
-                patch = patch + (self._history_row(req),)
-            self._admit_patches[slot] = patch
-            self._deact_slots.discard(slot)
+        if self.fused_prefill:
+            self._clear_pf_slot(slot)
+        rem = min(max_new - len(tokens),
+                  self.kv.allocator.remaining(slot))
+        eos = -1 if req.eos_token_id is None else int(req.eos_token_id)
+        # admit-style patch, but the lane resumes at the migrated
+        # cursor (pos = fill, not prompt_len): the carried last
+        # token's KV row is written by the lane's first step here
+        patch = (tokens[-1], fill, rem, eos)
+        if self.fused_prefill:
+            patch = patch + (0,)        # pf_rem: fully prefilled
+        if self.speculative:
+            patch = patch + (self._history_row(req),)
+        self._admit_patches[slot] = patch
+        self._deact_slots.discard(slot)
         telemetry.instant("serve/migrate_import", uid=req.uid,
                           slot=slot, fill=fill,
                           n_blocks=int(bundle["n_blocks"]))
@@ -1249,10 +1160,7 @@ class ServingEngine:
         last call returned with nothing in flight to drain completely."""
         before = len(self.scheduler.finished)
         with telemetry.span("serve/pump"):
-            if not self._chunked:
-                self._admit()
-                self._decode_once()
-            elif self._pending is None:
+            if self._pending is None:
                 self._admit()
                 if self.scheduler.running:
                     self._pending = self._launch_chunk(self._host_state())
@@ -1278,15 +1186,12 @@ class ServingEngine:
     def step(self) -> List[Request]:
         """One synchronous continuous-batching iteration: admit
         newly-runnable requests into free slots (bucketed batched prefill
-        + arena insert), then one decode invocation over all live slots —
-        a single fused step when ``decode_chunk == 1``, a K-step
-        device-resident chunk otherwise. Returns requests finished this
-        iteration."""
+        + arena insert), then one K-step device-resident decode chunk
+        over all live slots, launched and consumed. Returns requests
+        finished this iteration."""
         before = len(self.scheduler.finished)
         self._admit()
-        if not self._chunked:
-            self._decode_once()
-        elif self.scheduler.running:
+        if self.scheduler.running:
             self._consume_chunk(self._launch_chunk(self._host_state()),
                                 device_queue_empty=True)
         self._drop_starved_if_idle()
@@ -1296,18 +1201,14 @@ class ServingEngine:
             **request_kwargs) -> List[Request]:
         """Serve until drained. ``prompts``: token-id sequences (or Request
         objects) submitted up front; per-request kwargs (max_new_tokens,
-        eos_token_id, deadline_s) apply to all of them. With
-        ``decode_chunk > 1`` the loop is double-buffered: the next chunk
-        is enqueued from device-resident carry state before the previous
-        chunk's token buffer is synced. Returns the submitted requests in
-        submission order (rejected ones included, flagged by status)."""
+        eos_token_id, deadline_s) apply to all of them. The loop is
+        double-buffered: the next chunk is enqueued from device-resident
+        carry state before the previous chunk's token buffer is synced.
+        Returns the submitted requests in submission order (rejected ones
+        included, flagged by status)."""
         submitted = [self.submit(p, **request_kwargs)
                      for p in (prompts or [])]
-        if not self._chunked:
-            while self.scheduler.has_work():
-                self.step()
-        else:
-            self._serve_pipelined()
+        self._serve_pipelined()
         self.metrics.maybe_emit(self.scheduler.queue_depth,
                                 self.kv.occupancy, force=True)
         return submitted
@@ -1395,12 +1296,8 @@ class ServingEngine:
         params = jax.tree.map(abst, self.engine.params)
         cache = jax.tree.map(abst, self.kv.cache)
         rng = abst(self._rng)
-        if self._chunked:
-            decode = _mem.compiled_memory_analysis(
-                self._jit_decode_chunk, *self._abstract_chunk_args())
-        else:
-            decode = _mem.compiled_memory_analysis(
-                self._jit_decode, params, cache, i32, i32, rng)
+        decode = _mem.compiled_memory_analysis(
+            self._jit_decode_chunk, *self._abstract_chunk_args())
         if decode is None:
             return None
         top = self._buckets[-1]
@@ -1524,8 +1421,7 @@ class ServingEngine:
         self._last_token[req.slot] = first
         self.metrics.on_tokens(1)
         self.scheduler.record_first_token(req, first)
-        if self._chunked:
-            self._record_admit_patch(req)
+        self._record_admit_patch(req)
 
     def _budget_drain(self) -> int:
         """Tokens the RUNNING lanes consume per fused scan step: one
@@ -1664,11 +1560,6 @@ class ServingEngine:
         (the dense path verbatim; paged misses ride it too, with the
         block-scatter insert and a prefix-cache commit per request)."""
         import jax.numpy as jnp
-        prof = self.profiler
-        # decode slots live beyond this admission batch: every prefill
-        # below pushes their next chunk launch out — the ROADMAP item-4
-        # stall the profiler accounts as prefill_stall_s
-        n_decoding = len(self.scheduler.running) - len(admitted)
         groups: Dict[Tuple[int, bool], List[Request]] = {}
         for req in admitted:
             use_sp = (self._jit_prefill_sp is not None
@@ -1695,7 +1586,6 @@ class ServingEngine:
             # and serve/prefill_wait, the np.asarray(toks) sync alone,
             # waits for all of that. The device's own time is in the
             # profiler's trace, under the programs' names
-            pt0 = prof.clock() if prof is not None else 0.0
             with telemetry.span("serve/prefill", n=n, bucket=bucket,
                                 sp=use_sp,
                                 uids=str([r.uid for r in reqs])):
@@ -1718,9 +1608,6 @@ class ServingEngine:
                     self._count_routing("prefill", routing)
                 # everything dispatched before that sync has run
                 self._starve("serve/starved_after_prefill")
-            if prof is not None:
-                prof.on_prefill(pt0, prof.clock(), n=n, bucket=bucket,
-                                stalled=n_decoding > 0)
             telemetry.count("serve/prefill_tokens", float(lens.sum()))
             if use_sp:
                 # long prompts routed over the sp mesh axis (Ulysses)
@@ -1749,8 +1636,7 @@ class ServingEngine:
                 # may retire the request immediately (max_new_tokens == 1
                 # or an instant EOS) — its slot frees before any decode
                 self.scheduler.record_first_token(r, first)
-                if self._chunked:
-                    self._record_admit_patch(r)
+                self._record_admit_patch(r)
 
     def _handoff(self, cache, reqs: List[Request], bucket: int):
         """Disaggregation: the finished prompt KV leaves the prefill
@@ -1801,46 +1687,7 @@ class ServingEngine:
             self._admit_patches.pop(slot, None)
             self._deact_slots.add(slot)
 
-    # ------------------------------------------------- per-token (K == 1)
-    def _decode_once(self) -> None:
-        import jax.numpy as jnp
-        running = self.scheduler.running
-        if not running:
-            return
-        slots = sorted(running)
-        tokens = np.zeros(self.max_batch, np.int32)
-        # paged: idle lanes pin the max_seq sentinel so their speculative
-        # writes DROP — a stale block-table row may point at a block
-        # already re-leased to another slot, so a dense-style position-0
-        # write would corrupt a live request (the dense arena tolerates
-        # it: each slot owns its row, and fill masks the stale entry)
-        positions = np.full(self.max_batch, self.max_seq_len, np.int32) \
-            if self.paged else np.zeros(self.max_batch, np.int32)
-        for s in slots:
-            tokens[s] = self._last_token[s]
-            positions[s] = self.kv.fill[s]
-        # np.asarray(tok) is the per-token host sync — the span covers
-        # dispatch + device step (the K=1 reference path's whole cost)
-        self._device_fed()
-        with telemetry.span("serve/decode_step", n=len(slots)):
-            tok, new_cache = self._jit_decode(
-                self._decode_params, self.kv.cache, jnp.asarray(tokens),
-                jnp.asarray(positions), self._next_rng())
-            self.kv.update(new_cache)
-            self.kv.allocator.advance(slots)
-            tok_host = np.asarray(tok)
-        self._starve("serve/starved_after_chunk")
-        for s in slots:
-            self._last_token[s] = int(tok_host[s])
-        finished = self.scheduler.step_tokens(
-            {s: int(tok_host[s]) for s in slots})
-        self.metrics.on_tokens(len(slots))
-        self.metrics.on_decode_step()
-        self.metrics.on_finished(finished)
-        self.metrics.maybe_emit(self.scheduler.queue_depth,
-                                self.kv.occupancy)
-
-    # --------------------------------------------- fused chunks (K > 1)
+    # ------------------------------------------------------ decode chunks
     def _host_state(self) -> Tuple:
         """Full chunk-input state vectors rebuilt from scheduler/allocator
         mirrors (authoritative — any pending patches are subsumed)."""
@@ -1971,8 +1818,6 @@ class ServingEngine:
         """Enqueue one K-step decode chunk (returns immediately — JAX
         async dispatch; nothing here blocks on device results)."""
         import jax.numpy as jnp
-        prof = self.profiler
-        t0 = prof.clock() if prof is not None else 0.0
         self._device_fed()
         # dispatch-only span BY DESIGN (no sync=): the chunk is meant to
         # run asynchronously; the honest device wait is measured at
@@ -2023,10 +1868,6 @@ class ServingEngine:
             slot_uids={s: r.uid for s, r in self.scheduler.running.items()},
             tokens=toks, valid=valid, state=carry,
             wall_t0=time.perf_counter(), routing=routing)
-        if prof is not None:
-            t1 = prof.clock()
-            inflight.launch_t = t1
-            prof.on_launch(t0, t1, n_slots=len(inflight.slot_uids))
         if self.flight is not None:
             self.flight.record("chunk_launch", k=self.decode_chunk,
                                slot_uids=dict(inflight.slot_uids))
@@ -2038,15 +1879,12 @@ class ServingEngine:
         steps) and feed it through the scheduler. ``device_queue_empty``:
         no chunk was launched ahead of this sync, so when it returns the
         chip has nothing to run."""
-        prof = self.profiler
-        hw0 = prof.clock() if prof is not None else 0.0
         with telemetry.span("serve/chunk_host_wait"):
             toks = np.asarray(chunk.tokens)
             valid = np.asarray(chunk.valid)
             self._count_routing("decode", chunk.routing)
         if device_queue_empty:
             self._starve("serve/starved_after_chunk")
-        rt0 = prof.clock() if prof is not None else 0.0
         if self._overlap_active and chunk.wall_t0:
             # cumulative wall seconds of decode chunks served with the
             # RS/AG collective/MLP overlap decomposition active
@@ -2102,16 +1940,13 @@ class ServingEngine:
                     self._last_token[slot] = seq[-1]
             self.scheduler.step_tokens_chunk(per_slot)
             finished = self.scheduler.finished[fin_before:]
-        rt1 = prof.clock() if prof is not None else 0.0
         n_tokens = sum(len(v) for v in per_slot.values())
-        proposed = accepted = 0
         if self.flight is not None:
             self.flight.record("chunk_retire", n_tokens=n_tokens,
                                finished=[r.uid for r in finished],
                                queue_depth=self.scheduler.queue_depth,
                                occupancy=float(self.kv.occupancy))
         telemetry.count("serve/decode_tokens", float(n_tokens))
-        decode_iters = n_tokens      # 1 token per live decode step
         if inline_tokens:
             telemetry.count("serve/prefill_inline_tokens",
                             float(inline_tokens))
@@ -2133,8 +1968,7 @@ class ServingEngine:
             live_steps = v3[:, :, 0]
             if pf_steps is not None:
                 live_steps = live_steps & ~pf_steps
-            decode_iters = int(live_steps.sum())
-            proposed = decode_iters * self.spec_k
+            proposed = int(live_steps.sum()) * self.spec_k
             accepted = int(np.maximum(
                 np.where(live_steps, v3.sum(axis=2), 0) - live_steps,
                 0).sum())
@@ -2154,27 +1988,6 @@ class ServingEngine:
             telemetry.gauge("serve/arena_headroom_bytes",
                             float(self.kv.allocator.n_free
                                   * self._arena_bytes_per_slot))
-        if prof is not None:
-            if self.fused_prefill:
-                pf_total = int(pf_steps.sum()) if pf_steps is not None \
-                    else 0
-                prof.on_chunk(
-                    launch_t=chunk.launch_t, hw0=hw0,
-                    hw1=rt0, rt0=rt0, rt1=rt1,
-                    n_tokens=n_tokens,
-                    occupancy=float(self.kv.occupancy),
-                    proposed=proposed, accepted=accepted,
-                    inline_pf_tokens=inline_tokens,
-                    # every fused scan iteration is the same C-wide
-                    # compute: split the device span by step count
-                    inline_pf_frac=pf_total / max(
-                        pf_total + decode_iters, 1))
-            else:
-                prof.on_chunk(launch_t=chunk.launch_t, hw0=hw0,
-                              hw1=rt0, rt0=rt0, rt1=rt1,
-                              n_tokens=n_tokens,
-                              occupancy=float(self.kv.occupancy),
-                              proposed=proposed, accepted=accepted)
         self.metrics.on_tokens(n_tokens)
         self.metrics.on_decode_step()
         self.metrics.on_finished(finished)

@@ -13,9 +13,8 @@ Host-side only — no JAX. The engine (serving/engine.py) drives it:
     while scheduler.has_work():
         for req in scheduler.admit():        # prefill + slot insert
             ...; scheduler.record_first_token(req, tok)
-        finished = scheduler.step_tokens({slot: tok, ...})      # K=1 loop
         finished = scheduler.step_tokens_chunk({slot: [t0, t1, ...], ...})
-        # fused K-step loop: one host sync per chunk, same semantics
+        # one host sync per K-step chunk; K = 1 is a list of one token
 
 Backpressure: the queue is bounded; ``submit`` rejects with a reason
 (``queue_full`` / ``prompt_too_long``) instead of buffering unboundedly —
@@ -205,30 +204,19 @@ class ContinuousBatchScheduler:
         req.first_token_t = self.clock()
         self._append(req, token)
 
-    def step_tokens(self, tokens_by_slot: Dict[int, int]) -> List[Request]:
-        """Apply one decode iteration's sampled token per slot; returns the
-        requests that finished this step (their slots are already free for
-        the next admission pass)."""
-        before = len(self.finished)
-        for slot, token in tokens_by_slot.items():
-            req = self.running.get(slot)
-            if req is None:
-                raise KeyError(f"no running request in slot {slot}")
-            self._append(req, token)
-        return self.finished[before:]
-
     def step_tokens_chunk(self, tokens_by_slot: Dict[int, List[int]]
                           ) -> List[Request]:
         """Apply one fused multi-step decode chunk: a SEQUENCE of sampled
         tokens per slot (serving/engine.py's device-resident K-step loop
         syncs once per chunk and hands the whole token buffer here).
-        Per-token semantics are identical to K ``step_tokens`` calls for
-        that slot: the allocator fill advances one row per consumed token
-        (so the cache-row safety net in ``_append`` sees the same
-        remaining count the per-token loop would), and consumption stops
+        Per-token semantics are identical to K calls with one token each
+        for that slot: the allocator fill advances one row per consumed
+        token (so the cache-row safety net in ``_append`` sees the same
+        remaining count every time), and consumption stops
         at the request's own termination — trailing tokens a speculative
         chunk produced past EOS/budget/deadline are dropped, never
-        appended. Returns the requests finished within this chunk."""
+        appended. Returns the requests finished within this chunk (their
+        slots are already free for the next admission pass)."""
         before = len(self.finished)
         for slot, tokens in tokens_by_slot.items():
             req = self.running.get(slot)

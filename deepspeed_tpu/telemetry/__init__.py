@@ -64,8 +64,6 @@ from .fleetobs import (FleetMetricsAggregator,  # noqa: F401
 from .slo import SLOEngine, SLOSpec, default_slos  # noqa: F401
 from .flight_recorder import (FlightRecorder, dump_all,  # noqa: F401
                               install_sigterm_handler)
-from .profiler import (PID_DEVICE, ChunkProfiler,  # noqa: F401
-                       validate_report)
 from .anomaly import (AnomalyDetector, AnomalySpec,  # noqa: F401
                       default_specs)
 
@@ -85,6 +83,5 @@ __all__ = [
     "FleetMetricsAggregator", "ScrapeTarget",
     "SLOSpec", "SLOEngine", "default_slos",
     "FlightRecorder", "install_sigterm_handler", "dump_all",
-    "PID_DEVICE", "ChunkProfiler", "validate_report",
     "AnomalySpec", "AnomalyDetector", "default_specs",
 ]

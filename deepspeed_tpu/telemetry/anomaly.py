@@ -3,7 +3,7 @@
 ``bin/benchdiff`` catches regressions offline, between runs; nothing
 watches *live* traffic for the slow drifts that precede an incident —
 TPOT creeping up, speculative acceptance sagging, the prefix cache
-going cold, the decode pipeline hollowing out into bubbles.
+going cold.
 :class:`AnomalyDetector` closes that gap with the classic streaming
 recipe:
 
@@ -23,9 +23,9 @@ The detector-level healthy→tripped transition fires a one-shot
 once per flip, mirroring the watchdog's unhealthy-flip debounce — and
 ``HealthMonitor`` can opt in so a trip degrades ``/readyz`` until the
 metric re-arms. Feed it from a ``TraceLog`` (:meth:`attach` folds TPOT
-per finished request) and poll :meth:`observe_profile` /
-:meth:`observe` for engine-side vitals (bubble fraction, spec
-acceptance, prefix-cache hit rate).
+per finished request) and poll :meth:`observe` for engine-side vitals
+(spec acceptance from ``ServingMetrics.spec_acceptance_rate``,
+prefix-cache hit rate).
 
 Stdlib-only; safe to import without JAX.
 """
@@ -70,13 +70,12 @@ class AnomalySpec:
 
 
 def default_specs() -> List[AnomalySpec]:
-    """The serving tier's stock watchlist: the four vitals whose drift
+    """The serving tier's stock watchlist: the three vitals whose drift
     most reliably precedes an SLO breach."""
     return [
         AnomalySpec("tpot_s", direction="higher_is_bad"),
         AnomalySpec("spec_acceptance", direction="lower_is_bad"),
         AnomalySpec("prefix_hit_rate", direction="lower_is_bad"),
-        AnomalySpec("bubble_fraction", direction="higher_is_bad"),
     ]
 
 
@@ -238,14 +237,6 @@ class AnomalyDetector:
         so ``AnomalyDetector().attach(log)`` chains."""
         tracelog.add_listener(self.observe_trace)
         return self
-
-    def observe_profile(self, report: Dict[str, Any]) -> bool:
-        """Fold engine vitals out of a ``ChunkProfiler``
-        ``profile_report()`` (bubble fraction + spec acceptance)."""
-        self.observe("bubble_fraction", report.get("bubble_fraction"))
-        goodput = report.get("goodput") or {}
-        return self.observe("spec_acceptance",
-                            goodput.get("spec_acceptance"))
 
     # --------------------------------------------------------- inspection
     @property

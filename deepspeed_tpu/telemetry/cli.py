@@ -15,15 +15,6 @@ Subcommands::
                                               --validate gates each
                                               journey's connectedness
                                               (exit 1 on problems)
-    tputrace profile <report.json>            chunk-timeline profiler
-                                              report (bubble/stall
-                                              breakdown + per-tenant
-                                              goodput table) from a
-                                              bench JSON or a bare
-                                              ``profile_report()`` dump;
-                                              --validate gates
-                                              attribution sums ~= wall
-                                              (exit 1 on problems)
 
 Stdlib-only on purpose: like ``bin/tracelint``, the launcher installs a
 synthetic parent package so this file imports in milliseconds without
@@ -40,7 +31,6 @@ from typing import Any, Dict, List, Tuple
 from .export import chrome_trace, request_trace_events
 from .journey import PID_JOURNEYS, summarize_journeys, validate_journeys
 from .memory import format_bytes
-from .profiler import COMPONENTS, validate_report
 
 _NUMBER = (int, float)
 
@@ -270,70 +260,6 @@ def cmd_journey(args) -> int:
     return rc
 
 
-# ---------------------------------------------------------------- profile
-
-def cmd_profile(args) -> int:
-    try:
-        obj = _load(args.report)
-    except (OSError, ValueError) as exc:
-        print(f"tputrace: cannot read {args.report}: {exc}",
-              file=sys.stderr)
-        return 1
-    # accept either a bench result JSON (profile/tenant_goodput blocks)
-    # or a bare ChunkProfiler.profile_report() dump
-    report = obj.get("profile", obj) if isinstance(obj, dict) else None
-    tenants = obj.get("tenant_goodput") if isinstance(obj, dict) else None
-    if not isinstance(report, dict) or "components" not in report:
-        print(f"tputrace: {args.report}: no profiler report found "
-              "(expected a 'profile' block or a profile_report() dump)",
-              file=sys.stderr)
-        return 1
-    wall = float(report.get("wall_s") or 0.0)
-    print(f"{args.report}: {report.get('n_chunks', 0)} chunks, "
-          f"{report.get('n_tokens', 0)} tokens over {wall * 1e3:.1f} ms")
-    comps = report.get("components", {})
-    fracs = report.get("fractions", {})
-    print("\nchunk time attribution:")
-    for key in COMPONENTS:
-        label = key[:-2]  # strip _s
-        frac = fracs.get(label, 0.0) or 0.0
-        print(f"  {label:<16} {float(comps.get(key, 0.0)) * 1e3:>10.2f} ms"
-              f"  {frac:>6.1%}")
-    print(f"  {'bubble_fraction':<16} "
-          f"{report.get('bubble_fraction', 0.0):>17.3f} (rolling)")
-    pf = report.get("prefill") or {}
-    print(f"\nprefill: {pf.get('n', 0)} windows, "
-          f"{float(pf.get('total_s', 0.0)) * 1e3:.2f} ms total; "
-          f"stall {float(pf.get('stall_s', 0.0)) * 1e3:.2f} ms over "
-          f"{pf.get('n_stalled', 0)} stalled windows")
-    occ = report.get("occupancy") or {}
-    gp = report.get("goodput") or {}
-    print(f"occupancy: mean {occ.get('mean', 0.0):.2f}  "
-          f"p50 {occ.get('p50', 0.0):.2f}  p95 {occ.get('p95', 0.0):.2f}")
-    acc = gp.get("spec_acceptance")
-    print(f"goodput: {gp.get('tokens_per_chunk', 0.0):.2f} tokens/chunk"
-          + (f", spec acceptance {acc:.1%}" if acc is not None else ""))
-    if isinstance(tenants, dict) and tenants.get("tenants"):
-        print(f"\nper-tenant goodput ({tenants.get('n_tenants', 0)} "
-              "tenants):")
-        print(f"  {'tenant':<16} {'requests':>8} {'tokens':>8} "
-              f"{'goodput':>8} {'ttft p95':>9} {'tpot p95':>9}")
-        for name, t in sorted(tenants["tenants"].items()):
-            print(f"  {name:<16} {t.get('n_requests', 0):>8} "
-                  f"{t.get('total_tokens', 0):>8} "
-                  f"{t.get('goodput_fraction', 0.0):>8.1%} "
-                  f"{(t.get('ttft_s') or {}).get('p95', 0.0):>8.3f}s "
-                  f"{(t.get('tpot_s') or {}).get('p95', 0.0):>8.3f}s")
-    if args.validate:
-        problems = validate_report(report)
-        for p in problems:
-            print(f"FAIL: {p}", file=sys.stderr)
-        if problems:
-            return 1
-        print("attribution OK: components sum to wall within 5%")
-    return 0
-
-
 # ---------------------------------------------------------------- convert
 
 def cmd_convert(args) -> int:
@@ -379,16 +305,6 @@ def main(argv=None) -> int:
                         "links on hierarchy traces (exit 1 on problems)")
     p.add_argument("--pid", type=int, default=PID_JOURNEYS)
     p.set_defaults(fn=cmd_journey)
-    p = sub.add_parser("profile",
-                       help="chunk-timeline profiler report + per-tenant "
-                            "goodput table")
-    p.add_argument("report",
-                   help="bench result JSON (profile block) or a bare "
-                        "profile_report() dump")
-    p.add_argument("--validate", action="store_true",
-                   help="gate attribution sums ~= wall time "
-                        "(exit 1 on problems)")
-    p.set_defaults(fn=cmd_profile)
     args = ap.parse_args(argv)
     return args.fn(args)
 

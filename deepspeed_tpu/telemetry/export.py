@@ -31,8 +31,7 @@ from typing import Any, Dict, Iterable, List, Optional
 
 _US = 1e6
 
-#: pid lanes in the merged file (3 = journeys, see ``journey.py``;
-#: 4 = the profiler's device timeline, see ``profiler.py``)
+#: pid lanes in the merged file (3 = journeys, see ``journey.py``)
 PID_RUNTIME = 1
 PID_REQUESTS = 2
 

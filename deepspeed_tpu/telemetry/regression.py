@@ -181,18 +181,6 @@ FRONTEND_SPECS: List[MetricSpec] = [
                note="the bench GETs /slo live and checks its schema"),
     MetricSpec(("slo", "n_slos"), SHIFT, abs_tol=0.0,
                note="stock objective count is deterministic"),
-    # ---- chunk-timeline profiler (overload window + steady-state) ----
-    MetricSpec(("profile", "attribution_ok"), SHIFT, abs_tol=0.0,
-               note="components must sum to wall within 5%, binary"),
-    MetricSpec(("profile", "steady_state", "attribution_ok"), SHIFT,
-               abs_tol=0.0),
-    MetricSpec(("profile", "steady_state", "bubble_fraction"), LOWER,
-               0.50, abs_tol=0.08,
-               note="steady-state decode idle share; the <0.15 ceiling "
-                    "is asserted inside the bench"),
-    MetricSpec(("profile", "stalled_prefills_seen"), SHIFT, abs_tol=0.0,
-               note="the mixed overload workload must exhibit the "
-                    "decode-behind-prefill stall (ROADMAP item 4)"),
     # ---- per-tenant goodput accounting (live /tenants self-fetch) ----
     MetricSpec(("tenant_goodput", "endpoint_ok"), SHIFT, abs_tol=0.0,
                note="the bench GETs /tenants live and checks its schema"),
@@ -220,10 +208,6 @@ FRONTEND_SPECS: List[MetricSpec] = [
                abs_tol=0.5,
                note="fused TTFT p99 / bucketed TTFT p99 — chunking the "
                     "prompt must not blow up time-to-first-token"),
-    MetricSpec(("fused_mixed", "profile", "prefill", "stall_s"), LOWER,
-               0.50, abs_tol=0.05,
-               note="in-scan prompt chunks cannot preempt decode "
-                    "launches: stall stays ~0 in fused profiles"),
 ]
 
 FLEET_SPECS: List[MetricSpec] = [
@@ -311,9 +295,6 @@ FLEET_SPECS: List[MetricSpec] = [
                abs_tol=2.0,
                note="recovery-window TTFT stays bounded (wedge hold + "
                     "survivor backlog; CPU timing is noisy)"),
-    # ---- chunk-timeline profiler (busiest parity replica) ----
-    MetricSpec(("profile", "attribution_ok"), SHIFT, abs_tol=0.0,
-               note="components must sum to wall within 5%, binary"),
     # ---- fleet-wide per-tenant goodput (router merge) ----
     MetricSpec(("tenant_goodput", "n_tenants"), SHIFT, abs_tol=0.0,
                note="tenant-a + tenant-b on the pinned parity workload"),

@@ -11,7 +11,6 @@ Covered here:
   * paged PrefixCache hits short-circuiting every remaining chunk;
   * scheduler chunk-token-budget admission (token_budget / lane_cost);
   * engine budget accounting (_budget_drain / _lane_cost);
-  * ChunkProfiler inline-prefill attribution;
   * AdmissionConfig.cost_tokens (ceil(L/C) + max_new fused estimate vs
     the bucket-weight estimate) and the frontend auto-wiring of it.
 """
@@ -262,57 +261,6 @@ class TestBudgetAdmission:
         a = ref.run(list(prompts), max_new_tokens=8)
         b = fz.run(list(prompts), max_new_tokens=8)
         _assert_parity(a, b)
-
-
-# ------------------------------------------ profiler inline attribution
-class TestProfilerInlineAttribution:
-    def test_inline_fields_accumulate(self):
-        from deepspeed_tpu.telemetry.profiler import ChunkProfiler
-        t = [0.0]
-
-        def clock():
-            return t[0]
-
-        prof = ChunkProfiler(clock=clock, gauge_fn=lambda *a, **k: None)
-        # two chunk iterations, the first carrying 8 inline prompt tokens
-        prof.on_launch(0.00, 0.01, n_slots=2)
-        prof.on_chunk(0.01, 0.01, 0.05, 0.05, 0.06, n_tokens=4,
-                      occupancy=0.5, inline_pf_tokens=8,
-                      inline_pf_frac=0.5)
-        prof.on_launch(0.06, 0.07, n_slots=2)
-        prof.on_chunk(0.07, 0.07, 0.11, 0.11, 0.12, n_tokens=8,
-                      occupancy=0.5, inline_pf_tokens=0,
-                      inline_pf_frac=0.0)
-        t[0] = 0.12
-        rep = prof.profile_report()
-        assert rep["n_chunks"] == 2
-        assert rep["prefill"]["inline_tokens"] == 8
-        # inline_s: the hardware window of iterations that carried
-        # prompt chunks, scaled by the inline fraction
-        assert rep["prefill"]["inline_s"] == pytest.approx(0.02)
-        # fused mode launches no prefill programs: stall stays zero
-        assert rep["prefill"]["stall_s"] == 0.0
-        assert rep["prefill"]["n"] == 0
-
-    def test_live_engine_attribution(self, tiny_engine, prompts):
-        """On a real fused run the profiler's inline token count matches
-        the engine counter and no prefill windows are recorded."""
-        from deepspeed_tpu.telemetry.profiler import ChunkProfiler
-        fz = ServingEngine(engine=tiny_engine, max_batch=3,
-                           max_prompt_len=16, max_queue=16,
-                           decode_chunk=4, fused_prefill=True,
-                           prefill_chunk=4)
-        fz.run(list(prompts), max_new_tokens=4)      # warm
-        before = fz.inline_prefill_tokens
-        prof = ChunkProfiler()
-        fz.profiler = prof
-        fz.run(list(prompts), max_new_tokens=4)
-        rep = prof.profile_report()
-        assert rep["prefill"]["inline_tokens"] == \
-            fz.inline_prefill_tokens - before
-        assert rep["prefill"]["stall_s"] == 0.0
-        assert rep["prefill"]["n"] == 0
-        assert rep["prefill"]["inline_s"] > 0.0
 
 
 # -------------------------------------------- admission cost unification

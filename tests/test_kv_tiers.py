@@ -1,5 +1,5 @@
 """Tiered KV cache: HBM -> host DRAM -> NVMe demotion ladder, async
-promotion, fleet prefix fetch, tier-aware admission, capacity tuner.
+promotion, fleet prefix fetch, tier-aware admission.
 
 Layered like the subsystem: pure host-side KVTierManager units first
 (no JAX — eviction order, spill round-trip bit-parity, watermark
@@ -8,10 +8,8 @@ round trips must reproduce the dense arena's greedy outputs bit for
 bit — fp32, int8, and speculative compositions; the async promotion
 race pinned with a slowed worker), then the fleet surface (loopback
 ReplicaServer peer fetch with ZERO re-prefill, router tier-fetch
-fallback), and the capacity autotuner smoke (tiny grid -> valid
-``dstpu-tuned-v1`` Pareto JSON -> the engine loads and runs it)."""
+fallback)."""
 
-import json
 import os
 import time
 
@@ -654,39 +652,3 @@ class TestFleetPrefixFetch:
         assert not FleetRouter._tier_fetch(dead, target, b"\x01")
         bare = SimpleNamespace(frontend=SimpleNamespace())
         assert not FleetRouter._tier_fetch(bare, target, b"\x01")
-
-
-# ----------------------------------------------- capacity tuner smoke
-class TestCapacityTunerSmoke:
-    def test_tiny_grid_emits_pareto_and_engine_loads_it(
-            self, tiny_engine, tmp_path):
-        from deepspeed_tpu.autotuning import (ServingTuningSpace,
-                                              TUNED_SCHEMA,
-                                              tune_serving_capacity)
-        from deepspeed_tpu.serving import ServingEngine
-        out = tmp_path / "tuned.json"
-        doc = tune_serving_capacity(
-            tiny_engine, n_requests=2, prompt_len=8, max_new_tokens=4,
-            space=ServingTuningSpace(block_sizes=(8,),
-                                     decode_chunks=(4,),
-                                     spec_ks=(0,), prefill_chunks=(8,),
-                                     tier_dram_bytes=(None, 64 << 10)),
-            out=str(out), results_dir=str(tmp_path / "results"))
-        assert doc["schema"] == TUNED_SCHEMA
-        assert doc["pareto"] and doc["best"] is not None
-        assert doc["best"]["tokens_per_s"] > 0
-        for point in doc["pareto"]:
-            assert point["hbm_bytes"] >= 0
-        on_disk = json.loads(out.read_text())
-        assert on_disk["schema"] == TUNED_SCHEMA
-        # the emitted JSON drives a real engine end to end
-        eng = ServingEngine(engine=tiny_engine, max_batch=2,
-                            max_prompt_len=8, max_queue=4, paged=True,
-                            tuned_config=str(out))
-        try:
-            assert eng.tuned_config is not None
-            assert eng.kv.allocator.block_size == 8
-            res = eng.run([_prompt(8, seed=11)], max_new_tokens=4)
-            assert res[0].status == "done" and len(res[0].tokens) == 4
-        finally:
-            eng.close()

@@ -251,8 +251,8 @@ def tiny_engine():
 class TestPagedEngineParity:
     def test_paged_matches_dense_mixed_lengths(self, tiny_engine):
         """Paged greedy output is BIT-identical to the dense arena for
-        mixed prompt lengths, more requests than slots — per-token and
-        chunked paged loops both."""
+        mixed prompt lengths, more requests than slots — at chunks of
+        one step and of eight."""
         from deepspeed_tpu.serving import ServingEngine
         rng = np.random.default_rng(0)
         prompts = [rng.integers(0, 64, (n,)).astype(np.int32)

@@ -1,27 +1,16 @@
-"""Chunk-timeline profiler, per-tenant goodput, and anomaly detection.
+"""Per-tenant goodput and anomaly detection.
 
-Three layers under test, host-side first:
+Two layers under test, both host-side:
 
-* ``ChunkProfiler`` attribution — synthetic perf_counter stamps drive
-  the four-way (device/host-wait/scheduler/bubble) split, which must be
-  conservative (components sum to wall) by construction, and the
-  pid-4 device-timeline lane must pass the chrome-trace validator;
 * per-tenant goodput accounting in ``TraceLog`` (untagged submits fold
   under ``"default"``) with the ``/tenants`` endpoint and
   ``tenant=``-labelled ``/metrics`` series scraped live;
 * ``AnomalyDetector`` trip/debounce/re-arm mechanics, the one-shot
   postmortem per healthy→tripped flip, and the full injected-drift →
   ``/readyz`` degraded → recovery loop.
-
-The engine-integration test shares the same tiny compiled GPT the HBM
-tests use; the overhead gate mirrors the PR-5 telemetry gate (min-of-5
-timing, gc disabled) with the reference iteration shaped like the
-engine's real chunk: one jitted K-step scan dispatch + the host sync.
 """
 
-import gc
 import json
-import time
 import urllib.error
 import urllib.request
 
@@ -32,11 +21,7 @@ import deepspeed_tpu.telemetry as tel
 from deepspeed_tpu.serving.frontend import HealthMonitor, TraceLog
 from deepspeed_tpu.serving.scheduler import Request
 from deepspeed_tpu.telemetry import (AnomalyDetector, AnomalySpec,
-                                     ChunkProfiler, FlightRecorder,
-                                     PID_DEVICE, default_specs,
-                                     validate_report)
-from deepspeed_tpu.telemetry.cli import main as tputrace_main
-from deepspeed_tpu.telemetry.cli import validate_trace
+                                     FlightRecorder, default_specs)
 from deepspeed_tpu.telemetry.exposition import (MetricsServer,
                                                 parse_prometheus_text)
 
@@ -52,157 +37,6 @@ class FakeClock:
 
     def advance(self, dt):
         self.t += dt
-
-
-def _drive(prof, n=4, *, t0=100.0, launch_s=0.0005, device_s=0.002,
-           retire_s=0.0005, gap_s=0.001, prefill_at=(), prefill_s=0.002,
-           n_tokens=8, proposed=0, accepted=0):
-    """Synthetic engine loop: launch -> (optional prefill) -> sync ->
-    retire, ``gap_s`` of bubble between iterations. Returns final t."""
-    t = t0
-    for i in range(n):
-        l0, l1 = t, t + launch_s
-        prof.on_launch(l0, l1, 2)
-        t = l1
-        if i in prefill_at:
-            prof.on_prefill(t, t + prefill_s, n=1, bucket=16,
-                            stalled=True)
-            t += prefill_s
-        hw0 = t
-        hw1 = hw0 + device_s
-        rt1 = hw1 + retire_s
-        prof.on_chunk(launch_t=l1, hw0=hw0, hw1=hw1, rt0=hw1, rt1=rt1,
-                      n_tokens=n_tokens, occupancy=0.5,
-                      proposed=proposed, accepted=accepted)
-        t = rt1 + gap_s
-    return t
-
-
-# ----------------------------------------------------------- profiler
-class TestChunkProfiler:
-    def test_attribution_is_conservative(self):
-        prof = ChunkProfiler(gauge_fn=lambda *_: None)
-        _drive(prof, n=5, prefill_at=(2,), proposed=4, accepted=3)
-        rep = prof.profile_report(timeline=5)
-        assert rep["schema"] == "dstpu-profile-v1"
-        assert rep["n_chunks"] == 5 and rep["n_tokens"] == 40
-        comps = rep["components"]
-        total = sum(comps.values())
-        assert total == pytest.approx(rep["wall_s"], rel=1e-9)
-        assert rep["attribution_error_frac"] == pytest.approx(0.0,
-                                                              abs=1e-9)
-        assert rep["attribution_ok"] is True
-        assert validate_report(rep) == []
-        # the synthetic schedule is exact: 5 launches + 5 retires,
-        # 5 device windows, 1 prefill, 4 inter-iteration gaps
-        assert comps["device_compute_s"] == pytest.approx(5 * 0.002)
-        assert comps["scheduler_s"] == pytest.approx(5 * 0.001)
-        assert comps["host_wait_s"] == pytest.approx(0.002)
-        assert comps["bubble_s"] == pytest.approx(4 * 0.001)
-        assert len(rep["timeline"]) == 5
-        assert rep["timeline"][0]["wall_s"] > 0
-
-    def test_prefill_stall_accounting(self):
-        prof = ChunkProfiler(gauge_fn=lambda *_: None)
-        prof.on_prefill(1.0, 1.5, n=2, bucket=32, stalled=True)
-        prof.on_prefill(2.0, 2.25, n=1, bucket=16, stalled=False)
-        prof.on_chunk(launch_t=2.3, hw0=2.35, hw1=2.4, rt0=2.4, rt1=2.45)
-        rep = prof.profile_report()
-        assert rep["prefill"]["n"] == 2
-        assert rep["prefill"]["total_s"] == pytest.approx(0.75)
-        assert rep["prefill"]["stall_s"] == pytest.approx(0.5)
-        assert rep["prefill"]["n_stalled"] == 1
-        # both windows were pending, so they attribute as host wait
-        assert rep["components"]["host_wait_s"] == pytest.approx(0.75)
-
-    def test_bubble_fraction_and_gauges(self):
-        seen = {}
-        prof = ChunkProfiler(gauge_fn=lambda n, v: seen.__setitem__(n, v),
-                             gauge_every=2)
-        _drive(prof, n=4, gap_s=0.002)
-        bf = prof.bubble_fraction()
-        assert 0.0 < bf < 1.0
-        assert seen["serve/bubble_fraction"] == pytest.approx(bf)
-        assert "serve/prefill_stall_s" in seen
-
-    def test_spec_goodput(self):
-        prof = ChunkProfiler(gauge_fn=lambda *_: None)
-        _drive(prof, n=2, proposed=8, accepted=6)
-        good = prof.profile_report()["goodput"]
-        assert good["spec_proposed"] == 16 and good["spec_accepted"] == 12
-        assert good["spec_acceptance"] == pytest.approx(0.75)
-        assert good["tokens_per_chunk"] == pytest.approx(8.0)
-        # no speculation at all -> None, not 0/0
-        prof.clear()
-        _drive(prof, n=1)
-        assert prof.profile_report()["goodput"]["spec_acceptance"] is None
-
-    def test_clear_resets_everything(self):
-        prof = ChunkProfiler(gauge_fn=lambda *_: None)
-        _drive(prof, n=3, prefill_at=(1,))
-        prof.clear()
-        rep = prof.profile_report()
-        assert rep["n_chunks"] == 0 and rep["wall_s"] == 0.0
-        assert rep["prefill"]["n"] == 0
-        assert prof.bubble_fraction() == 0.0
-
-    def test_validate_report_flags_problems(self):
-        prof = ChunkProfiler(gauge_fn=lambda *_: None)
-        _drive(prof, n=2)
-        rep = prof.profile_report()
-        rep["wall_s"] *= 2.0                     # break conservation
-        problems = validate_report(rep)
-        assert len(problems) == 1 and "wall" in problems[0]
-        del rep["components"]["bubble_s"]
-        assert any("missing component bubble_s" in p
-                   for p in validate_report(rep))
-
-    def test_trace_events_validate_as_chrome_trace(self):
-        prof = ChunkProfiler(gauge_fn=lambda *_: None)
-        _drive(prof, n=3, prefill_at=(1,))
-        events = prof.trace_events()
-        assert validate_trace({"traceEvents": events}) == []
-        assert all(e["pid"] == PID_DEVICE for e in events)
-        names = {e["name"] for e in events if e["ph"] == "X"}
-        assert names == {"chunk", "host_wait", "launch", "retire",
-                         "prefill"}
-        lane = [e for e in events if e["ph"] == "M"
-                and e["name"] == "process_name"]
-        assert lane[0]["args"]["name"] == "device timeline"
-
-
-# ------------------------------------------------- tputrace profile CLI
-class TestProfileCLI:
-    def _report_file(self, tmp_path, mutate=None, wrap=False):
-        prof = ChunkProfiler(gauge_fn=lambda *_: None)
-        _drive(prof, n=4, prefill_at=(2,), proposed=4, accepted=3)
-        rep = prof.profile_report()
-        if mutate:
-            mutate(rep)
-        doc = {"profile": rep} if wrap else rep
-        p = tmp_path / "profile.json"
-        p.write_text(json.dumps(doc))
-        return p
-
-    def test_cli_profile_validate_ok(self, tmp_path, capsys):
-        p = self._report_file(tmp_path)
-        assert tputrace_main(["profile", str(p), "--validate"]) == 0
-        out = capsys.readouterr().out
-        assert "attribution OK" in out
-        assert "device_compute" in out and "bubble" in out
-
-    def test_cli_profile_reads_bench_wrapper(self, tmp_path, capsys):
-        p = self._report_file(tmp_path, wrap=True)
-        assert tputrace_main(["profile", str(p)]) == 0
-        assert "chunks" in capsys.readouterr().out
-
-    def test_cli_profile_validate_fails_on_bad_sums(self, tmp_path,
-                                                    capsys):
-        p = self._report_file(
-            tmp_path, mutate=lambda r: r.__setitem__(
-                "wall_s", r["wall_s"] * 3.0))
-        assert tputrace_main(["profile", str(p), "--validate"]) == 1
-        assert "FAIL" in capsys.readouterr().err
 
 
 # ----------------------------------------------------- tenant goodput
@@ -332,8 +166,7 @@ def _baseline(det, n=10, base=0.010):
 class TestAnomalyDetector:
     def test_default_specs_cover_the_vitals(self):
         names = {s.metric for s in default_specs()}
-        assert names == {"tpot_s", "spec_acceptance", "prefix_hit_rate",
-                         "bubble_fraction"}
+        assert names == {"tpot_s", "spec_acceptance", "prefix_hit_rate"}
         with pytest.raises(ValueError):
             AnomalySpec("x", direction="sideways_is_bad")
 
@@ -411,20 +244,6 @@ class TestAnomalyDetector:
         det.observe_trace(T())
         assert det.n_observed == 1
 
-    def test_observe_profile_folds_engine_vitals(self):
-        det = AnomalyDetector(
-            [AnomalySpec("bubble_fraction", min_samples=4),
-             AnomalySpec("spec_acceptance", direction="lower_is_bad",
-                         min_samples=4)],
-            gauge_fn=lambda *_: None)
-        det.observe_profile({"bubble_fraction": 0.05,
-                             "goodput": {"spec_acceptance": 0.8}})
-        assert det.n_observed == 2
-        # spec_acceptance None (no speculation) must not count
-        det.observe_profile({"bubble_fraction": 0.05,
-                             "goodput": {"spec_acceptance": None}})
-        assert det.n_observed == 3
-
     def test_report_shape(self):
         det = AnomalyDetector([_spec()], gauge_fn=lambda *_: None)
         _baseline(det, n=6)
@@ -483,133 +302,3 @@ class TestAnomalyReadiness:
             assert fr.n_dumps == 1
         finally:
             server.stop()
-
-
-# ----------------------------------------------- engine integration
-def _tiny():
-    import jax
-    import jax.numpy as jnp
-    from deepspeed_tpu.models.gpt import GPT, GPTConfig
-    cfg = GPTConfig(vocab_size=64, max_seq_len=64, num_layers=2,
-                    num_heads=2, d_model=32, d_ff=64, dtype=jnp.float32,
-                    param_dtype=jnp.float32, remat=False)
-    model = GPT(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, 4), jnp.int32))["params"]
-    return model, params
-
-
-@pytest.fixture(scope="module")
-def tiny_engine():
-    import jax.numpy as jnp
-    import deepspeed_tpu as ds
-    model, params = _tiny()
-    return ds.init_inference(model, model_parameters=params,
-                             dtype=jnp.float32)
-
-
-class TestEngineIntegration:
-    def test_profiler_attributes_real_chunks_and_stalls(self,
-                                                        tiny_engine):
-        from deepspeed_tpu.serving import ServingEngine
-        serving = ServingEngine(engine=tiny_engine, max_batch=2,
-                                max_prompt_len=16, max_queue=16,
-                                decode_chunk=4)
-        prof = ChunkProfiler(gauge_fn=lambda *_: None)
-        serving.profiler = prof
-        serving.submit(np.arange(1, 6, dtype=np.int32),
-                       max_new_tokens=12)
-        # pump until a chunk is in flight, THEN submit the second
-        # request: its prefill runs while a decode slot is live, which
-        # is exactly the ROADMAP item-4 stall the profiler must see
-        for _ in range(50):
-            serving.pump()
-            if serving.chunk_in_flight:
-                break
-        assert serving.chunk_in_flight
-        serving.submit(np.arange(1, 10, dtype=np.int32),
-                       max_new_tokens=12)
-        while serving.scheduler.has_work() or serving.chunk_in_flight:
-            serving.pump()
-        rep = prof.profile_report()
-        assert rep["n_chunks"] >= 2 and rep["n_tokens"] > 0
-        assert rep["attribution_ok"], rep
-        assert validate_report(rep) == []
-        assert rep["components"]["device_compute_s"] > 0.0
-        assert rep["components"]["scheduler_s"] > 0.0
-        assert rep["prefill"]["n"] >= 2
-        # the second prefill was admitted under live decode slots
-        assert rep["prefill"]["n_stalled"] >= 1
-        assert rep["prefill"]["stall_s"] > 0.0
-        events = prof.trace_events()
-        assert validate_trace({"traceEvents": events}) == []
-        assert any(e["name"] == "prefill" for e in events)
-
-    def test_detached_profiler_is_default(self, tiny_engine):
-        from deepspeed_tpu.serving import ServingEngine
-        serving = ServingEngine(engine=tiny_engine, max_batch=2,
-                                max_prompt_len=16, max_queue=16,
-                                decode_chunk=4)
-        assert serving.profiler is None
-        serving.run([np.arange(1, 6, dtype=np.int32)], max_new_tokens=4)
-
-
-# ------------------------------------------------------ overhead gate
-class TestProfilerOverheadGate:
-    def test_hooks_under_one_percent_of_chunk_iteration(self):
-        """The enabled profiler must cost <1% of a dispatch-bound chunk
-        iteration. The reference iteration is shaped like the engine's
-        real chunk: ONE jitted K-step scan dispatch + the np.asarray
-        host sync (`_launch_chunk` + `_consume_chunk`), so the ratio is
-        against what the hooks actually ride on."""
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-
-        def best(fn, iters, repeats=5):
-            out = []
-            for _ in range(repeats):
-                t0 = time.perf_counter()
-                fn()
-                out.append((time.perf_counter() - t0) / iters)
-            return min(out)
-
-        prof = ChunkProfiler(gauge_fn=lambda *_: None)
-        clk = prof.clock
-        n = 20000
-
-        def bare():
-            for _ in range(n):
-                clk(); clk(); clk(); clk(); clk()     # noqa: E702
-
-        def hooks():
-            for _ in range(n):
-                t0 = clk(); t1 = clk()                # noqa: E702
-                prof.on_launch(t0, t1, 2)
-                hw0 = clk(); rt0 = clk(); rt1 = clk()  # noqa: E702
-                prof.on_chunk(launch_t=t1, hw0=hw0, hw1=rt0, rt0=rt0,
-                              rt1=rt1, n_tokens=8, occupancy=0.5,
-                              proposed=0, accepted=0)
-
-        x = jnp.eye(128) * 0.5
-        step = lambda i, a: jnp.maximum(a @ a, 0.0) + 1e-3  # noqa: E731
-        chunk_fn = jax.jit(lambda a: lax.fori_loop(0, 8, step, a))
-        chunk_fn(x).block_until_ready()                # compile once
-        m = 200
-
-        def iteration():
-            for _ in range(m):
-                np.asarray(chunk_fn(x))                # dispatch + sync
-
-        gc.disable()
-        try:
-            hook_cost = best(hooks, n) - best(bare, n)
-            iter_cost = best(iteration, m)
-        finally:
-            gc.enable()
-        ratio = hook_cost / iter_cost
-        assert hook_cost < 3.5e-6, \
-            f"profiler hooks cost {hook_cost * 1e6:.2f}us per chunk"
-        assert ratio < 0.01, \
-            (f"profiler hooks are {ratio:.2%} of a "
-             f"{iter_cost * 1e6:.0f}us chunk iteration")

@@ -130,8 +130,8 @@ class TestScheduler:
         sched.submit(r)
         (req,) = sched.admit()
         sched.record_first_token(req, 10)
-        assert sched.step_tokens({req.slot: 11}) == []
-        done = sched.step_tokens({0: 12})
+        assert sched.step_tokens_chunk({req.slot: [11]}) == []
+        done = sched.step_tokens_chunk({0: [12]})
         assert done == [r] and r.status == "done"
         assert r.tokens == [10, 11, 12]
         assert list(r.output_ids) == [1, 2, 10, 11, 12]
@@ -143,7 +143,7 @@ class TestScheduler:
         sched.submit(r)
         sched.admit()
         sched.record_first_token(r, 3)
-        done = sched.step_tokens({r.slot: 7})
+        done = sched.step_tokens_chunk({r.slot: [7]})
         assert done == [r] and r.status == "done"
         assert r.tokens == [3, 7]                   # EOS included
 
@@ -166,7 +166,7 @@ class TestScheduler:
         sched.admit()                               # keep takes the slot
         clock.advance(10.0)                         # late expires in queue
         sched.record_first_token(keep, 1)
-        sched.step_tokens({keep.slot: 2})           # frees the slot
+        sched.step_tokens_chunk({keep.slot: [2]})    # frees the slot
         assert sched.admit() == []                  # late shed, not admitted
         assert late.status == "expired" and sched.n_expired == 1
         assert not sched.has_work()
@@ -179,7 +179,7 @@ class TestScheduler:
         sched.admit()
         sched.record_first_token(r, 1)
         clock.advance(10.0)
-        done = sched.step_tokens({r.slot: 2})
+        done = sched.step_tokens_chunk({r.slot: [2]})
         assert done == [r] and r.status == "expired"
         assert alloc.n_free == 1
 
@@ -239,22 +239,27 @@ class TestScheduler:
         assert r.ttft_s == pytest.approx(0.25)
 
     def test_step_tokens_chunk_matches_per_token_calls(self):
-        """A fused chunk's token list must behave exactly like K
-        step_tokens calls: per-token allocator advance, termination
-        mid-list, trailing speculative tokens dropped."""
-        sched, alloc, _ = _sched(max_batch=2, max_seq=32)
-        a = Request(prompt=[1, 2], max_new_tokens=10, eos_token_id=7)
-        b = Request(prompt=[3], max_new_tokens=3)
-        sched.submit(a)
-        sched.submit(b)
-        sched.admit()
-        sched.record_first_token(a, 4)
-        sched.record_first_token(b, 5)
-        fill_a, fill_b = int(alloc.fill[a.slot]), int(alloc.fill[b.slot])
+        """A chunk's token list must behave exactly like as many calls
+        of width one: per-token allocator advance, termination mid-list,
+        trailing speculative tokens dropped."""
+        lists = {"a": [9, 9, 7, 8, 8], "b": [6, 6, 6, 6]}
+
+        def admit():
+            sched, alloc, _ = _sched(max_batch=2, max_seq=32)
+            a = Request(prompt=[1, 2], max_new_tokens=10, eos_token_id=7)
+            b = Request(prompt=[3], max_new_tokens=3)
+            sched.submit(a)
+            sched.submit(b)
+            sched.admit()
+            sched.record_first_token(a, 4)
+            sched.record_first_token(b, 5)
+            return sched, alloc, a, b
+
         # a hits EOS at its 3rd chunk token; b exhausts max_new_tokens at
         # its 2nd — trailing tokens in both lists are speculative junk
-        done = sched.step_tokens_chunk({a.slot: [9, 9, 7, 8, 8],
-                                        b.slot: [6, 6, 6, 6]})
+        sched, alloc, a, b = admit()
+        done = sched.step_tokens_chunk({a.slot: lists["a"],
+                                        b.slot: lists["b"]})
         assert sorted(r.uid for r in done) == sorted([a.uid, b.uid])
         assert a.status == "done" and a.tokens == [4, 9, 9, 7]
         assert b.status == "done" and b.tokens == [5, 6, 6]
@@ -264,10 +269,24 @@ class TestScheduler:
         with pytest.raises(KeyError):
             sched.step_tokens_chunk({1: [1]})
 
+        # the reference: one token a call, to lanes still running
+        ref, ref_alloc, ra, rb = admit()
+        fills = []
+        for j in range(5):
+            step = {r.slot: [lists[k][j]]
+                    for k, r in (("a", ra), ("b", rb))
+                    if r.status == "running" and j < len(lists[k])}
+            fills.append({s: int(ref_alloc.fill[s]) for s in step})
+            ref.step_tokens_chunk(step)
+        assert (ra.tokens, rb.tokens) == (a.tokens, b.tokens)
+        assert (ra.status, rb.status) == (a.status, b.status)
+        assert fills[:3] == [{0: 2, 1: 1}, {0: 3, 1: 2}, {0: 4}]
+        assert fills[3:] == [{}, {}] and ref_alloc.n_free == 2
+
     def test_step_tokens_chunk_advances_fill_per_token(self):
-        """The cache-row safety net must see the same remaining count the
-        per-token loop would — fill advances inside the chunk, not once
-        at the end."""
+        """The cache-row safety net must see the same remaining count
+        calls of one token each would — fill advances inside the chunk,
+        not once at the end."""
         sched, alloc, _ = _sched(max_batch=1, max_seq=8)
         r = Request(prompt=[1, 2, 3], max_new_tokens=5)
         sched.submit(r)
@@ -363,18 +382,35 @@ def tiny_engine():
                              dtype=jnp.float32)
 
 
+FAMILIES = {
+    "dense": {},
+    "paged": dict(paged=True, kv_block_size=8),
+    "int8": dict(kv_dtype="int8"),
+    "speculative": dict(speculative=True, spec_k=3),
+    "fused": dict(fused_prefill=True, prefill_chunk=4),
+}
+
+
 class TestServingEngine:
-    def test_greedy_parity_with_generate(self, tiny_engine):
+    @pytest.mark.parametrize("decode_chunk", [1, 3, 8])
+    @pytest.mark.parametrize("family", list(FAMILIES))
+    def test_greedy_parity_with_generate(self, tiny_engine, family,
+                                         decode_chunk):
         """Mixed-length prompts, more requests than slots: every request's
         output must match a dedicated InferenceEngine.generate run — the
-        continuous batch changes throughput, never tokens."""
+        continuous batch changes throughput, never tokens. In every
+        program family, at every chunk length: K = 1 is a chunk of one
+        step through the same scan, and 3 divides neither the answer
+        nor the other lengths."""
         rng = np.random.default_rng(0)
         vocab = tiny_engine.module.cfg.vocab_size
         lens = [3, 7, 5, 9, 4, 6]
         prompts = [rng.integers(0, vocab, (n,)).astype(np.int32)
                    for n in lens]
         serving = ServingEngine(engine=tiny_engine, max_batch=3,
-                                max_prompt_len=16, max_queue=8)
+                                max_prompt_len=16, max_queue=8,
+                                decode_chunk=decode_chunk,
+                                **FAMILIES[family])
         results = serving.run(prompts, max_new_tokens=6)
         assert all(r.status == "done" for r in results)
         for p, r in zip(prompts, results):
@@ -382,11 +418,11 @@ class TestServingEngine:
                 p[None], max_new_tokens=6, temperature=0.0))[0]
             np.testing.assert_array_equal(r.output_ids, ref)
 
-    def test_chunked_decode_matches_per_token_loop(self, tiny_engine):
-        """The fused K-step loop is an execution strategy, not a model
-        change: greedy outputs must be BIT-identical to the per-token
-        loop for mixed-length prompts, mid-chunk EOS, and EOS on the very
-        first (prefill-sampled) token."""
+    def test_chunk_of_one_matches_chunk_of_eight(self, tiny_engine):
+        """K is an execution strategy, not a model change: greedy outputs
+        must be BIT-identical between a chunk of one step and a chunk of
+        eight for mixed-length prompts, mid-chunk EOS, and EOS on the
+        very first (prefill-sampled) token."""
         rng = np.random.default_rng(1)
         vocab = tiny_engine.module.cfg.vocab_size
         prompts = [rng.integers(0, vocab, (n,)).astype(np.int32)
@@ -414,6 +450,17 @@ class TestServingEngine:
         first_eos = base[1].tokens[0]
         res = both(max_new_tokens=11, eos_token_id=int(first_eos))
         assert any(len(r.tokens) == 1 for r in res)
+
+    def test_the_constructor_gains_no_option_unnoticed(self):
+        """33 keyword options are what the benchmark's cells and the
+        tests' families need today. The next one is a deliberate edit of
+        this number, made with the case for it."""
+        import inspect
+        params = inspect.signature(ServingEngine.__init__).parameters
+        options = [n for n, p in params.items()
+                   if p.kind is inspect.Parameter.KEYWORD_ONLY]
+        assert "tuned_config" not in params
+        assert len(options) <= 33, options
 
     def test_engine_rejections_surface(self, tiny_engine):
         serving = ServingEngine(engine=tiny_engine, max_batch=2,
@@ -459,13 +506,17 @@ class TestServeLoopSpans:
     as annotations in the profiler's trace)."""
 
     def _run(self, tiny_engine, decode_chunk, n_requests=5):
+        return self._drive(
+            ServingEngine(engine=tiny_engine, max_batch=2,
+                          max_prompt_len=16, max_queue=8,
+                          decode_chunk=decode_chunk), n_requests)
+
+    @staticmethod
+    def _drive(serving, n_requests=5):
         rng = np.random.default_rng(3)
-        vocab = tiny_engine.module.cfg.vocab_size
+        vocab = serving.module.cfg.vocab_size
         prompts = [rng.integers(0, vocab, (n,)).astype(np.int32)
                    for n in [3, 7, 5, 9, 4][:n_requests]]
-        serving = ServingEngine(engine=tiny_engine, max_batch=2,
-                                max_prompt_len=16, max_queue=8,
-                                decode_chunk=decode_chunk)
         # answers of different lengths: lanes retire and are refilled
         # while the other lane's chunk is in flight (the patched path)
         results = [serving.submit(p, max_new_tokens=n)
@@ -474,15 +525,18 @@ class TestServeLoopSpans:
         assert all(r.status == "done" for r in results)
         return serving
 
-    @pytest.mark.parametrize("decode_chunk,waits", [
-        (4, ("serve/chunk_host_wait", "serve/prefill_wait")),
-        (1, ("serve/decode_step", "serve/prefill_wait"))])
+    @pytest.mark.parametrize("decode_chunk", [4, 1])
     def test_starved_spans_never_overlap_a_device_wait(
-            self, tiny_engine, telemetry_on, decode_chunk, waits):
+            self, tiny_engine, telemetry_on, decode_chunk):
+        waits = ("serve/chunk_host_wait", "serve/prefill_wait")
         serving = self._run(tiny_engine, decode_chunk)
         starved = _spans(telemetry_on, *STARVED)
         blocked = _spans(telemetry_on, *waits)
-        assert {n for n, _, _ in starved} == set(STARVED)
+        # a chunk of one step is launched ahead like any other, so its
+        # sync leaves the chip idle only where every lane was on its
+        # last token: this traffic has that at K = 4 and not at K = 1
+        names = {n for n, _, _ in starved}
+        assert names == set(STARVED if decode_chunk > 1 else STARVED[:1])
         assert {n for n, _, _ in blocked} == set(waits)
         for _, s0, s1 in starved:
             assert s1 >= s0
@@ -530,13 +584,82 @@ class TestServeLoopSpans:
         assert patches and marks
         assert all(_inside(m, patches) for m in marks)
 
-    def test_telemetry_off_opens_nothing(self, tiny_engine):
+    @pytest.mark.parametrize("decode_chunk", [4, 1])
+    def test_telemetry_off_opens_nothing(self, tiny_engine, decode_chunk):
         from deepspeed_tpu.telemetry import core as tel
         rt = tel.get_runtime()
         assert not rt.enabled
-        before = len(rt.events())
-        serving = self._run(tiny_engine, 4, n_requests=3)
-        assert serving._starved is None and len(rt.events()) == before
+        before = (len(rt.events()), rt.span_stats(), rt.counter_totals())
+        serving = self._run(tiny_engine, decode_chunk, n_requests=3)
+        assert serving._starved is None
+        assert (len(rt.events()), rt.span_stats(),
+                rt.counter_totals()) == before
+
+    @pytest.mark.parametrize("decode_chunk", [8, 1])
+    def test_chunk_spans_count_the_chunks(self, tiny_engine, telemetry_on,
+                                          decode_chunk):
+        """One loop at every K: each chunk is one launch, one host wait
+        and one retire on the spans, as many as ``ServingMetrics``
+        counted, and at K = 1 too they are the chunk's spans."""
+        calls = []
+        serving = ServingEngine(engine=tiny_engine, max_batch=2,
+                                max_prompt_len=16, max_queue=8,
+                                decode_chunk=decode_chunk)
+        assert not hasattr(serving, "_jit_decode")
+        jitted = serving._jit_decode_chunk
+        serving._jit_decode_chunk = \
+            lambda *a: calls.append(1) or jitted(*a)
+        results = serving.run([np.arange(1, n, dtype=np.int32)
+                               for n in (4, 8, 6)], max_new_tokens=11)
+        assert all(r.status == "done" for r in results)
+        stats = telemetry_on.span_stats()
+        chunks = serving.metrics.decode_steps
+        # 30 tokens after the three that prefill sampled, two lanes
+        assert chunks == len(calls) >= 30 / (2 * decode_chunk)
+        for name in ("serve/chunk_launch", "serve/chunk_host_wait",
+                     "serve/chunk_retire"):
+            assert stats[name]["count"] == chunks, name
+        assert "serve/decode_step" not in stats
+        assert telemetry_on.counter_totals()["serve/decode_tokens"] == 30
+
+    def test_one_prefill_wait_for_each_prefill_call(self, tiny_engine,
+                                                    telemetry_on):
+        """What the host waited on prefill programs while lanes were
+        decoding is the sum of these spans: one for each call."""
+        calls = []
+        serving = ServingEngine(engine=tiny_engine, max_batch=2,
+                                max_prompt_len=16, max_queue=8,
+                                decode_chunk=4)
+        jitted = serving._jit_prefill
+        serving._jit_prefill = lambda *a: calls.append(1) or jitted(*a)
+        self._drive(serving)
+        stats = telemetry_on.span_stats()
+        # two lanes, five requests of different lengths: refills arrive
+        # one by one, so there are more calls than compiled shapes
+        assert len(calls) > serving.metrics.prefill_programs
+        assert stats["serve/prefill_wait"]["count"] == len(calls)
+        assert stats["serve/prefill"]["count"] == len(calls)
+        totals = telemetry_on.counter_totals()
+        assert totals["serve/prefill_tokens"] == 3 + 7 + 5 + 9 + 4
+        assert "serve/prefill_inline_tokens" not in totals
+
+    def test_a_fused_run_waits_on_no_prefill(self, tiny_engine,
+                                             telemetry_on):
+        """Fused prefill has no prefill program to wait on: the prompt
+        is consumed inside the scan, and the counter says how much."""
+        serving = ServingEngine(engine=tiny_engine, max_batch=2,
+                                max_prompt_len=16, max_queue=8,
+                                decode_chunk=4, fused_prefill=True,
+                                prefill_chunk=4)
+        self._drive(serving)
+        stats = telemetry_on.span_stats()
+        assert "serve/prefill_wait" not in stats
+        assert "serve/prefill" not in stats
+        assert stats["serve/chunk_host_wait"]["count"] == \
+            serving.metrics.decode_steps
+        totals = telemetry_on.counter_totals()
+        assert totals["serve/prefill_inline_tokens"] == 3 + 7 + 5 + 9 + 4
+        assert serving.inline_prefill_tokens == 3 + 7 + 5 + 9 + 4
 
     def test_program_names_the_benchmark_reads_by(self, tiny_engine):
         """chipbench finds the serving programs in a device trace by their
@@ -704,9 +827,9 @@ class TestSampling:
 
 def test_serving_bench_smoke(tmp_path):
     """Fast end-to-end smoke over the real benchmark path (the
-    bin/serving_smoke.sh entry point): per-token vs chunked loops on the
-    tiny model, greedy parity asserted inside run_bench, JSON-ready
-    result dict with tokens/s for both loops."""
+    bin/serving_smoke.sh entry point): chunks of one step ("per-token")
+    vs chunks of K on the tiny model, greedy parity asserted inside
+    run_bench, JSON-ready result dict with tokens/s for both."""
     from deepspeed_tpu.benchmarks.serving_bench import run_bench
     result = run_bench(n_requests=4, max_new_tokens=10, max_batch=4,
                        prompt_len=16, decode_chunk=4,

@@ -171,8 +171,8 @@ class TestVerify:
 # ------------------------------------------------------ engine: spec
 class TestSpeculativeEngine:
     def test_spec_greedy_parity_dense(self, tiny_engine):
-        """Speculative greedy output is BIT-identical to the per-token
-        loop and to generate(): mixed-length prompts, K not dividing the
+        """Speculative greedy output is BIT-identical to the plain scan
+        at a chunk of one step and to generate(): mixed-length prompts, K not dividing the
         budget, mid-chunk EOS, and EOS on the very first token."""
         rng = np.random.default_rng(4)
         vocab = tiny_engine.module.cfg.vocab_size
@@ -284,7 +284,7 @@ class TestInt8KV:
 
     def test_spec_over_int8_arena_parity(self, tiny_engine):
         """The combined case: speculative decode over the quantized
-        arena matches the non-speculative int8 per-token loop — the
+        arena matches the non-speculative int8 scan at K = 1 — the
         drafter/verifier sees quantized-model logits, so exactness holds
         against the int8 model, not the fp one."""
         rng = np.random.default_rng(9)
