@@ -456,7 +456,8 @@ def server_leg(cfg, sz, params, counter, *, megakernel: bool,
     elif megakernel:
         path = "pallas decode kernel + fused sampling epilogue"
     else:
-        path = "xla (reference): einsum decode + sort-based sampler"
+        path = ("einsum decode (the live-rows read's gate refuses heads "
+                "of 64) + sort-based sampler")
     say(leg, f"decode path: {path} (decode_impl="
         f"{srv.module.cfg.decode_impl!r}, megakernel={megakernel})")
     devices = jax.devices()
@@ -502,7 +503,8 @@ def kernel_leg(cfg, sz, on_chip: bool) -> None:
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.ops.pallas.decode_attention import (
-        decode_attention, masked_cache_attention, paged_decode_attention)
+        decode_attention, live_decode_attention, masked_cache_attention,
+        paged_decode_attention)
     from deepspeed_tpu.ops.pallas.flash_attention import (
         flash_attention, reference_attention)
     from deepspeed_tpu.ops.pallas.gelu import bias_gelu, bias_gelu_reference
@@ -617,6 +619,25 @@ def kernel_leg(cfg, sz, on_chip: bool) -> None:
               (qs, to_pool(kq), to_pool(vq), table, fills,
                to_pool(ks)[..., 0], to_pool(vs)[..., 0]), ref8,
               ["paged_decode_attention"])
+
+    # ---- the default decode read ("auto" where heads are whole 128-lane
+    # rows, which this model's heads of 64 are not): each lane's live
+    # blocks of the layer-stacked rank-4 leaves at a traced layer index,
+    # one lane masked (fill past S)
+    Ll, Hl, Dl = 2, 16, 128
+    kl, vl = rn(Ll, B, S, Hl, Dl), rn(Ll, B, S, Hl, Dl)
+    ql = rn(B, 1, Hl, Dl)
+    fills = np.asarray(rng.integers(1, S + 1, (B,)), np.int32)
+    fills[0], fills[-1] = S, S + 1
+    live = jnp.asarray(fills <= S)[:, None, None, None]
+    agree("live_decode_attention layer-stacked rows",
+          lambda q, k, v, n, i: jnp.where(
+              live, live_decode_attention(q, k, v, n, i), 0),
+          (ql, kl, vl, jnp.asarray(fills), jnp.int32(1)),
+          ref_of(lambda q, k, v, n: jnp.where(live, masked_cache_attention(
+              q, k[1], v[1], n - 1, 1.0 / Dl ** 0.5), 0),
+              ql, kl, vl, jnp.asarray(fills)),
+          ["decode_attention_live"])
 
     # ---- sampling epilogue: first-index argmax and the kept sets are
     # exact by construction. The references run under jit like the kernel:

@@ -252,15 +252,15 @@ class GPTConfig:
     # not measured (PERF.md, PR 21 has the kernel-level smoke readings).
     attention_impl: str = "auto"     # auto | xla | pallas | sparse
     sparse_attention: Any = None     # SparsityConfig when attention_impl=sparse
-    # "auto" resolves to the fused prefix-only Pallas kernel on a TPU when
-    # its gate accepts the shape (manual DMA pipeline over live cache blocks
-    # — O(cache_len) HBM traffic; the KV cache is stored FLAT [b, S, h*d] so
-    # XLA's d-dim lane padding never costs a relayout) and to the masked
-    # einsum otherwise, logging the choice once; "pallas" asks by name and
-    # raises where "auto" would take the einsum. Default stays "xla": the
-    # kernel compiles and agrees with the einsum on a v5e (PR 21) but has
-    # not been timed against it (ROADMAP S5).
-    decode_impl: str = "xla"         # auto | xla | pallas
+    # "auto" keeps the cache rank-4 [b, S, h, d] and, on a TPU, reads each
+    # lane's LIVE blocks of it where live_read_block's gate accepts the call
+    # (one device, plain bf16/f32 rows, one query a lane, d % 128 == 0), the
+    # masked einsum over all S rows everywhere else, logging the choice once
+    # (serve-batch, PR 29: the step 14.7 -> 10.1 ms). "pallas" asks BY NAME
+    # for the older all-lanes kernel (int8 and speculative widths in its DMA
+    # window) over a FLAT [b, S, h*d] cache and raises where its gate
+    # refuses; "xla" is the einsum alone. The knob is ROADMAP D1's.
+    decode_impl: str = "auto"        # auto | xla | pallas
     # KV-cache storage dtype: "auto" stores at the compute dtype; "int8"
     # stores symmetric per-token-group int8 (ops/quantizer.quantize_kv —
     # one scale per position's concatenated heads, kept in f32
@@ -464,6 +464,35 @@ def _decode_mesh_refusal() -> Optional[str]:
     return None
 
 
+_NO_WINDOW_KERNEL = "the decode kernels have no local-window path"
+
+
+def live_read_block(cfg: GPTConfig, b: int, s: int = 1,
+                    window: Optional[int] = None) -> Optional[int]:
+    """Rows a block of the live-rows decode read carries, where a dense
+    decode call of ``b`` lanes and query width ``s`` under ``cfg`` takes
+    that read (ops/pallas/decode_attention.live_decode_attention: each
+    lane's ``ceil(fill / block)`` blocks of the rank-4 rows); None where it
+    reads the layer's whole ``[b, S, h, d]`` rows with the masked einsum.
+    ``decode_impl="auto"`` alone chooses, from what a trace can see: the
+    platform, the mesh, the cache dtype, the width, a window, the shape.
+    The serving engine asks the same question to count what a step read
+    (``serve/kv_blocks_read``)."""
+    if cfg.decode_impl != "auto":
+        return None
+    from ..ops.pallas import _utils as kernels
+    from ..ops.pallas.decode_attention import (live_block,
+                                               live_decode_refusal)
+    kv_dt = jnp.int8 if cfg.kv_cache_dtype == "int8" else cfg.dtype
+    refusal = (_NO_WINDOW_KERNEL if window is not None else
+               live_decode_refusal(b, cfg.max_seq_len, cfg.num_heads,
+                                   cfg.head_dim, kv_dt, s)
+               or _decode_mesh_refusal())
+    if not kernels.auto_path("decode_attention", refusal):
+        return None
+    return live_block(cfg.max_seq_len)
+
+
 class SelfAttention(nn.Module):
     cfg: GPTConfig
     window: Optional[int] = None    # local-attention window (GPT-Neo style)
@@ -541,19 +570,27 @@ class SelfAttention(nn.Module):
     def _decode_attention(self, q, k, v, layer=None):
         """KV-cache attention (reference ``softmax_context`` kernel with
         cache append, inference/csrc/softmax.cu): writes this step's k/v at
-        ``cache_index`` and attends over the filled prefix. Under the
-        Pallas decode impl the cache lives FLAT [b, S, h*d]: XLA lane-pads
-        a trailing d=64 dim (to 128), so a rank-4 cache would pay a
-        full-cache relayout copy on every kernel call.
+        ``cache_index`` and attends over the filled prefix. The cache is
+        rank-4 [b, S, h, d] under ``decode_impl`` "auto" and "xla"; asked
+        for BY NAME ("pallas") the all-lanes kernel keeps it FLAT
+        [b, S, h*d], since XLA lane-pads a trailing d=64 dim (to 128) and a
+        rank-4 cache would pay a full-cache relayout copy on every call.
 
         ``layer`` None: the ``cache`` leaves are this layer's own
         ([b, S, ...]; the cache is being created, or the layers are not
         scanned). ``layer`` an index: the leaves are the layer-stacked
         [L, b, S, ...] arrays that the layer loop carries; the write lands
-        at ``(layer, row, pos)`` in place (:func:`_kv_write`) and attention
-        reads the layer's rows where they lie (:func:`_layer_rows`), so a
-        decode step produces no array of a layer's cache size. The
-        masked-lane sentinel drops the write in every layer either way.
+        at ``(layer, row, pos)`` in place (:func:`_kv_write`). What the
+        step then READS: under "auto" on a TPU, where
+        :func:`live_read_block` accepts the call, a kernel that is handed
+        the stacked leaves whole and DMAs ``ceil(fill / block)`` blocks of
+        each lane's rows (none of a masked lane); otherwise the masked
+        einsum over all S rows of the layer where they lie
+        (:func:`_layer_rows`, fused into the dot). Neither produces an
+        array of a layer's cache size. The flat kernel under "pallas" is
+        handed :func:`_layer_rows` of the leaf as a custom call's operand,
+        which IS a copy of the layer's rows. The masked-lane sentinel drops
+        the write in every layer either way.
 
         ``cache_index`` may be a scalar (every row at the same fill — the
         single-stream generate path) or a [b] vector (per-slot fills — the
@@ -588,19 +625,18 @@ class SelfAttention(nn.Module):
         from ..ops.pallas.decode_attention import decode_refusal
         int8 = cfg.kv_cache_dtype == "int8"
         kv_dt = jnp.int8 if int8 else cfg.dtype
-        # the cache LAYOUT follows the choice (flat for the kernel), so the
-        # gate is asked at the decode width s=1 whatever this call's width
-        refusal = ("the decode kernel has no local-window path"
-                   if self.window is not None else
-                   decode_refusal(b, cfg.max_seq_len, h, d, cfg.dtype)
-                   or _decode_mesh_refusal())
-        impl = cfg.decode_impl
-        if impl == "pallas" and refusal is not None:
-            kernels.refuse("decode_impl='pallas'",
-                           f"b={b} S={cfg.max_seq_len} h={h} d={d}", refusal)
-        use_flat = impl == "pallas" or (
-            impl == "auto" and kernels.auto_path("decode_attention",
-                                                 refusal))
+        use_flat = cfg.decode_impl == "pallas"
+        if use_flat:
+            # asked for by name: the flat cache LAYOUT follows, so the gate
+            # is asked at the decode width s=1 whatever this call's width
+            refusal = (_NO_WINDOW_KERNEL if self.window is not None else
+                       decode_refusal(b, cfg.max_seq_len, h, d, cfg.dtype)
+                       or _decode_mesh_refusal())
+            if refusal is not None:
+                kernels.refuse("decode_impl='pallas'",
+                               f"b={b} S={cfg.max_seq_len} h={h} d={d}",
+                               refusal)
+        live_read = live_read_block(cfg, b, s, self.window) is not None
         scale = (cfg.qk_scale if cfg.qk_scale is not None
                  else 1.0 / math.sqrt(d))
         idx = self.variable("cache", "cache_index",
@@ -641,8 +677,8 @@ class SelfAttention(nn.Module):
                 # blocks are DMA-streamed and dequantized in VMEM. s > 1
                 # is the k+1 speculative-verify shape, handled in-kernel
                 # by the s-position qmat, so the spec hot loop never
-                # materializes a dequantized f32 cache view
-                # (a carried cache hands the kernel its layer's slice)
+                # materializes a dequantized f32 cache view (a carried
+                # cache hands the kernel a COPY of its layer's rows)
                 return decode_attention(
                     q, rows(ck.value), rows(cv.value), cur + s, scale=scale,
                     k_scale=rows(ksc.value)[..., 0] if int8 else None,
@@ -670,6 +706,10 @@ class SelfAttention(nn.Module):
             ck.value = write(ck.value, k.astype(cfg.dtype))
             cv.value = write(cv.value, v.astype(cfg.dtype))
         idx.value = _set_layer_rows(idx.value, layer, cur + s)
+        if live_read:
+            from ..ops.pallas.decode_attention import live_decode_attention
+            return live_decode_attention(q, ck.value, cv.value, cur + s,
+                                         layer, scale=scale)
         if int8:
             from ..ops.quantizer import dequantize_kv
             kf = dequantize_kv(rows(ck.value), rows(ksc.value)[..., None],
@@ -981,6 +1021,16 @@ class GPT(nn.Module):
             # (moe/grouped.py::routing_counters) and the reference check reads
             return logits, {"expert_choice": expert_choice}
         return logits
+
+    @nn.nowrap
+    def decode_read_block(self, b: int) -> Optional[int]:
+        """Rows a block of what a one-token decode step of ``b`` lanes reads
+        of each lane's dense cache rows, None where the step reads every
+        row of every lane (:func:`live_read_block`; the latent block's
+        attention and a model with window layers read them all)."""
+        if self.cfg.block is not None or self.cfg.attn_windows is not None:
+            return None
+        return live_read_block(self.cfg, b)
 
     @nn.nowrap
     def routing_counters(self, routed, live):

@@ -1,31 +1,43 @@
-"""Fused KV-cache decode attention: read only the filled prefix.
+"""Decode attention over a KV cache: three reads of it, and their gates.
 
 Reference analogue: the ``softmax_context`` inference kernel
 (``csrc/transformer/inference/csrc/softmax.cu``) — single-token attention
-over the KV cache. The plain XLA decode path does O(max_seq_len) work per
-token regardless of fill (masked einsum over the whole cache).
+over the KV cache.
 
-This kernel makes both COMPUTE and HBM TRAFFIC O(cache_len): the cache
-stays in HBM and the kernel drives its own double-buffered DMA pipeline
-over a ``fori_loop`` whose trip count is the number of LIVE kv blocks (a
-scalar-prefetch operand). Dead blocks are never fetched — the
-splash-attention pattern applied to a dynamic prefix length. (The previous
-revision walked a grid over all of S with a clamped index_map; Mosaic
-re-issued the clamped block's DMA every dead step, so HBM traffic stayed
-O(max_seq_len) and XLA won.)
+* :func:`masked_cache_attention` — the ONE masked einsum, every model's
+  reference and what ``decode_impl="xla"``, every prefill, speculative,
+  fused-prefill, window and int8 call runs: it reads all S rows of every
+  lane whatever their fill.
+* :func:`live_decode_attention` — what ``decode_impl="auto"`` (the
+  default) runs for a one-token step on a TPU: ONE kernel call a layer is
+  handed the layer-stacked arena leaves ``[L, b, S, h, d]`` whole, in HBM,
+  as they lie (rank-4 rows at d % 128 == 0 have no padding, so nothing is
+  sliced, reshaped or copied on the way in), and DMAs
+  ``ceil(fill / 128)`` blocks of each lane's rows and nothing of a masked
+  lane. On ``serve-batch`` (8 lanes x 2048, a fifth of them live) the
+  16 layers' attention went from 6.18 ms (the einsum) to 1.33 ms
+  (my chip run, PR 29); a prefix-bounded einsum took 3.21 ms.
+* :func:`decode_attention` / :func:`paged_decode_attention` — the older
+  kernels, asked for BY NAME (``decode_impl="pallas"``,
+  ``megakernel=True``): all lanes ride one DMA window sized by the
+  DEEPEST lane over a FLAT ``[b, S, h*d]`` cache (int8 dequant and the
+  speculative width in the window). Their layout notes:
 
-Layout notes, the part that makes Mosaic happy AND fast:
-  * The cache rides FLATTENED as [b, S, h*d] — a free reshape of the
-    native [b, S, h, d] cache. The rank-4 layout tiles (h, d) and
-    lane-pads d (64 -> 128), which both doubles the DMA bytes and makes
-    dynamic sub-slices unaligned; the flat layout's (S, h*d) tiling is
-    exactly aligned, so a [bk, h*d] block is one contiguous DMA.
+  * The flat layout is a free reshape of ``[b, S, h, d]`` only when the
+    cache is STORED flat (models/gpt.py does under ``"pallas"``): rank-4
+    rows tile (h, d) and lane-pad d = 64 to 128, which doubles the DMA
+    bytes and makes dynamic sub-slices unaligned; ``(S, h*d)`` tiles
+    exactly, so a ``[bk, h*d]`` block is one contiguous DMA.
   * Per-head dots become ONE MXU matmul against a block-diagonal query
     matrix qmat [h*d, hp] (qmat[g*d + j, g] = q[g, j]):
     s = k_flat @ qmat. The combine p^T @ v_flat yields [hp, h*d] whose
     row g holds every head's segment weighted by head g's probabilities;
     the wrapper slices the block diagonal — 16x more output elements than
     needed, but the arrays are tiny and it keeps the hot loop on the MXU.
+  * Under a cache the layer loop carries they are handed a
+    ``dynamic_index_in_dim`` of the stacked leaf, which a custom call's
+    operand makes a COPY of the layer's rows every layer, every step; no
+    cell has timed them since the loop carries the cache (PR 25).
 """
 
 from __future__ import annotations
@@ -172,10 +184,13 @@ def _pick_block(s: int, want: int = 256) -> Optional[int]:
     return s if s <= 128 and s % 8 == 0 else None
 
 
-# Staging window budget (2 slots x k+v). At GPT-2 125M (b 8, h*d 768) the
-# 256-row bf16 window is 6 MiB and compiles on a v5e inside Mosaic's
-# default scoped VMEM limit (chip_smoke kernel leg, PR 21); nothing larger
-# has been through the compiler, so the gate stays here.
+# Staging window budget of the all-lanes kernels (2 slots x k+v x b lanes).
+# At GPT-2 125M (b 8, h*d 768) the 256-row bf16 window is 6 MiB and compiles
+# on a v5e inside Mosaic's scoped VMEM (chip_smoke kernel leg, PR 21). What
+# else has been through the compiler: the live-rows read's ONE-lane window
+# at h*d 4096, 4 MiB at 128 rows (5.7 MB of scoped VMEM with its scores) and
+# 8 MiB at 256 (PR 29, compiled for v5e and run); an all-lanes window there
+# would be 32 MiB, which this budget refuses.
 _VMEM_BUDGET = 8 * 1024 * 1024
 
 
@@ -205,10 +220,12 @@ def _choose_block(b: int, S: int, h: int, d: int, itemsize: int,
 
 def decode_refusal(b: int, S: int, h: int, d: int, dtype, s: int = 1,
                    block_k: Optional[int] = None) -> Optional[str]:
-    """Why the dense kernel cannot run this shape; None when it can.
-    Callers choosing a cache LAYOUT (models/gpt.py flat cache) ask the same
-    gate the kernel does. ``s``: query positions per lane (1 = plain
-    decode, 2..MAX_SPEC_S = the speculative-verify shape)."""
+    """Why the all-lanes FLAT-cache kernel (:func:`decode_attention`, asked
+    for by name) cannot run this shape; None when it can. Callers choosing
+    a cache LAYOUT (models/gpt.py flat cache) ask the same gate the kernel
+    does. ``s``: query positions per lane (1 = plain decode, 2..MAX_SPEC_S
+    = the speculative-verify shape). The default read's gate is
+    :func:`live_decode_refusal`: one lane's window, so no budget on b."""
     if not 1 <= s <= MAX_SPEC_S:
         return (f"query width s={s} outside 1..{MAX_SPEC_S} (wider calls "
                 f"are prefill and take the masked einsum)")
@@ -348,6 +365,202 @@ def decode_attention(q: jnp.ndarray, cached_key: jnp.ndarray,
     )(*operands)
     # block diagonal: (query i, head g)'s output is row i*hp+g, segment g
     return _slice_block_diagonal(out, s_q, h, d)
+
+
+# --------------------------------------------------------------------------
+# Dense decode over each lane's LIVE rows of the layer-stacked arena
+# --------------------------------------------------------------------------
+
+def _live_kernel(layer_ref, fill_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+                 v_buf, k_sem, v_sem, lane_of, blk_of, *, scale, block_k,
+                 b, S, h, d):
+    """One program for all lanes. k_hbm/v_hbm: the arena leaves
+    [L, b, S, h, d] WHOLE in HBM; k_buf/v_buf: [2, block_k, h, d] VMEM
+    slots. The scalar core first writes the step's schedule into SMEM —
+    one entry (lane, block) per LIVE block, lane after lane: a lane of fill
+    f has ceil(f / block_k) of them, a masked lane (f > S, the engine's
+    retired-lane sentinel) none — and ONE double-buffered loop then walks
+    it, so the DMA of a lane's first block is in flight while the lane
+    before it computes its last. Online-softmax state rides the loop carry
+    and starts anew at a lane's block 0; a lane's output is written at its
+    last block, a masked lane's stays zero.
+
+    Per block: the [block_k, h, d] rows are [block_k*h, d] to the MXU (a
+    free reshape, h being whole sublane tiles), the h queries meet all of
+    them in one dot on bf16 operands with float32 accumulation, and the
+    mask keeps of column (k, g) the row g alone (and k < fill), so the
+    rest of the body is flash attention with one query row a head."""
+    layer = layer_ref[0]
+
+    def lane_blocks(lane, n):
+        f = fill_ref[lane]
+        nb = jnp.where(f > S, 0, (f + block_k - 1) // block_k)
+
+        def put(j, n):
+            # stores to the kernel's SMEM scratch refs, not host state
+            lane_of[n] = lane   # tracelint: disable=mutation-in-trace
+            blk_of[n] = j       # tracelint: disable=mutation-in-trace
+            return n + 1
+        return jax.lax.fori_loop(0, nb, put, n)
+
+    total = jax.lax.fori_loop(0, b, lane_blocks, jnp.int32(0))
+
+    def copies(i, slot):
+        at = (layer, lane_of[i], pl.ds(blk_of[i] * block_k, block_k))
+        return (pltpu.make_async_copy(k_hbm.at[at], k_buf.at[slot],
+                                      k_sem.at[slot]),
+                pltpu.make_async_copy(v_hbm.at[at], v_buf.at[slot],
+                                      v_sem.at[slot]))
+
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(total > 0)
+    def _prologue():
+        for c in copies(0, 0):
+            c.start()
+
+    # column (k, g) of a block's [h, block_k*h] scores is key k under head
+    # g's rows: row g keeps it while k is under the lane's fill, no other
+    # row ever does
+    n = block_k * h
+    col = jax.lax.broadcasted_iota(jnp.int32, (h, n), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (h, n), 0)
+    own_key = jnp.where(col % h == row, col // h, S)   # S: never under a fill
+
+    def body(i, carry):
+        m_prev, l_prev, acc = carry                # [h,1] [h,1] [h,d]
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < total)
+        def _prefetch():
+            for c in copies(i + 1, 1 - slot):
+                c.start()
+
+        for c in copies(i, slot):
+            c.wait()
+        lane, blk = lane_of[i], blk_of[i]
+        fill = fill_ref[lane]
+        first = blk == 0
+        m_prev = jnp.where(first, NEG_INF, m_prev)
+        l_prev = jnp.where(first, 0.0, l_prev)
+        acc = jnp.where(first, 0.0, acc)
+        q = q_ref[lane]                                         # [h, d]
+        sc = jax.lax.dot_general(
+            q, k_buf[slot].reshape(n, d), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale         # [h, n]
+        sc = jnp.where(own_key < fill - blk * block_k, sc, NEG_INF)
+        # a scheduled block holds a live key, so every head's max is finite
+        # and a masked column's exp is an exact zero
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot(p.astype(v_buf.dtype), v_buf[slot].reshape(n, d),
+                         preferred_element_type=jnp.float32)    # [h, d]
+        acc = acc * corr + pv
+
+        @pl.when((blk + 1) * block_k >= fill)
+        def _lane_done():
+            o_ref[lane] = (acc / l_new).astype(o_ref.dtype)
+        return m_new, l_new, acc
+
+    jax.lax.fori_loop(
+        0, total, body,
+        (jnp.full((h, 1), NEG_INF, jnp.float32),
+         jnp.zeros((h, 1), jnp.float32), jnp.zeros((h, d), jnp.float32)))
+
+
+# Rows a DMA of the live-rows read carries. One lane's k+v window of 128
+# rows, double-buffered, is 4 MiB at h*d 4096 in bf16, and the [h, 128*h]
+# float32 scores and probabilities beside it 1 MiB each: inside Mosaic's
+# scoped VMEM on a v5e. 256 rows read more dead rows a lane and were slower
+# at every fill tried (1.43 against 1.33 ms for serve-batch's 16 layers; my
+# chip run, PR 29).
+_LIVE_BLOCK = 128
+
+
+def live_block(S: int) -> int:
+    """Rows a DMA of the live-rows read carries over a cache of S rows."""
+    return min(_LIVE_BLOCK, S)
+
+
+def live_decode_refusal(b: int, S: int, h: int, d: int, dtype, s: int = 1,
+                        block_k: Optional[int] = None) -> Optional[str]:
+    """Why :func:`live_decode_attention` cannot run this shape; None when
+    it can. It reads the rank-4 rows as they lie, so it takes what lies
+    without padding: a head of whole 128-lane rows, heads in whole sublane
+    tiles, a plain floating cache, one query a lane."""
+    if s != 1:
+        return ("more than one query a lane: the live-rows read takes the "
+                "decode width alone (prefill, speculative and fused-prefill "
+                "widths take the masked einsum)")
+    dt = jnp.dtype(dtype)
+    if not jnp.issubdtype(dt, jnp.floating):
+        return (f"cache dtype {dt.name}: the live-rows read has no dequant "
+                f"in its window")
+    sublane = 32 // dt.itemsize
+    if d % 128 != 0:
+        return (f"head size d={d} is not whole 128-lane rows: a rank-4 "
+                f"[b, S, h, d] leaf is lane-padded in HBM")
+    if h % sublane != 0:
+        return (f"h={h} heads are not whole {sublane}-row sublane tiles of "
+                f"{dt.name}")
+    bk = block_k or live_block(S)
+    if S % bk != 0:
+        return (f"cache length {S} is not a multiple of the {bk}-row block")
+    return None
+
+
+def live_decode_attention(q: jnp.ndarray, k_leaf: jnp.ndarray,
+                          v_leaf: jnp.ndarray, fills, layer=None,
+                          scale: Optional[float] = None,
+                          block_k: Optional[int] = None) -> jnp.ndarray:
+    """q: [b, 1, h, d]. k_leaf/v_leaf: the cache leaves as the layer loop
+    carries them, [L, b, S, h, d] with ``layer`` a (traced) index, or one
+    layer's [b, S, h, d] with ``layer`` None. ``fills``: valid positions a
+    lane (this token included, already written), scalar or [b]; a lane
+    whose fill is past S is MASKED (the serving engine's retired-lane
+    sentinel writes at ``max_seq_len``): nothing of it is read and its
+    output is zeros the caller discards. Reads ceil(fill / block) blocks
+    of each lane's rows and nothing else of the leaf; no slice or reshape
+    of the leaf is made on the way in. Returns [b, 1, h, d]."""
+    b, s_q, h, d = q.shape
+    if layer is None:
+        k_leaf, v_leaf, layer = k_leaf[None], v_leaf[None], 0
+    S = k_leaf.shape[2]
+    reason = live_decode_refusal(b, S, h, d, k_leaf.dtype, s_q, block_k)
+    if reason is not None:
+        refuse("live_decode_attention",
+               f"q={q.shape} cache={k_leaf.shape}", reason)
+    bk = block_k or live_block(S)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    fills = jnp.broadcast_to(jnp.asarray(fills, jnp.int32), (b,))
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    kernel = functools.partial(_live_kernel, scale=scale, block_k=bk, b=b,
+                               S=S, h=h, d=d)
+    n_max = b * (S // bk)
+    whole = pl.BlockSpec((b, h, d), lambda g, layer, fills: (0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,              # layer index + per-lane fills
+        grid=(1,),
+        in_specs=[whole, pl.BlockSpec(memory_space=pltpu.HBM),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=whole,
+        scratch_shapes=[
+            pltpu.VMEM((2, bk, h, d), k_leaf.dtype),
+            pltpu.VMEM((2, bk, h, d), v_leaf.dtype),
+            pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((n_max,), jnp.int32), pltpu.SMEM((n_max,), jnp.int32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        name="decode_attention_live",
+        interpret=interpret_mode(),
+    )(layer, fills, q.reshape(b, h, d), k_leaf, v_leaf)
+    return out[:, None]
 
 
 # --------------------------------------------------------------------------

@@ -193,12 +193,17 @@ class ServingEngine:
             cfg = dataclasses.replace(cfg, kv_cache_dtype="int8")
             self.module = type(self.module)(cfg)
         # ---- fused decode megakernel ----
-        # One knob flips the decode stack onto the fused fast path: the
-        # Pallas decode kernel (int8 dequant inside the DMA window,
-        # in-kernel k+1 speculative verify) on the chip — on the CPU mesh
-        # the decode stays on the partition-friendly einsum, where Pallas
-        # would only interpret, so CPU parity gates run the program they
-        # always did — the sort-free sampling epilogue
+        # One knob asks for the fused decode stack BY NAME. On the chip the
+        # module is rebuilt with ``decode_impl="pallas"``: the all-lanes
+        # decode kernel over a FLAT cache (int8 dequant inside the DMA
+        # window, in-kernel k+1 speculative verify; its window is sized by
+        # the deepest lane and, under the carried cache, it is handed a
+        # copy of its layer's rows — the default ``"auto"`` read of each
+        # lane's live blocks, models/gpt.py::live_read_block, is NOT what
+        # this knob runs). On the CPU mesh the decode stays on the
+        # partition-friendly einsum, where Pallas would only interpret, so
+        # CPU parity gates run the program they always did. With it: the
+        # sort-free sampling epilogue
         # (ops/pallas/sampling.py, swapped in below), and — when the mesh
         # has a tp axis under a parallel-residual model — the RS/AG
         # collective/MLP overlap (ops/tp_overlap.py). The knob asks for
@@ -213,7 +218,7 @@ class ServingEngine:
         if self.megakernel:
             from ..utils.platform import on_chip
             rebuild = {}
-            if on_chip() and getattr(cfg, "decode_impl", None) == "xla":
+            if on_chip() and getattr(cfg, "decode_impl", "pallas") != "pallas":
                 rebuild["decode_impl"] = "pallas"
             if (self.tp > 1 and getattr(cfg, "parallel_residual", False)
                     and hasattr(cfg, "tp_overlap")):
@@ -892,6 +897,18 @@ class ServingEngine:
             self._gauge_block_pool()
         else:
             self._arena_bytes_per_slot = arena["bytes_per_slot"]
+        # what a decode step reads of the dense arena, for the
+        # serve/kv_blocks_* counters: the block of the live-rows read where
+        # the chunk program takes it (the model says; the plain one-token
+        # chunk body alone can), else every row of every lane
+        from ..ops.pallas.decode_attention import live_block
+        read_block = getattr(self.module, "decode_read_block", None)
+        self._kv_read_block = None
+        if read_block is not None and not (
+                self.paged or self.speculative or self.fused_prefill):
+            self._kv_read_block = read_block(self.max_batch)
+        self._kv_count_block = (self._kv_read_block
+                                or live_block(self.max_seq_len))
         log_dist(f"serving engine ready: slots={self.max_batch} "
                  f"prefill_buckets={self._buckets} "
                  f"decode_chunk={self.decode_chunk} "
@@ -932,7 +949,8 @@ class ServingEngine:
                    "mesh (ROADMAP R9)")
         if cfg.decode_impl != "pallas":
             return
-        h, d = int(cfg.num_heads), self.kv.head_dim(cfg.num_heads)
+        # (asked before the arena exists: the config's own head size)
+        h, d = int(cfg.num_heads), int(cfg.head_dim)
         # query positions per decode-scan step
         width = (self.spec_k + 1) if self.speculative else 1
         if self.fused_prefill:
@@ -1873,6 +1891,29 @@ class ServingEngine:
                                slot_uids=dict(inflight.slot_uids))
         return inflight
 
+    def _count_kv_read(self, per_slot: Dict[int, List[int]]) -> None:
+        """Count the blocks of one layer's rows that the chunk's steps read
+        (``serve/kv_blocks_read``) beside the blocks its K steps would read
+        of the whole arena (``serve/kv_blocks_arena``), from what the host
+        holds before the chunk's tokens advance the fills: a lane that
+        entered the chunk with ``fill`` rows written and was live for n
+        steps read ``ceil((fill + j) / block)`` blocks in step j = 1..n.
+        Where the step reads every row (the einsum, the paged pool, the
+        speculative and fused widths) the two are equal."""
+        block = self._kv_count_block
+        arena = self.decode_chunk * self.max_batch * \
+            -(-self.max_seq_len // block)
+        read = arena
+        if self._kv_read_block is not None:
+            fill = self.kv.allocator.fill
+            read = sum(
+                int(np.sum(-(-(int(fill[slot]) + np.arange(1, len(seq) + 1))
+                             // block)))
+                for slot, seq in per_slot.items())
+        telemetry.count("serve/kv_blocks_read", float(read))
+        telemetry.count("serve/kv_blocks_arena", float(arena))
+        self.metrics.on_kv_read(read, arena)
+
     def _consume_chunk(self, chunk: _InflightChunk, *,
                        device_queue_empty: bool) -> List[Request]:
         """Block on the chunk's token buffer (the ONE host sync per K
@@ -1938,6 +1979,7 @@ class ServingEngine:
                 if seq:
                     per_slot[slot] = seq
                     self._last_token[slot] = seq[-1]
+            self._count_kv_read(per_slot)
             self.scheduler.step_tokens_chunk(per_slot)
             finished = self.scheduler.finished[fin_before:]
         n_tokens = sum(len(v) for v in per_slot.values())

@@ -130,6 +130,8 @@ class ServingMetrics:
         self.n_cow_forks = 0
         self.spec_proposed = 0
         self.spec_accepted = 0
+        self.kv_blocks_read = 0
+        self.kv_blocks_arena = 0
 
     # ----------------------------------------------------------- recording
     def start(self) -> None:
@@ -186,7 +188,20 @@ class ServingMetrics:
         self.spec_proposed += int(proposed)
         self.spec_accepted += int(accepted)
 
+    def on_kv_read(self, read: int, arena: int) -> None:
+        """One decode chunk consumed: blocks of a layer's KV rows its steps
+        read, and the blocks those steps would read of the whole arena."""
+        self.kv_blocks_read += int(read)
+        self.kv_blocks_arena += int(arena)
+
     # ------------------------------------------------------------ reading
+    @property
+    def kv_read_share(self) -> float:
+        """Share of the arena's rows a decode step read, in blocks (1.0
+        where the step reads every row; 0.0 before the first chunk)."""
+        return (self.kv_blocks_read / self.kv_blocks_arena
+                if self.kv_blocks_arena else 0.0)
+
     @property
     def padding_waste(self) -> float:
         """Fraction of padded prefill positions that carried no prompt
@@ -237,6 +252,7 @@ class ServingMetrics:
             "serving/prefix_hit_rate": float(self.prefix_hit_rate),
             "serving/cow_forks": float(self.n_cow_forks),
             "serving/spec_acceptance_rate": float(self.spec_acceptance_rate),
+            "serving/kv_read_share": float(self.kv_read_share),
         }
 
     # ------------------------------------------------------------ emitting

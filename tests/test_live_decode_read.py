@@ -1,0 +1,297 @@
+"""The decode step's read of each lane's LIVE rows (ISSUE 29):
+``ops/pallas/decode_attention.live_decode_attention`` against the masked
+einsum, its gate, the model's choice (``models/gpt.live_read_block``), the
+serving engine through it, and the two counters that say what a step read.
+
+On the CPU the kernel runs in the Pallas interpreter and ``"auto"`` takes
+the einsum, so the tests that drive the kernel through the model force the
+choice past ``kernels.auto_path`` (the gate still decides)."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.models.gpt import GPT, GPTConfig, live_read_block
+from deepspeed_tpu.ops.pallas import _utils as kernels
+from deepspeed_tpu.ops.pallas import decode_attention as da
+from deepspeed_tpu.ops.pallas.decode_attention import (
+    live_decode_attention, live_decode_refusal, masked_cache_attention)
+from deepspeed_tpu.serving import ServingEngine
+
+L, B, S, D, BK = 3, 5, 64, 128, 16
+SENTINEL = S + 1        # the fill of a lane whose write position is max_seq
+
+
+def _leaves(dtype, h, seed=0):
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    kl = jax.random.normal(k1, (L, B, S, h, D), dtype)
+    vl = jax.random.normal(k2, (L, B, S, h, D), dtype)
+    q = jax.random.normal(k3, (B, 1, h, D), dtype)
+    return q, kl, vl
+
+
+@pytest.mark.parametrize("fills", [
+    (1, BK, BK + 1, S, SENTINEL),           # the edges, a masked lane last
+    (SENTINEL, 2 * BK, SENTINEL, 7, S - 1),     # masked lanes between live
+    (S, S, S, S, S),                        # every block of every lane
+    (SENTINEL,) * B,                        # nothing live: no DMA at all
+], ids=["edges", "masked-between", "full", "all-masked"])
+@pytest.mark.parametrize("dtype,h,tol", [(jnp.bfloat16, 16, 2e-2),
+                                         (jnp.float32, 8, 2e-5)],
+                         ids=["bf16", "f32"])
+def test_live_rows_read_matches_the_masked_einsum(dtype, h, tol, fills):
+    """A layer-stacked leaf at a TRACED layer index: live lanes agree with
+    ``masked_cache_attention`` over that layer's rows to the einsum's own
+    tolerance, a masked lane's output is zeros nobody reads."""
+    q, kl, vl = _leaves(dtype, h)
+    fills = jnp.asarray(fills, jnp.int32)
+    layer = 1
+    got = jax.jit(lambda q, kl, vl, f, i: live_decode_attention(
+        q, kl, vl, f, i, block_k=BK))(q, kl, vl, fills, jnp.int32(layer))
+    assert got.shape == q.shape and got.dtype == q.dtype
+    ref = masked_cache_attention(q, kl[layer], vl[layer], fills - 1,
+                                 1.0 / np.sqrt(D))
+    live = np.asarray(fills) <= S
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    np.testing.assert_allclose(got[live], ref[live], atol=tol, rtol=tol)
+    assert not got[~live].any()
+
+
+def test_one_layers_own_leaf_and_a_scalar_fill():
+    """``layer`` None is the unscanned model's [b, S, h, d] leaf, a scalar
+    fill the single-stream ``generate()``: the same read."""
+    q, kl, vl = _leaves(jnp.float32, 8, seed=1)
+    got = live_decode_attention(q, kl[2], vl[2], jnp.int32(BK + 3),
+                                block_k=BK)
+    ref = masked_cache_attention(q, kl[2], vl[2], BK + 2, 1.0 / np.sqrt(D))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+REFUSED = {
+    "speculative width": (dict(s=5), "more than one query"),
+    "prefill width": (dict(s=512), "more than one query"),
+    "int8 cache": (dict(dtype=jnp.int8), "no dequant"),
+    "head of 64": (dict(d=64), "lane-padded"),
+    "12 heads": (dict(h=12), "sublane"),
+    "ragged length": (dict(S=2000), "not a multiple"),
+}
+
+
+@pytest.mark.parametrize("case", list(REFUSED))
+def test_the_gate_names_why_it_refuses(case):
+    shape = dict(b=8, S=2048, h=32, d=128, dtype=jnp.bfloat16, s=1)
+    over, names = REFUSED[case]
+    shape.update(over)
+    reason = live_decode_refusal(**shape)
+    assert reason is not None and names in reason, reason
+    with pytest.raises(kernels.KernelUnsupported, match=names):
+        s, dt = shape["s"], shape["dtype"]
+        live_decode_attention(
+            jnp.zeros((shape["b"], s, shape["h"], shape["d"]), jnp.bfloat16),
+            *(jnp.zeros((2, shape["b"], shape["S"], shape["h"],
+                         shape["d"]), dt),) * 2,
+            jnp.ones((shape["b"],), jnp.int32), 0)
+
+
+def test_the_gate_accepts_the_cells_shape():
+    """``serve-batch``: 8 lanes of 2048 rows, 32 heads of 128, bf16."""
+    assert live_decode_refusal(8, 2048, 32, 128, jnp.bfloat16) is None
+    assert da.live_block(2048) == 128
+
+
+@pytest.fixture
+def past_auto_path(monkeypatch):
+    """``"auto"`` resolved as on the chip: the kernel where its gate
+    accepts. What was asked and answered is handed to the test."""
+    asked = []
+
+    def auto_path(kernel, refusal):
+        asked.append((kernel, refusal))
+        return refusal is None
+    monkeypatch.setattr(kernels, "auto_path", auto_path)
+    return asked
+
+
+def _cell_cfg(**kw):
+    return GPTConfig(vocab_size=50432, max_seq_len=2048, num_layers=16,
+                     num_heads=32, d_model=4096, d_ff=16384, rotary=True,
+                     rotary_pct=0.25, parallel_residual=True,
+                     tie_embeddings=False, dtype=jnp.bfloat16, **kw)
+
+
+def test_the_default_is_auto_and_the_cpu_keeps_the_einsum():
+    assert GPTConfig().decode_impl == "auto"
+    assert live_read_block(_cell_cfg(), 8) is None      # Pallas would interpret
+
+
+@pytest.mark.parametrize("case,kw,call,block", [
+    ("the cell", {}, dict(s=1), 128),
+    ("speculative width", {}, dict(s=5), None),
+    ("fused-prefill width", {}, dict(s=16), None),
+    ("window layer", {}, dict(s=1, window=256), None),
+    ("int8 cache", dict(kv_cache_dtype="int8"), dict(s=1), None),
+    ("xla by name", dict(decode_impl="xla"), dict(s=1), None),
+    ("the flat kernel by name", dict(decode_impl="pallas"), dict(s=1), None),
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_what_the_model_chooses_from_what_a_trace_sees(past_auto_path, case,
+                                                       kw, call, block):
+    assert live_read_block(_cell_cfg(**kw), 8, **call) == block
+    if block is None and "decode_impl" not in kw:
+        (kernel, refusal), = past_auto_path
+        assert kernel == "decode_attention" and refusal      # named, logged
+
+
+def _tiny_engine(max_seq=48):
+    import deepspeed_tpu as ds
+    cfg = GPTConfig(vocab_size=64, max_seq_len=max_seq, num_layers=2,
+                    num_heads=8, d_model=8 * D, d_ff=64, dtype=jnp.float32,
+                    param_dtype=jnp.float32, remat=False)
+    model = GPT(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 4), jnp.int32))["params"]
+    return ds.init_inference(model, model_parameters=params,
+                             dtype=jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def tiny_engine():
+    return _tiny_engine()
+
+
+@pytest.fixture
+def blocks_of_16(monkeypatch):
+    monkeypatch.setattr(da, "_LIVE_BLOCK", BK)
+
+
+@pytest.mark.parametrize("decode_chunk", [1, 8])
+def test_greedy_parity_with_generate_through_the_live_read(
+        tiny_engine, past_auto_path, blocks_of_16, decode_chunk):
+    """More requests than lanes, fills that cross block edges, lanes that
+    retire mid-chunk (the sentinel beside live lanes): every request's
+    tokens are ``generate()``'s, both through the kernel."""
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 64, (n,)).astype(np.int32)
+               for n in [3, 15, 5, 9, 14, 6]]
+    serving = ServingEngine(engine=tiny_engine, max_batch=3,
+                            max_prompt_len=16, max_queue=8,
+                            decode_chunk=decode_chunk)
+    assert serving._kv_read_block == BK
+    results = serving.run(prompts, max_new_tokens=20)
+    assert ("decode_attention", None) in past_auto_path
+    for p, r in zip(prompts, results):
+        assert r.status == "done"
+        ref = np.asarray(tiny_engine.generate(
+            p[None], max_new_tokens=20, temperature=0.0))[0]
+        np.testing.assert_array_equal(r.output_ids, ref)
+    m = serving.metrics
+    assert 0 < m.kv_blocks_read < m.kv_blocks_arena
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_the_chunk_program_slices_no_arena_leaf(tiny_engine, past_auto_path):
+    """The kernel is handed the layer-stacked leaves WHOLE: no
+    ``dynamic_slice`` anywhere in the chunk program produces a layer's rows
+    (a custom call's operand has to exist, so a slice of the leaf before it
+    is a copy of a layer's cache every layer, every step)."""
+    serving = ServingEngine(engine=tiny_engine, max_batch=3,
+                            max_prompt_len=16, max_queue=8, decode_chunk=8)
+    b, s, h = 3, 48, 8
+    state = (jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
+             jnp.ones((b,), bool), jnp.full((b,), -1, jnp.int32),
+             jnp.full((b,), 4, jnp.int32))
+    jaxpr = jax.make_jaxpr(serving._jit_decode_chunk)(
+        serving._decode_params, serving.kv.cache, *state,
+        jax.random.PRNGKey(0)).jaxpr
+    eqns = list(_eqns(jaxpr))
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert calls, "the forced chunk program holds no kernel"
+    for call in calls:
+        leaves = [v.aval.shape for v in call.invars
+                  if len(v.aval.shape) == 5]
+        assert leaves == [(2, b, s, h, D)] * 2, leaves
+    rows = {(b, s, h, D), (1, b, s, h, D)}
+    sliced = [e for e in eqns
+              if e.primitive.name in ("dynamic_slice", "gather", "slice")
+              and any(v.aval.shape in rows for v in e.outvars)]
+    assert not sliced, sliced
+
+
+def _run_one(engine, prompt_len, new_tokens, decode_chunk):
+    serving = ServingEngine(engine=engine, max_batch=3, max_prompt_len=16,
+                            max_queue=8, decode_chunk=decode_chunk)
+    prompt = np.arange(prompt_len, dtype=np.int32) % 64
+    res, = serving.run([prompt], max_new_tokens=new_tokens)
+    assert res.status == "done" and len(res.tokens) == new_tokens
+    return serving
+
+
+def test_the_counters_against_a_hand_count(tiny_engine, past_auto_path,
+                                           blocks_of_16, telemetry_on):
+    """One request, 14 prompt rows, 6 tokens: prefill samples the first,
+    five decode steps read fills 15..19 of one lane in blocks of 16:
+    1 + 1 + 2 + 2 + 2 = 8 blocks. The arena a step would read is 3 lanes x
+    48 / 16 blocks, 8 steps a chunk."""
+    serving = _run_one(tiny_engine, 14, 6, decode_chunk=8)
+    m = serving.metrics
+    chunks = m.decode_steps
+    assert m.kv_blocks_read == 8
+    assert m.kv_blocks_arena == chunks * 8 * 3 * 3
+    totals = telemetry_on.counter_totals()
+    assert totals["serve/kv_blocks_read"] == 8
+    assert totals["serve/kv_blocks_arena"] == m.kv_blocks_arena
+    assert m.snapshot(0, 0.0)["serving/kv_read_share"] == \
+        pytest.approx(8 / m.kv_blocks_arena)
+
+
+@pytest.mark.parametrize("family", [{}, dict(speculative=True, spec_k=3),
+                                    dict(paged=True, kv_block_size=8)],
+                         ids=["dense", "speculative", "paged"])
+def test_the_einsum_path_counts_the_whole_arena(tiny_engine, family):
+    """On the CPU, and at every width and layout the gate refuses, a step
+    reads every row of every lane: the share is 100 %."""
+    serving = ServingEngine(engine=tiny_engine, max_batch=3,
+                            max_prompt_len=16, max_queue=8, decode_chunk=8,
+                            **family)
+    assert serving._kv_read_block is None
+    serving.run([np.arange(9, dtype=np.int32)], max_new_tokens=6)
+    m = serving.metrics
+    assert m.kv_blocks_read == m.kv_blocks_arena > 0
+    assert m.kv_read_share == 1.0
+
+
+@pytest.mark.parametrize("heads,d_model,refused", [(12, 768, None),
+                                                   (3, 60, r"h\*d=60")],
+                         ids=["125m", "h*d=60"])
+def test_megakernels_gate_is_asked_before_the_arena_exists(
+        monkeypatch, heads, d_model, refused):
+    """``megakernel=True`` on the chip rebuilds the module with
+    ``decode_impl="pallas"`` and asks the all-lanes kernel's gate at
+    construction, BEFORE ``self.kv`` is built (on a v5e the check read
+    ``self.kv`` and died with an AttributeError: my chip run, PR 29; the
+    CPU never reaches that line). Driven here on a stand-in for the
+    half-built engine with the platform patched."""
+    from types import SimpleNamespace
+    from deepspeed_tpu.utils import platform
+    monkeypatch.setattr(platform, "on_chip", lambda: True)
+    cfg = GPTConfig(vocab_size=50304, max_seq_len=1024, num_layers=1,
+                    num_heads=heads, d_model=d_model, d_ff=64,
+                    dtype=jnp.bfloat16, decode_impl="pallas")
+    half_built = SimpleNamespace(
+        max_batch=8, max_seq_len=1024, engine=SimpleNamespace(mesh=None),
+        speculative=False, spec_k=0, fused_prefill=False, prefill_chunk=16,
+        kv_dtype="auto", paged=False)
+    check = lambda: ServingEngine._check_megakernel_gates(half_built, cfg, 16)
+    if refused is None:
+        check()
+    else:
+        with pytest.raises(kernels.KernelUnsupported, match=refused):
+            check()

@@ -287,6 +287,49 @@ def test_compiled_for_v5e_2x2_gathers_one_layer_in_the_loop(v5e_2x2,
         mesh_lib.reset_global_mesh()
 
 
+def test_live_rows_decode_read_compiles_for_v5e_at_serve_batchs_shape(
+        v5e_2x2, monkeypatch):
+    """The serving path's kernel, here because ONE file of a test run may
+    load the TPU's compiler (the fixture above): Mosaic takes
+    ``live_decode_attention`` at 8 lanes x 2048 rows x 32 heads of 128 in
+    bf16 inside a layer loop that carries the two 2.1 GB leaves and writes
+    a token into them first, and the program holds no copy of a layer's
+    rows (temporaries of kilobytes beside 4.29 GB of aliased arena)."""
+    from jax.sharding import SingleDeviceSharding
+    from deepspeed_tpu.models.gpt import _kv_write
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(da, "interpret_mode", lambda: False)
+    L, b, S, h, d = 16, 8, 2048, 32, 128
+
+    def step(x, kl, vl, cur):
+        def body(c, layer):
+            x, kl, vl = c
+            q = x.reshape(b, 1, h, d)
+            kl, vl = _kv_write(kl, q, cur, layer), _kv_write(vl, q, cur,
+                                                             layer)
+            o = da.live_decode_attention(q, kl, vl, cur + 1, layer)
+            return (o.reshape(b, h * d), kl, vl), None
+        return jax.lax.scan(body, (x, kl, vl),
+                            jnp.arange(L, dtype=jnp.int32))[0]
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+    leaf = jax.ShapeDtypeStruct((L, b, S, h, d), jnp.bfloat16, sharding=one)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+            jax.ShapeDtypeStruct((b, h * d), jnp.bfloat16, sharding=one),
+            leaf, leaf,
+            jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * L * b * S * h * d * 2
+    assert mem.temp_size_in_bytes < 4 * 2 ** 20, mem
+
+
 # ------------------------------------------------------------------ parity
 @pytest.mark.parametrize("layers,mesh", SHAPES, ids=["dp4xtp2", "dp8"])
 @pytest.mark.parametrize("stage", [1, 2, 3])
