@@ -212,7 +212,7 @@ class GPTConfig:
     rotary: bool = False             # False: learned positions (GPT-2)
     rotary_pct: float = 1.0
     rotary_base: float = 10000.0
-    block: Any = None   # None: this file's block; a models/mla.py LatentBlockConfig
+    block: Any = None   # None: this file's block; else a kind's config (_kind)
     parallel_residual: bool = False  # True for NeoX
     # Decode-time tp collective/MLP overlap (ops/tp_overlap.py): the attention
     # branch's output is pinned hidden-sharded so that GSPMD splits its
@@ -897,7 +897,7 @@ class GPT(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, deterministic=True, positions=None,
-                 pld_theta=None):
+                 pld_theta=None, lengths=None):
         cfg = self.cfg
         b, s = input_ids.shape
         if positions is None:
@@ -998,37 +998,40 @@ class GPT(nn.Module):
                                                   **extra)
                 moe_aux = moe_aux + aux
         else:
-            # dense prefix, then scanned expert layers, over ONE
-            # layer-stacked latent cache leaf (models/mla.py)
-            from .mla import LatentStack, RMSNorm
-            x, expert_choice = LatentStack(cfg, name="blocks")(x, positions)
+            # another kind of block: its own stack of layers over its own
+            # cache leaves, and what it hands out beside the logits
+            kind = _kind(cfg.block)
+            x, handed = kind.Stack(cfg, name="blocks")(x, positions, lengths)
 
         if cfg.block is None:
             x = nn.LayerNorm(epsilon=cfg.layer_norm_eps, dtype=cfg.dtype,
                              param_dtype=cfg.param_dtype, name="ln_f")(x)
         else:
-            x = RMSNorm(cfg, name="ln_f")(x)
-        if cfg.tie_embeddings:
+            x = kind.FinalNorm(cfg, name="ln_f")(x)
+        if cfg.block is not None and hasattr(kind, "Head"):
+            logits = kind.Head(cfg, name="lm_head")(x)
+        elif cfg.tie_embeddings:
             logits = embed.attend(x)
         else:
             logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                               param_dtype=cfg.param_dtype, name="lm_head")(x)
         if cfg.moe:
             return logits, cfg.moe_aux_loss_coef * moe_aux
-        if cfg.block is not None and expert_choice is not None:
-            # [expert layers, b, s, k] ids of the experts each token chose,
-            # of the PUBLISHED router width: what the serving programs count
-            # (moe/grouped.py::routing_counters) and the reference check reads
-            return logits, {"expert_choice": expert_choice}
+        if cfg.block is not None and handed is not None:
+            # what the serving programs count and the reference check reads
+            # (the latent kind: the experts each token chose)
+            return logits, handed
         return logits
 
     @nn.nowrap
     def decode_read_block(self, b: int) -> Optional[int]:
         """Rows a block of what a one-token decode step of ``b`` lanes reads
         of each lane's dense cache rows, None where the step reads every
-        row of every lane (:func:`live_read_block`; the latent block's
-        attention and a model with window layers read them all)."""
-        if self.cfg.block is not None or self.cfg.attn_windows is not None:
+        row of every lane (:func:`live_read_block`; a model with window
+        layers reads them all; another kind of block answers for itself)."""
+        if self.cfg.block is not None:
+            return _kind(self.cfg.block).decode_read_block(self.cfg, b)
+        if self.cfg.attn_windows is not None:
             return None
         return live_read_block(self.cfg, b)
 
@@ -1036,13 +1039,43 @@ class GPT(nn.Module):
     def routing_counters(self, routed, live):
         """What a call's expert layers routed, as scalars a serving program
         sums on the device: ``routed`` is the dict a model with expert layers
-        returns beside its logits, ``live [b, s]`` the tokens that count
-        (moe/grouped.py::routing_counters)."""
-        from ..moe.grouped import routing_counters
-        block = self.cfg.block
-        return routing_counters(routed["expert_choice"], live,
-                                expert_offset=block.expert_offset,
-                                experts_held=block.experts_held)
+        returns beside its logits, ``live [b, s]`` the tokens that count."""
+        return _kind(self.cfg.block).routing_counters(self.cfg, routed, live)
+
+    @property
+    def prefill_takes_lengths(self) -> bool:
+        """A call that creates a cache from padded rows has to be told where
+        each row ends (``lengths [b]``): the cache this kind of block hands
+        out depends on it, where a cache of one row a position just leaves
+        the padding above the fill."""
+        return getattr(_kind(self.cfg.block), "PREFILL_TAKES_LENGTHS", False)
+
+    @nn.nowrap
+    def lane_rows(self) -> int:
+        """Rows of state one lane holds in one layer: a row a position,
+        unless the block's kind keeps another count."""
+        rows = getattr(_kind(self.cfg.block), "lane_rows", None)
+        return self.cfg.max_seq_len if rows is None else rows(self.cfg)
+
+    @nn.nowrap
+    def step_counters(self, positions, live):
+        """What one decode step of lanes at ``positions [b]`` read of their
+        state, as named scalars a serving program sums on the device over
+        the lanes ``live [b]``; None for a kind of block that counts
+        nothing there."""
+        count = getattr(_kind(self.cfg.block), "step_counters", None)
+        return None if count is None else count(self.cfg, positions, live)
+
+
+def _kind(block):
+    """The module that defines a block kind's config is the kind's door:
+    ``Stack(cfg)(x, positions, lengths) -> (x, handed out or None)``,
+    ``FinalNorm(cfg)``, ``decode_read_block(cfg, b)``; where it has them
+    ``Head(cfg)``, ``routing_counters(cfg, routed, live)``,
+    ``PREFILL_TAKES_LENGTHS``, ``lane_rows(cfg)``, ``step_counters(cfg,
+    positions, live)`` (models/mla.py, models/eva.py). None: this file."""
+    import sys
+    return sys.modules[type(block).__module__] if block is not None else None
 
 
 def _layer(cfg):

@@ -300,12 +300,12 @@ _BANKS = ("expert_gate", "expert_up", "expert_down")
 class LatentStack(nn.Module):
     """``dense_layers`` blocks with the dense FFN, then the expert layers
     under one ``lax.scan``, all over the one latent cache leaf. Returns the
-    hidden state and the experts chosen ``[expert layers, b, s, k]`` (None
-    for a stack without expert layers)."""
+    hidden state and ``{"expert_choice": [expert layers, b, s, k]}`` of the
+    PUBLISHED router width (None without expert layers); reads no ``lengths``."""
     cfg: object
 
     @nn.compact
-    def __call__(self, x, positions):
+    def __call__(self, x, positions, lengths=None):
         cfg, lc = self.cfg, self.cfg.block
         b, s, _ = x.shape
         n_dense = min(lc.dense_layers, cfg.num_layers)
@@ -358,5 +358,24 @@ class LatentStack(nn.Module):
         if caching:
             lat.value = latent
             idx.value = cur + s
-        return x, choice
+        return x, (None if choice is None else {"expert_choice": choice})
+
+
+# ---- what models/gpt.py asks of a block kind's module ---------------------
+Stack = LatentStack
+FinalNorm = RMSNorm
+
+
+def decode_read_block(cfg, b: int):
+    """Absorbed attention reads every row of every lane's latent."""
+    return None
+
+
+def routing_counters(cfg, routed, live):
+    """moe/grouped.py::routing_counters over what :class:`LatentStack`
+    handed out, for the experts this chip holds."""
+    from ..moe.grouped import routing_counters as count
+    return count(routed["expert_choice"], live,
+                 expert_offset=cfg.block.expert_offset,
+                 experts_held=cfg.block.experts_held)
 

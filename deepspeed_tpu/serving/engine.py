@@ -470,11 +470,21 @@ class ServingEngine:
                 return out[0], None
             return out[0], count_routing(out[1], live)
 
+        # a model whose cache is not a row a position (a window beside chunk
+        # summaries) is told where each padded prompt ends, and says what a
+        # decode step read of its lanes' state, from their positions alone
+        def told(true_lens):
+            if getattr(module, "prefill_takes_lengths", False):
+                return {"lengths": true_lens}
+            return {}
+
+        count_state = getattr(module, "step_counters", None)
+
         def prefill(params, ids, true_lens, rng):
             pm = mat(params)
             positions = jnp.arange(ids.shape[1])[None, :]
-            out, vc = module.apply({"params": pm}, ids,
-                                   positions=positions, mutable=["cache"])
+            out, vc = module.apply({"params": pm}, ids, positions=positions,
+                                   mutable=["cache"], **told(true_lens))
             logits, routing = logits_and_routing(
                 out, positions < true_lens[:, None])
             last = jnp.take_along_axis(
@@ -538,6 +548,9 @@ class ServingEngine:
                     {"params": pm, "cache": c}, tok[:, None],
                     positions=pos[:, None], mutable=["cache"])
                 logits, routing = logits_and_routing(out, act[:, None])
+                state = count_state(pos, act) if count_state else None
+                if state is not None:
+                    routing = dict(routing or {}, state=state)
                 key, sub = jax.random.split(key)
                 nxt = sample_(logits[:, -1], sub,
                               temperature_, top_k_, top_p_)
@@ -1360,8 +1373,13 @@ class ServingEngine:
         (moe/grouped.py::routing_counters). Its tokens were fetched just
         before, so the program has ended and this waits for nothing."""
         import jax
-        for name, value in jax.device_get(routing[0] if routing else {}
-                                          ).items():
+        counted = dict(jax.device_get(routing[0] if routing else {}))
+        # what the lanes' state held and the steps read of it (the model's
+        # step_counters, under the names it gave)
+        for name, value in counted.pop("state", {}).items():
+            self.metrics.on_state_rows(name, float(value))
+            telemetry.count(f"serve/{name}", float(value))
+        for name, value in counted.items():
             self.metrics.on_routing(kind, name, float(value))
             telemetry.count(f"serve/moe_{kind}_{name}", float(value))
 
@@ -1901,8 +1919,9 @@ class ServingEngine:
         Where the step reads every row (the einsum, the paged pool, the
         speculative and fused widths) the two are equal."""
         block = self._kv_count_block
+        # (the arena's lanes are counted in the model's rows, kv_cache.py)
         arena = self.decode_chunk * self.max_batch * \
-            -(-self.max_seq_len // block)
+            -(-getattr(self.kv, "rows_per_slot", self.max_seq_len) // block)
         read = arena
         if self._kv_read_block is not None:
             fill = self.kv.allocator.fill

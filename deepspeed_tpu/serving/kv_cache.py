@@ -136,6 +136,11 @@ class SlotKVCacheManager:
         self._fp_itemsize = int(jnp.dtype(
             getattr(cfg, "dtype", jnp.float32)).itemsize)
         self.allocator = SlotAllocator(max_batch, self.max_seq_len)
+        # rows a lane's state holds in a layer: a row a position, unless the
+        # model keeps another count (a window beside chunk summaries)
+        lane_rows = getattr(model, "lane_rows", None)
+        self.rows_per_slot = int(lane_rows()) if lane_rows \
+            else self.max_seq_len
         if slot_axis is None:
             slot_axis = 1 if getattr(cfg, "scan_layers", False) else 0
         self._slot_axis = slot_axis
@@ -183,9 +188,13 @@ class SlotKVCacheManager:
             """Move a batch-n bucketed prefill cache into n leased slot
             rows. The prefill leaves are [.., n, P_bucket, ..] with
             P_bucket <= max_seq — only the bucket's prefix of each row is
-            overwritten; stale tail positions from a previous occupant
-            stay masked (fill < their position) until the new request's
-            own decode writes them, so they are never attended."""
+            overwritten; stale tail rows from a previous occupant stay
+            masked until the new request's own decode writes them, so they
+            are never attended. WHICH rows a lane at its fill sees is the
+            model's: a row a position sees the rows below the fill; a
+            window beside chunk summaries (models/eva.py, whose prefill
+            hands out both leaves whole) masks by ``fill mod w`` and by
+            ``fill // w``. Leaves of any meaning pass here by shape."""
             def leaf(a, o):
                 if a.ndim == o.ndim:        # cached_key / cached_value rows
                     for i in range(o.shape[ax]):    # n <= max_batch: unroll
@@ -228,8 +237,12 @@ class SlotKVCacheManager:
     # ---------------------------------------------------------- accounting
     def arena_report(self) -> dict:
         """HBM accounting of the arena pytree: total/kv/index bytes plus
-        the derived per-slot and per-token costs and the current
-        headroom (bytes of KV the free slots could still hold). This is
+        the derived per-slot, per-row and per-token costs and the current
+        headroom (bytes of KV the free slots could still hold). A ROW is
+        what a lane's state is made of (``rows_per_slot`` of them a layer,
+        by the model's count) and a TOKEN a position of context: they are
+        the same where the cache has a row a position; where it has not,
+        ``bytes_per_token`` is a full lane's cost over its positions. This is
         the ground truth the admission cost model and the bench ``hbm``
         block read — computed from the live leaves, so dtype changes
         (e.g. a future int8 KV) are reflected automatically."""
@@ -274,6 +287,8 @@ class SlotKVCacheManager:
             "max_batch": alloc.max_batch,
             "max_seq_len": self.max_seq_len,
             "bytes_per_slot": per_slot,
+            "rows_per_slot": self.rows_per_slot,
+            "bytes_per_row": per_slot // self.rows_per_slot,
             "bytes_per_token": per_token,
             "n_active": alloc.n_active,
             "n_free": alloc.n_free,

@@ -119,6 +119,8 @@ class ServingMetrics:
         # what the expert layers routed, "<prefill|decode>_<counter>" ->
         # the sum over the programs' steps (moe/grouped.py COUNTERS)
         self.routing: Dict[str, float] = {}
+        # what the model's own step counters summed (on_state_rows)
+        self.state_rows: Dict[str, float] = {}
         self._ttft_sum = 0.0
         self._ttft_n = 0
         self.ttft_reservoir = Reservoir()
@@ -170,6 +172,12 @@ class ServingMetrics:
         decode program (``kind``), summed on the device over its steps."""
         key = f"{kind}_{name}"
         self.routing[key] = self.routing.get(key, 0.0) + value
+
+    def on_state_rows(self, name: str, value: float) -> None:
+        """A counter of what the lanes' state held or a decode chunk's steps
+        read of it, under the name the model gave it (a model whose lane
+        state is not a row a position: ``GPT.step_counters``)."""
+        self.state_rows[name] = self.state_rows.get(name, 0.0) + value
 
     def on_prefix(self, hit: bool) -> None:
         """One paged admission resolved against the prefix cache."""
