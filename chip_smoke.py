@@ -632,7 +632,7 @@ def kernel_leg(cfg, sz, on_chip: bool) -> None:
     live = jnp.asarray(fills <= S)[:, None, None, None]
     agree("live_decode_attention layer-stacked rows",
           lambda q, k, v, n, i: jnp.where(
-              live, live_decode_attention(q, k, v, n, i), 0),
+              live, live_decode_attention(q, [(k, v, n)], i), 0),
           (ql, kl, vl, jnp.asarray(fills), jnp.int32(1)),
           ref_of(lambda q, k, v, n: jnp.where(live, masked_cache_attention(
               q, k[1], v[1], n - 1, 1.0 / Dl ** 0.5), 0),

@@ -46,11 +46,16 @@ occupant's or zero, and is masked:
     EVERY step: the row is overwritten until its chunk is full and is masked
     until its window has closed, so no step branches and no lane is treated
     differently at a window edge. It attends over window rows ``<= t mod w``
-    and summary rows ``< (w / c) * W(t)``, both leaves whole under the masked
-    einsum. A cursor at or past ``max_seq_len`` is the serving engine's
-    retired-lane sentinel: ``max_seq_len mod w`` is row 0 of the window leaf,
-    in range, so both writes are sent out of range explicitly and dropped
-    (``gpt._kv_write``).
+    and summary rows ``< (w / c) * W(t)``: two prefixes, so where
+    :func:`decode_read_block` accepts (a TPU, one device, whole 128-lane
+    heads) ONE kernel call a layer reads each lane's live blocks of both
+    leaf pairs under one softmax
+    (``ops/pallas/decode_attention.live_decode_attention``), and elsewhere
+    the masked einsum reads both leaves whole. A cursor at or past
+    ``max_seq_len`` is the serving engine's retired-lane sentinel:
+    ``max_seq_len mod w`` is row 0 of the window leaf, in range, so both
+    writes are sent out of range explicitly and dropped (``gpt._kv_write``)
+    and both fills are sent past their leaves (nothing of the lane is read).
   * a call that has no cache, or creates one (prefill), attends window by
     window in blocks of queries, each block against its own window's keys
     and against the summaries, never a ``[T, T]`` matrix. It hands out the
@@ -274,6 +279,15 @@ def _decode_attention(cfg, q, k, v, mu, phi, leaves, cur, layer):
     cv = _kv_write(cv, vsum[:, None].astype(cv.dtype), chunk, layer)
     with jax.named_scope("eva/decode"):
         n_win, n_old = live_rows(cfg, cur)
+        if decode_read_block(cfg, b) is not None:
+            # each lane's live blocks of both leaf pairs, one softmax; a
+            # dead lane's fills lie past both leaves (max_seq_len mod w = 0
+            # would read as one live window row)
+            from ..ops.pallas.decode_attention import live_decode_attention
+            ctx = live_decode_attention(
+                q, [(wk, wv, jnp.where(dead, w + 1, n_win)),
+                    (ck, cv, jnp.where(dead, n_sum + 1, n_old))], layer)
+            return ctx, (wk, wv, ck, cv)
         seen_w = jnp.arange(w, dtype=jnp.int32) < n_win[:, None, None, None]
         seen_s = jnp.arange(n_sum, dtype=jnp.int32) \
             < n_old[:, None, None, None]
@@ -439,21 +453,56 @@ PREFILL_TAKES_LENGTHS = True
 
 
 def decode_read_block(cfg, b: int):
-    """A decode step reads both leaves of every lane whole."""
-    return None
+    """Rows a block of the live-rows decode read carries, where a decode
+    step of ``b`` lanes takes it
+    (ops/pallas/decode_attention.live_decode_attention over the two leaf
+    pairs: each lane's live window blocks, then its live summary blocks);
+    None where it reads both leaves of every lane whole with the masked
+    einsum. The question ``gpt.live_read_block`` asks, of both leaves'
+    shapes: ``decode_impl="auto"`` alone chooses, from the platform, the
+    mesh, the dtype and the shapes."""
+    if cfg.decode_impl != "auto":
+        return None
+    from ..ops.pallas import _utils as kernels
+    from ..ops.pallas.decode_attention import live_block, live_decode_refusal
+    from .gpt import _decode_mesh_refusal
+    rows = (cfg.block.window_size, summary_rows(cfg))
+    refusal = live_decode_refusal(b, rows, cfg.num_heads, cfg.head_dim,
+                                  cfg.dtype) or _decode_mesh_refusal()
+    if not kernels.auto_path("decode_attention", refusal):
+        return None
+    return live_block(min(rows))
+
+
+def blocks_read(cfg, t, block: int):
+    """Blocks of ``block`` rows the live-rows read takes of a lane whose
+    next token is at position ``t``: its live window rows and its live
+    summary rows, each rounded up to whole blocks. Works on ints, numpy and
+    jax arrays."""
+    n_win, n_old = live_rows(cfg, t)
+    return -(-n_win // block) + -(-n_old // block)
 
 
 def step_counters(cfg, positions, live):
     """What ONE decode step of lanes at ``positions [b]`` read, as scalars a
     serving program sums on the device: the window and summary rows that were
     live in the lanes that ``live [b]`` says are somebody's; the rows the
-    step read (both leaves of EVERY lane: the masked einsum does not know a
-    lane is idle); the lanes that wrote a window's last row."""
+    step read (the live blocks of those lanes where the live-rows read runs,
+    :func:`decode_read_block`; else both leaves of EVERY lane: the masked
+    einsum does not know a lane is idle); the lanes that wrote a window's
+    last row."""
     n_win, n_old = live_rows(cfg, positions)
     w = cfg.block.window_size
+    b = positions.shape[0]
+    block = decode_read_block(cfg, b)
+    if block is None:
+        read = jnp.asarray(b * lane_rows(cfg))
+    else:
+        read = block * jnp.sum(
+            jnp.where(live, blocks_read(cfg, positions, block), 0))
     return {
         "eva_window_rows_live": jnp.sum(jnp.where(live, n_win, 0)),
         "eva_summary_rows_live": jnp.sum(jnp.where(live, n_old, 0)),
-        "eva_rows_read": jnp.asarray(positions.shape[0] * lane_rows(cfg)),
+        "eva_rows_read": read,
         "eva_windows_closed": jnp.sum(live & (n_win == w)),
     }

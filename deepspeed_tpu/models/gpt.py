@@ -708,7 +708,7 @@ class SelfAttention(nn.Module):
         idx.value = _set_layer_rows(idx.value, layer, cur + s)
         if live_read:
             from ..ops.pallas.decode_attention import live_decode_attention
-            return live_decode_attention(q, ck.value, cv.value, cur + s,
+            return live_decode_attention(q, [(ck.value, cv.value, cur + s)],
                                          layer, scale=scale)
         if int8:
             from ..ops.quantizer import dequantize_kv
@@ -1058,6 +1058,17 @@ class GPT(nn.Module):
         return self.cfg.max_seq_len if rows is None else rows(self.cfg)
 
     @nn.nowrap
+    def blocks_read(self, positions, block: int):
+        """Blocks of ``block`` rows the live-rows decode read takes of a lane
+        whose next token is at ``positions`` (ints or an array): the rows
+        under its fill, this token's among them, unless the block's kind
+        keeps another count."""
+        count = getattr(_kind(self.cfg.block), "blocks_read", None)
+        if count is None:
+            return -(-(positions + 1) // block)
+        return count(self.cfg, positions, block)
+
+    @nn.nowrap
     def step_counters(self, positions, live):
         """What one decode step of lanes at ``positions [b]`` read of their
         state, as named scalars a serving program sums on the device over
@@ -1072,8 +1083,9 @@ def _kind(block):
     ``Stack(cfg)(x, positions, lengths) -> (x, handed out or None)``,
     ``FinalNorm(cfg)``, ``decode_read_block(cfg, b)``; where it has them
     ``Head(cfg)``, ``routing_counters(cfg, routed, live)``,
-    ``PREFILL_TAKES_LENGTHS``, ``lane_rows(cfg)``, ``step_counters(cfg,
-    positions, live)`` (models/mla.py, models/eva.py). None: this file."""
+    ``PREFILL_TAKES_LENGTHS``, ``lane_rows(cfg)``, ``blocks_read(cfg,
+    positions, block)``, ``step_counters(cfg, positions, live)``
+    (models/mla.py, models/eva.py). None: this file."""
     import sys
     return sys.modules[type(block).__module__] if block is not None else None
 

@@ -16,7 +16,11 @@ over the KV cache.
   ``ceil(fill / 128)`` blocks of each lane's rows and nothing of a masked
   lane. On ``serve-batch`` (8 lanes x 2048, a fifth of them live) the
   16 layers' attention went from 6.18 ms (the einsum) to 1.33 ms
-  (my chip run, PR 29); a prefix-bounded einsum took 3.21 ms.
+  (my chip run, PR 29); a prefix-bounded einsum took 3.21 ms. It takes a
+  LIST of (k leaf, v leaf, fills) pairs under one softmax a lane: one for
+  a NeoX block, two for a block that keeps a window beside chunk summaries
+  (models/eva.py: each lane's live window blocks, then its live summary
+  blocks, walked by the same loop).
 * :func:`decode_attention` / :func:`paged_decode_attention` — the older
   kernels, asked for BY NAME (``decode_impl="pallas"``,
   ``megakernel=True``): all lanes ride one DMA window sized by the
@@ -371,53 +375,77 @@ def decode_attention(q: jnp.ndarray, cached_key: jnp.ndarray,
 # Dense decode over each lane's LIVE rows of the layer-stacked arena
 # --------------------------------------------------------------------------
 
-def _live_kernel(layer_ref, fill_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
-                 v_buf, k_sem, v_sem, lane_of, blk_of, *, scale, block_k,
-                 b, S, h, d):
-    """One program for all lanes. k_hbm/v_hbm: the arena leaves
-    [L, b, S, h, d] WHOLE in HBM; k_buf/v_buf: [2, block_k, h, d] VMEM
-    slots. The scalar core first writes the step's schedule into SMEM —
-    one entry (lane, block) per LIVE block, lane after lane: a lane of fill
-    f has ceil(f / block_k) of them, a masked lane (f > S, the engine's
-    retired-lane sentinel) none — and ONE double-buffered loop then walks
-    it, so the DMA of a lane's first block is in flight while the lane
-    before it computes its last. Online-softmax state rides the loop carry
-    and starts anew at a lane's block 0; a lane's output is written at its
-    last block, a masked lane's stays zero.
+def _live_kernel(layer_ref, *refs, scale, block_k, b, rows, h, d):
+    """One program for all lanes, over ``len(rows)`` (k leaf, v leaf, fills)
+    pairs under ONE softmax a lane. ``refs``: the pairs' fills (scalar
+    prefetch), the queries, then each pair's arena leaves
+    [L, b, rows[p], h, d] WHOLE in HBM; k_buf/v_buf: [2, block_k, h, d] VMEM
+    slots that every pair's blocks share. The scalar core first writes the
+    step's schedule into SMEM — one entry (lane, block) per LIVE block, lane
+    after lane, and within a lane pair after pair: a fill of f has
+    ceil(f / block_k) of them, a masked lane (a fill past its leaf's rows,
+    the engine's retired-lane sentinel) none in any pair — and ONE
+    double-buffered loop then walks it, so the DMA of a lane's first block
+    is in flight while the lane before it computes its last, whichever leaf
+    either lies in. Online-softmax state rides the loop carry and starts
+    anew at a lane's first entry; a lane's output is written at its last, a
+    masked lane's stays zero. With more than one pair a third SMEM array
+    says which pair's leaves an entry's copy starts from; a single pair
+    emits no such branch.
 
     Per block: the [block_k, h, d] rows are [block_k*h, d] to the MXU (a
     free reshape, h being whole sublane tiles), the h queries meet all of
     them in one dot on bf16 operands with float32 accumulation, and the
     mask keeps of column (k, g) the row g alone (and k < fill), so the
     rest of the body is flash attention with one query row a head."""
+    P = len(rows)
+    fill_refs, q_ref, leaves = refs[:P], refs[P], refs[P + 1:3 * P + 1]
+    o_ref, k_buf, v_buf, k_sem, v_sem, lane_of, blk_of, *pair_of = \
+        refs[3 * P + 1:]
     layer = layer_ref[0]
 
     def lane_blocks(lane, n):
-        f = fill_ref[lane]
-        nb = jnp.where(f > S, 0, (f + block_k - 1) // block_k)
-
-        def put(j, n):
-            # stores to the kernel's SMEM scratch refs, not host state
-            lane_of[n] = lane   # tracelint: disable=mutation-in-trace
-            blk_of[n] = j       # tracelint: disable=mutation-in-trace
-            return n + 1
-        return jax.lax.fori_loop(0, nb, put, n)
+        fills = [f[lane] for f in fill_refs]
+        masked = functools.reduce(
+            jnp.logical_or, [f > S for f, S in zip(fills, rows)])
+        for p, f in enumerate(fills):
+            def put(j, n, p=p):
+                # stores to the kernel's SMEM scratch refs, not host state
+                lane_of[n] = lane   # tracelint: disable=mutation-in-trace
+                blk_of[n] = j       # tracelint: disable=mutation-in-trace
+                if pair_of:
+                    pair_of[0][n] = p  # tracelint: disable=mutation-in-trace
+                return n + 1
+            n = jax.lax.fori_loop(
+                0, jnp.where(masked, 0, (f + block_k - 1) // block_k), put, n)
+        return n
 
     total = jax.lax.fori_loop(0, b, lane_blocks, jnp.int32(0))
+    lane_of[total] = b      # no lane's: the last entry ends its lane
 
-    def copies(i, slot):
+    def copies(i, slot, p):
         at = (layer, lane_of[i], pl.ds(blk_of[i] * block_k, block_k))
-        return (pltpu.make_async_copy(k_hbm.at[at], k_buf.at[slot],
+        return (pltpu.make_async_copy(leaves[2 * p].at[at], k_buf.at[slot],
                                       k_sem.at[slot]),
-                pltpu.make_async_copy(v_hbm.at[at], v_buf.at[slot],
-                                      v_sem.at[slot]))
+                pltpu.make_async_copy(leaves[2 * p + 1].at[at],
+                                      v_buf.at[slot], v_sem.at[slot]))
+
+    def start(i, slot):
+        if not pair_of:
+            for c in copies(i, slot, 0):
+                c.start()
+            return
+        for p in range(P):
+            @pl.when(pair_of[0][i] == p)
+            def _from_pair(p=p):
+                for c in copies(i, slot, p):
+                    c.start()
 
     o_ref[...] = jnp.zeros_like(o_ref)
 
     @pl.when(total > 0)
     def _prologue():
-        for c in copies(0, 0):
-            c.start()
+        start(0, 0)
 
     # column (k, g) of a block's [h, block_k*h] scores is key k under head
     # g's rows: row g keeps it while k is under the lane's fill, no other
@@ -425,7 +453,8 @@ def _live_kernel(layer_ref, fill_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
     n = block_k * h
     col = jax.lax.broadcasted_iota(jnp.int32, (h, n), 1)
     row = jax.lax.broadcasted_iota(jnp.int32, (h, n), 0)
-    own_key = jnp.where(col % h == row, col // h, S)   # S: never under a fill
+    never = max(rows)                                  # under no fill
+    own_key = jnp.where(col % h == row, col // h, never)
 
     def body(i, carry):
         m_prev, l_prev, acc = carry                # [h,1] [h,1] [h,d]
@@ -433,14 +462,19 @@ def _live_kernel(layer_ref, fill_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
 
         @pl.when(i + 1 < total)
         def _prefetch():
-            for c in copies(i + 1, 1 - slot):
-                c.start()
+            start(i + 1, 1 - slot)
 
-        for c in copies(i, slot):
+        # a wait reads its semaphore and its window's size alone, which
+        # every pair's blocks share
+        for c in copies(i, slot, 0):
             c.wait()
         lane, blk = lane_of[i], blk_of[i]
-        fill = fill_ref[lane]
-        first = blk == 0
+        fill = fill_refs[0][lane]
+        for pair in range(1, P):
+            fill = jnp.where(pair_of[0][i] == pair, fill_refs[pair][lane],
+                             fill)
+        first = jnp.logical_or(i == 0,
+                               lane_of[jnp.maximum(i - 1, 0)] != lane)
         m_prev = jnp.where(first, NEG_INF, m_prev)
         l_prev = jnp.where(first, 0.0, l_prev)
         acc = jnp.where(first, 0.0, acc)
@@ -459,7 +493,7 @@ def _live_kernel(layer_ref, fill_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
                          preferred_element_type=jnp.float32)    # [h, d]
         acc = acc * corr + pv
 
-        @pl.when((blk + 1) * block_k >= fill)
+        @pl.when(lane_of[i + 1] != lane)
         def _lane_done():
             o_ref[lane] = (acc / l_new).astype(o_ref.dtype)
         return m_new, l_new, acc
@@ -484,12 +518,14 @@ def live_block(S: int) -> int:
     return min(_LIVE_BLOCK, S)
 
 
-def live_decode_refusal(b: int, S: int, h: int, d: int, dtype, s: int = 1,
+def live_decode_refusal(b: int, S, h: int, d: int, dtype, s: int = 1,
                         block_k: Optional[int] = None) -> Optional[str]:
     """Why :func:`live_decode_attention` cannot run this shape; None when
-    it can. It reads the rank-4 rows as they lie, so it takes what lies
-    without padding: a head of whole 128-lane rows, heads in whole sublane
-    tiles, a plain floating cache, one query a lane."""
+    it can. ``S``: the rows of a lane's leaf, or of each pair's leaves (one
+    block size serves them all). It reads the rank-4 rows as they lie, so
+    it takes what lies without padding: a head of whole 128-lane rows,
+    heads in whole sublane tiles, a plain floating cache, one query a
+    lane."""
     if s != 1:
         return ("more than one query a lane: the live-rows read takes the "
                 "decode width alone (prefill, speculative and fused-prefill "
@@ -505,61 +541,71 @@ def live_decode_refusal(b: int, S: int, h: int, d: int, dtype, s: int = 1,
     if h % sublane != 0:
         return (f"h={h} heads are not whole {sublane}-row sublane tiles of "
                 f"{dt.name}")
-    bk = block_k or live_block(S)
-    if S % bk != 0:
-        return (f"cache length {S} is not a multiple of the {bk}-row block")
+    rows = (S,) if isinstance(S, int) else tuple(S)
+    bk = block_k or live_block(min(rows))
+    for n in rows:
+        if n % bk != 0:
+            return (f"cache length {n} is not a multiple of the {bk}-row "
+                    f"block")
     return None
 
 
-def live_decode_attention(q: jnp.ndarray, k_leaf: jnp.ndarray,
-                          v_leaf: jnp.ndarray, fills, layer=None,
+def live_decode_attention(q: jnp.ndarray, pairs, layer=None,
                           scale: Optional[float] = None,
                           block_k: Optional[int] = None) -> jnp.ndarray:
-    """q: [b, 1, h, d]. k_leaf/v_leaf: the cache leaves as the layer loop
-    carries them, [L, b, S, h, d] with ``layer`` a (traced) index, or one
-    layer's [b, S, h, d] with ``layer`` None. ``fills``: valid positions a
-    lane (this token included, already written), scalar or [b]; a lane
-    whose fill is past S is MASKED (the serving engine's retired-lane
-    sentinel writes at ``max_seq_len``): nothing of it is read and its
-    output is zeros the caller discards. Reads ceil(fill / block) blocks
-    of each lane's rows and nothing else of the leaf; no slice or reshape
-    of the leaf is made on the way in. Returns [b, 1, h, d]."""
+    """q: [b, 1, h, d]. ``pairs``: one ``(k_leaf, v_leaf, fills)`` a kind of
+    cache rows the lanes hold, attended under ONE softmax a lane (a NeoX
+    block's keys and values: one pair; a window beside chunk summaries,
+    models/eva.py: two). The leaves as the layer loop carries them,
+    [L, b, S, h, d] with ``layer`` a (traced) index, or one layer's
+    [b, S, h, d] with ``layer`` None; S may differ from pair to pair.
+    ``fills``: the pair's valid rows a lane (this token's included, already
+    written), scalar or [b]; a lane with a fill past its leaf's S is MASKED
+    (the serving engine's retired-lane sentinel writes at ``max_seq_len``):
+    nothing of it is read in any pair and its output is zeros the caller
+    discards. Reads ceil(fill / block) blocks of each lane's rows in each
+    pair and nothing else of the leaves; no slice or reshape of a leaf is
+    made on the way in. Returns [b, 1, h, d]."""
     b, s_q, h, d = q.shape
     if layer is None:
-        k_leaf, v_leaf, layer = k_leaf[None], v_leaf[None], 0
-    S = k_leaf.shape[2]
-    reason = live_decode_refusal(b, S, h, d, k_leaf.dtype, s_q, block_k)
+        pairs, layer = [(k[None], v[None], f) for k, v, f in pairs], 0
+    rows = tuple(k.shape[2] for k, _, _ in pairs)
+    dtype = pairs[0][0].dtype
+    bk = block_k or live_block(min(rows))
+    reason = live_decode_refusal(b, rows, h, d, dtype, s_q, bk)
     if reason is not None:
         refuse("live_decode_attention",
-               f"q={q.shape} cache={k_leaf.shape}", reason)
-    bk = block_k or live_block(S)
+               f"q={q.shape} cache={[k.shape for k, _, _ in pairs]}", reason)
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    fills = jnp.broadcast_to(jnp.asarray(fills, jnp.int32), (b,))
+    fills = [jnp.broadcast_to(jnp.asarray(f, jnp.int32), (b,))
+             for _, _, f in pairs]
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     kernel = functools.partial(_live_kernel, scale=scale, block_k=bk, b=b,
-                               S=S, h=h, d=d)
-    n_max = b * (S // bk)
-    whole = pl.BlockSpec((b, h, d), lambda g, layer, fills: (0, 0, 0))
+                               rows=rows, h=h, d=d)
+    # the schedule: every block of every lane, and the entry that ends it
+    n_max = b * sum(S // bk for S in rows) + 1
+    whole = pl.BlockSpec((b, h, d), lambda g, *prefetched: (0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,              # layer index + per-lane fills
+        num_scalar_prefetch=1 + len(pairs),   # layer index + per-lane fills
         grid=(1,),
-        in_specs=[whole, pl.BlockSpec(memory_space=pltpu.HBM),
-                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        in_specs=[whole] + [pl.BlockSpec(memory_space=pltpu.HBM)]
+        * (2 * len(pairs)),
         out_specs=whole,
         scratch_shapes=[
-            pltpu.VMEM((2, bk, h, d), k_leaf.dtype),
-            pltpu.VMEM((2, bk, h, d), v_leaf.dtype),
+            pltpu.VMEM((2, bk, h, d), dtype),
+            pltpu.VMEM((2, bk, h, d), dtype),
             pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SMEM((n_max,), jnp.int32), pltpu.SMEM((n_max,), jnp.int32),
-        ],
+        ] + [pltpu.SMEM((n_max,), jnp.int32)]
+        * (2 if len(pairs) == 1 else 3),
     )
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         name="decode_attention_live",
         interpret=interpret_mode(),
-    )(layer, fills, q.reshape(b, h, d), k_leaf, v_leaf)
+    )(layer, *fills, q.reshape(b, h, d),
+      *(leaf for k, v, _ in pairs for leaf in (k, v)))
     return out[:, None]
 
 

@@ -1914,10 +1914,13 @@ class ServingEngine:
         (``serve/kv_blocks_read``) beside the blocks its K steps would read
         of the whole arena (``serve/kv_blocks_arena``), from what the host
         holds before the chunk's tokens advance the fills: a lane that
-        entered the chunk with ``fill`` rows written and was live for n
-        steps read ``ceil((fill + j) / block)`` blocks in step j = 1..n.
-        Where the step reads every row (the einsum, the paged pool, the
-        speculative and fused widths) the two are equal."""
+        entered the chunk with ``fill`` positions written and was live for
+        n steps read, in step j = 1..n, what the model says a lane at
+        position ``fill + j - 1`` reads (``GPT.blocks_read``: ``ceil((fill
+        + j) / block)`` of a row a position; a window's and its summaries'
+        blocks of a block that keeps both). Where the step reads every row
+        (the einsum, the paged pool, the speculative and fused widths) the
+        two are equal."""
         block = self._kv_count_block
         # (the arena's lanes are counted in the model's rows, kv_cache.py)
         arena = self.decode_chunk * self.max_batch * \
@@ -1926,8 +1929,8 @@ class ServingEngine:
         if self._kv_read_block is not None:
             fill = self.kv.allocator.fill
             read = sum(
-                int(np.sum(-(-(int(fill[slot]) + np.arange(1, len(seq) + 1))
-                             // block)))
+                int(np.sum(self.module.blocks_read(
+                    int(fill[slot]) + np.arange(len(seq)), block)))
                 for slot, seq in per_slot.items())
         telemetry.count("serve/kv_blocks_read", float(read))
         telemetry.count("serve/kv_blocks_arena", float(arena))

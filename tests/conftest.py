@@ -105,3 +105,19 @@ def telemetry_on():
     yield rt
     rt.clear()
     rt.enabled = was_enabled
+
+
+@pytest.fixture
+def past_auto_path(monkeypatch):
+    """``"auto"`` resolved as on the chip: the kernel where its gate
+    accepts (on the CPU it takes the XLA path whatever the gate says, and
+    the Pallas kernel then runs in the interpreter). What was asked and
+    answered is handed to the test."""
+    from deepspeed_tpu.ops.pallas import _utils as kernels
+    asked = []
+
+    def auto_path(kernel, refusal):
+        asked.append((kernel, refusal))
+        return refusal is None
+    monkeypatch.setattr(kernels, "auto_path", auto_path)
+    return asked

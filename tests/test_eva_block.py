@@ -40,6 +40,35 @@ def _ids(rng, b, s):
     return rng.integers(0, TOY["vocab_size"], (b, s)).astype(np.int32)
 
 
+# the toy at widths the live-rows read takes (8 heads of 128 in float32) and
+# blocks of 8 rows: a window is two blocks, and a summary fill (4 a closed
+# window) ends inside a block in every other window
+KERNEL_TOY = dict(TOY, hidden_size=1024, num_attention_heads=8,
+                  num_key_value_heads=8, num_hidden_layers=2,
+                  num_pred_heads=2,
+                  model={"dtype": "float32", "param_dtype": "float32",
+                         "heads_out": 2})
+KERNEL_SERVED = dict(KERNEL_TOY, model={"dtype": "float32",
+                                        "param_dtype": "float32"})
+BLOCK = 8
+
+
+@pytest.fixture(scope="module")
+def kernel_toy():
+    import jax
+    model = arch.build_model(KERNEL_TOY)
+    return model, arch.init_params(model, jax.random.PRNGKey(5))
+
+
+@pytest.fixture
+def through_the_kernel(past_auto_path, monkeypatch):
+    """``"auto"`` resolved as on a TPU (the kernel then runs in the
+    interpreter), in blocks of 8 rows."""
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(da, "_LIVE_BLOCK", BLOCK)
+    return past_auto_path
+
+
 # ------------------------------------------------------------- (a) the model
 def test_full_forward_equals_the_reference_on_all_eight_heads(toy):
     import jax
@@ -58,21 +87,31 @@ def test_full_forward_equals_the_reference_on_all_eight_heads(toy):
     assert np.max(np.abs(one - ref[:, :, 0])) < ATOL
 
 
-@pytest.mark.parametrize("cursors", ["per_lane", "scalar"])
+@pytest.mark.parametrize("cursors,read", [
+    ("per_lane", "einsum"), ("scalar", "einsum"), ("per_lane", "kernel")])
 def test_prefill_then_decode_through_the_cache_equals_the_full_forward(
-        toy, cursors):
+        request, cursors, read):
     """Padded prefill, told where each row ends, creates both leaves; then
     every further token goes through them one at a time, across two window
     edges and more, from prompt lengths that are no multiple of the chunk:
-    the logits at EVERY position equal the reference's one full forward."""
+    the logits at EVERY position equal the reference's one full forward.
+    ``kernel``: the decode steps read each lane's live blocks of both leaf
+    pairs (``live_decode_attention``, interpreted) where the einsum reads
+    both leaves whole."""
     import jax
     import jax.numpy as jnp
-    model, params = toy
+    if read == "kernel":
+        asked = request.getfixturevalue("through_the_kernel")
+    config = {"einsum": TOY, "kernel": KERNEL_TOY}[read]
+    model, params = request.getfixturevalue(
+        {"einsum": "toy", "kernel": "kernel_toy"}[read])
+    layers, h = config["num_hidden_layers"], config["num_attention_heads"]
+    dh = config["hidden_size"] // h
     rng = np.random.default_rng(1)
     total = 53
     lens = np.array([7, 21] if cursors == "per_lane" else [13, 13], np.int32)
     ids = _ids(rng, 2, total)
-    ref = np.asarray(arch.reference_logits(TOY, params, ids)[0])
+    ref = np.asarray(arch.reference_logits(config, params, ids)[0])
     width = int(lens.max()) + 3                     # a bucket's padding
     padded = np.where(np.arange(width)[None] < lens[:, None],
                       ids[:, :width], 0)
@@ -81,8 +120,8 @@ def test_prefill_then_decode_through_the_cache_equals_the_full_forward(
     cache = vc["cache"]["blocks"]
     assert set(cache) == {"window_key", "window_value", "chunk_key",
                           "chunk_value", "cache_index"}
-    assert cache["window_key"].shape == (3, 2, W, 4, 8)
-    assert cache["chunk_value"].shape == (3, 2, S // C, 4, 8)
+    assert cache["window_key"].shape == (layers, 2, W, h, dh)
+    assert cache["chunk_value"].shape == (layers, 2, S // C, h, dh)
     for i, n in enumerate(lens):
         assert np.max(np.abs(np.asarray(logits)[i, :n] - ref[i, :n])) < ATOL
     step = jax.jit(lambda c, tok, pos: model.apply(
@@ -103,6 +142,9 @@ def test_prefill_then_decode_through_the_cache_equals_the_full_forward(
         edges += int((pos[pos < total] % W == 0).sum())
         pos = np.minimum(pos + 1, total)
     assert edges >= 4           # each lane began at least two windows
+    assert model.decode_read_block(2) == (BLOCK if read == "kernel" else None)
+    if read == "kernel":
+        assert ("decode_attention", None) in asked
 
 
 def test_inside_one_window_it_is_plain_causal_softmax_attention(toy):
@@ -202,8 +244,7 @@ def test_a_call_that_is_handed_the_cache_takes_one_token_a_lane(toy):
 
 
 # ----------------------------------------------- (b) the model, through serving
-@pytest.fixture(scope="module")
-def served(toy):
+def _serve(config, params):
     """Six requests through a three-lane engine with chunks of 8 steps, so
     that inside ONE chunk a lane crosses a window edge (prompt 13: positions
     13-20), a lane is mid-window (prompt 3), a lane is taken after a longer
@@ -211,8 +252,7 @@ def served(toy):
     and a lane is retired, its cursor pinned at ``max_seq_len``."""
     import jax.numpy as jnp
     from deepspeed_tpu.serving import ServingEngine
-    params = toy[1]
-    model = arch.build_model(SERVED)
+    model = arch.build_model(config)
     eng = ServingEngine(model, model_parameters=params, dtype=jnp.float32,
                         max_batch=3, decode_chunk=8, max_prompt_len=32,
                         prefill_buckets=[16, 32])
@@ -231,27 +271,50 @@ def served(toy):
     return eng, params, prompts, reqs, snaps
 
 
-def test_served_tokens_are_the_model_s_own_token_for_token(toy, served):
+@pytest.fixture(scope="module")
+def served(toy):
+    return _serve(SERVED, toy[1])
+
+
+@pytest.fixture(scope="module")
+def served_through_the_kernel(kernel_toy):
+    """The same requests at the widths the live-rows read takes, ``"auto"``
+    resolved as on a TPU for as long as the engine is built and runs."""
+    from deepspeed_tpu.ops.pallas import _utils as kernels
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(kernels, "auto_path", lambda kernel, why: why is None)
+        patch.setattr(da, "_LIVE_BLOCK", BLOCK)
+        return _serve(KERNEL_SERVED, kernel_toy[1])
+
+
+@pytest.mark.parametrize("read", ["einsum", "kernel"])
+def test_served_tokens_are_the_model_s_own_token_for_token(request, read):
     """Against the model alone: greedy over ONE full forward at a time."""
-    eng, params, prompts, reqs, _ = served
-    model = arch.build_model(SERVED)
+    config = {"einsum": SERVED, "kernel": KERNEL_SERVED}[read]
+    eng, params, prompts, reqs, _ = request.getfixturevalue(
+        {"einsum": "served", "kernel": "served_through_the_kernel"}[read])
+    assert eng._kv_read_block == (BLOCK if read == "kernel" else None)
+    model = arch.build_model(config)
     for prompt, req, budget in zip(prompts, reqs, BUDGETS):
         assert req.status == "done" and len(req.tokens) == budget
         full = np.concatenate([prompt, np.asarray(req.tokens, np.int32)])
         logits = np.asarray(model.apply({"params": params}, full[None]))[0]
         ref = np.asarray(arch.reference_logits(
-            SERVED, params, full[None])[0])[0, :, 0]
+            config, params, full[None])[0])[0, :, 0]
         for j, tok in enumerate(req.tokens):
             row = len(prompt) - 1 + j
             assert tok == int(logits[row].argmax()), (len(prompt), j)
             assert ref[row].max() - ref[row][tok] < 1e-3, (len(prompt), j)
 
 
-def test_a_retired_lane_s_rows_stay_as_they_were(served):
+@pytest.mark.parametrize("served_by", ["served",
+                                       "served_through_the_kernel"])
+def test_a_retired_lane_s_rows_stay_as_they_were(request, served_by):
     """From the pump after a lane's request ended to the pump before its
     next occupant's prefill is inserted, chunks run with that lane's cursor
     at ``max_seq_len``: both its leaves are bit for bit what they were."""
-    snaps = served[4]
+    snaps = request.getfixturevalue(served_by)[4]
     held = 0
     for (run0, leaves0), (run1, leaves1) in zip(snaps, snaps[1:]):
         for lane in range(3):
@@ -305,6 +368,30 @@ def test_the_chunk_program_counts_live_rows_from_positions(served):
     assert got["eva_rows_read"] > win + old
 
 
+def test_the_live_rows_read_counts_the_blocks_it_took(
+        served_through_the_kernel):
+    """Where the kernel runs, the device's ``eva_rows_read`` and the host's
+    ``serve/kv_blocks_read`` are the same replay by hand: every decode step
+    of a live lane at position t took ``ceil(((t mod 16) + 1) / 8)`` window
+    blocks and ``ceil(4 * (t // 16) / 8)`` summary blocks of 8 rows, and
+    nothing of an idle lane; the arena a step would read is 3 lanes x 48 / 8
+    blocks."""
+    eng, _, prompts, reqs, _ = served_through_the_kernel
+    m = eng.metrics
+    blocks = live = 0
+    for prompt, req in zip(prompts, reqs):
+        for t in range(len(prompt), len(prompt) + len(req.tokens) - 1):
+            n_win, n_old = t % W + 1, (W // C) * (t // W)
+            blocks += -(-n_win // BLOCK) + -(-n_old // BLOCK)
+            live += n_win + n_old
+    assert m.state_rows["eva_rows_read"] == blocks * BLOCK
+    assert m.state_rows["eva_window_rows_live"] \
+        + m.state_rows["eva_summary_rows_live"] == live
+    assert live < blocks * BLOCK < m.decode_steps * 8 * 3 * 48
+    assert m.kv_blocks_read == blocks
+    assert m.kv_blocks_arena == m.decode_steps * 8 * 3 * (48 // BLOCK)
+
+
 def test_step_counters_count_live_lanes_only(toy):
     import jax.numpy as jnp
     model = toy[0]
@@ -313,6 +400,47 @@ def test_step_counters_count_live_lanes_only(toy):
     assert {k: int(v) for k, v in got.items()} == {
         "eva_window_rows_live": 16 + 1, "eva_summary_rows_live": 0 + 4,
         "eva_rows_read": 4 * 48, "eva_windows_closed": 1}
+
+
+def test_step_counters_count_the_live_lanes_blocks_where_the_kernel_runs(
+        kernel_toy, through_the_kernel):
+    """Position 15: two window blocks of 8; position 16: one window block
+    and the block that holds the first window's four summaries; the lanes
+    that are nobody's read nothing."""
+    import jax.numpy as jnp
+    got = kernel_toy[0].step_counters(jnp.array([15, 16, 40, 128]),
+                                      jnp.array([True, True, False, False]))
+    assert {k: int(v) for k, v in got.items()} == {
+        "eva_window_rows_live": 16 + 1, "eva_summary_rows_live": 0 + 4,
+        "eva_rows_read": BLOCK * (2 + 0 + 1 + 1), "eva_windows_closed": 1}
+    assert kernel_toy[0].blocks_read(np.array([15, 16, 40]), BLOCK).tolist() \
+        == [2, 2, 3]
+
+
+def _cell_model(**kw):
+    from chipbench import spec
+    config = spec.load_cell(spec.load_benchmark(), "serve-longdoc")["config"]
+    return arch.build_model(dict(config, model=dict(config["model"], **kw)))
+
+
+@pytest.mark.parametrize("case,model,block,names", [
+    ("the cell", lambda: _cell_model(), 128, None),
+    ("xla by name", lambda: _cell_model(decode_impl="xla"), None, None),
+    ("heads of 8", lambda: arch.build_model(SERVED), None, "lane-padded"),
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_what_the_block_chooses_from_what_a_trace_sees(past_auto_path, case,
+                                                       model, block, names):
+    """``serve-longdoc``'s 16 lanes of 2,048 + 2,048 rows, 32 heads of 128 in
+    bf16 take the live-rows read where ``"auto"`` may; a refusal is named and
+    logged; on the CPU the einsum stays."""
+    assert model().decode_read_block(16) == block
+    if names is not None:
+        (kernel, refusal), = past_auto_path
+        assert kernel == "decode_attention" and names in refusal
+
+
+def test_on_the_cpu_the_cell_keeps_the_einsum():
+    assert _cell_model().decode_read_block(16) is None
 
 
 # ------------------------------------------------------- what the model forced

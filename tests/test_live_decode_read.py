@@ -22,38 +22,98 @@ from deepspeed_tpu.serving import ServingEngine
 
 L, B, S, D, BK = 3, 5, 64, 128, 16
 SENTINEL = S + 1        # the fill of a lane whose write position is max_seq
+S2 = 2 * S              # rows of the second pair's leaves (the summaries)
 
 
-def _leaves(dtype, h, seed=0):
-    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
-    kl = jax.random.normal(k1, (L, B, S, h, D), dtype)
-    vl = jax.random.normal(k2, (L, B, S, h, D), dtype)
-    q = jax.random.normal(k3, (B, 1, h, D), dtype)
-    return q, kl, vl
+def _leaves(dtype, h, seed=0, rows=(S,)):
+    """q and a (k leaf, v leaf) a pair, layer-stacked."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed),
+                                 1 + 2 * len(rows)))
+    q = jax.random.normal(next(keys), (B, 1, h, D), dtype)
+    return (q,) + tuple(jax.random.normal(next(keys), (L, B, n, h, D), dtype)
+                        for n in rows for _ in "kv")
 
 
-@pytest.mark.parametrize("fills", [
-    (1, BK, BK + 1, S, SENTINEL),           # the edges, a masked lane last
-    (SENTINEL, 2 * BK, SENTINEL, 7, S - 1),     # masked lanes between live
-    (S, S, S, S, S),                        # every block of every lane
-    (SENTINEL,) * B,                        # nothing live: no DMA at all
-], ids=["edges", "masked-between", "full", "all-masked"])
+def _one_softmax(q, pairs, layer):
+    """The reference over the whole leaves: the masked einsum for one pair,
+    ``eva._joint_attention`` for a window pair beside a summary pair."""
+    from deepspeed_tpu.models.eva import _joint_attention
+    if len(pairs) == 1:
+        (k, v, fills), = pairs
+        return masked_cache_attention(q, k[layer], v[layer], fills - 1,
+                                      1.0 / np.sqrt(D))
+    seen = [jnp.arange(k.shape[2]) < f[:, None, None, None]
+            for k, _, f in pairs]
+    (kw, vw, _), (ks, vs, _) = pairs
+    return _joint_attention(q, kw[layer], vw[layer], seen[0], ks[layer],
+                            vs[layer], seen[1], q.dtype)
+
+
+def _dead_rows_filled(leaf, layer, fills, blocks, partial):
+    """``leaf`` with ``blocks`` in every row of ``layer`` that lies in a
+    block NO live lane's fill reaches (a masked lane's rows all do) and
+    ``partial`` in the dead rows of a block a fill ends inside."""
+    rows = leaf.shape[2]
+    fills = np.asarray(fills)
+    reach = np.where(fills > rows, 0, -(-fills // BK) * BK)[:, None]
+    at = np.arange(rows)[None, :]
+    fill = np.where(fills > rows, 0, fills)[:, None]
+    garbage = np.where(at >= reach, blocks,
+                       np.where(at >= fill, partial, 0.0))
+    mask = (at >= fill)[..., None, None]
+    rows_l = jnp.where(mask, jnp.asarray(garbage, leaf.dtype)[..., None, None],
+                       leaf[layer])
+    return leaf.at[layer].set(rows_l)
+
+
+# one pair: a NeoX block's keys and values, the fills of five lanes
+ONE_PAIR = {
+    "edges": [(1, BK, BK + 1, S, SENTINEL)],    # a masked lane last
+    "masked-between": [(SENTINEL, 2 * BK, SENTINEL, 7, S - 1)],
+    "full": [(S, S, S, S, S)],                  # every block of every lane
+    "all-masked": [(SENTINEL,) * B],            # nothing live: no DMA at all
+}
+# two pairs under one softmax: a window of S rows beside S2 summary rows. A
+# lane inside its first window (no summary block), on a window's last row,
+# on a window's first row, a dead lane (both fills past their leaves), a
+# fill that ends inside a summary block
+TWO_PAIRS = {
+    "window-and-summaries": [(5, S, 1, S + 1, BK + 3),
+                             (0, 3 * BK, 2 * BK, S2 + 1, 2 * BK + 5)],
+    "first-window-only": [(1, BK, S, BK + 1, 7), (0,) * B],
+    "both-full": [(S,) * B, (S2,) * B],
+    "all-dead": [(S + 1,) * B, (S2 + 1,) * B],
+}
+CASES = dict(ONE_PAIR, **TWO_PAIRS)
+
+
+@pytest.mark.parametrize("case", list(CASES))
 @pytest.mark.parametrize("dtype,h,tol", [(jnp.bfloat16, 16, 2e-2),
                                          (jnp.float32, 8, 2e-5)],
                          ids=["bf16", "f32"])
-def test_live_rows_read_matches_the_masked_einsum(dtype, h, tol, fills):
-    """A layer-stacked leaf at a TRACED layer index: live lanes agree with
-    ``masked_cache_attention`` over that layer's rows to the einsum's own
-    tolerance, a masked lane's output is zeros nobody reads."""
-    q, kl, vl = _leaves(dtype, h)
-    fills = jnp.asarray(fills, jnp.int32)
+def test_live_rows_read_matches_the_masked_einsum(dtype, h, tol, case):
+    """Layer-stacked leaves at a TRACED layer index: live lanes agree with
+    the ONE masked softmax over that layer's whole rows (one pair: the
+    masked einsum; two: ``eva._joint_attention``) to the einsum's own
+    tolerance, a masked lane's output is zeros nobody reads. What a dead
+    row holds never reaches the output: NaN in every block no fill reaches
+    (never read), 1e30 in the dead rows of a block a fill ends in (read and
+    masked)."""
+    fills = [jnp.asarray(f, jnp.int32) for f in CASES[case]]
+    rows = (S, S2)[:len(fills)]
+    q, *leaves = _leaves(dtype, h, rows=rows)
     layer = 1
-    got = jax.jit(lambda q, kl, vl, f, i: live_decode_attention(
-        q, kl, vl, f, i, block_k=BK))(q, kl, vl, fills, jnp.int32(layer))
+    pairs = [(leaves[2 * i], leaves[2 * i + 1], f)
+             for i, f in enumerate(fills)]
+    dirty = [tuple(_dead_rows_filled(x, layer, f, np.nan, 1e30)
+                   for x in (k, v)) + (f,) for k, v, f in pairs]
+    clean = [tuple(_dead_rows_filled(x, layer, f, 0.0, 0.0)
+                   for x in (k, v)) + (f,) for k, v, f in pairs]
+    got = jax.jit(lambda q, pairs, i: live_decode_attention(
+        q, pairs, i, block_k=BK))(q, dirty, jnp.int32(layer))
     assert got.shape == q.shape and got.dtype == q.dtype
-    ref = masked_cache_attention(q, kl[layer], vl[layer], fills - 1,
-                                 1.0 / np.sqrt(D))
-    live = np.asarray(fills) <= S
+    ref = _one_softmax(q, clean, layer)
+    live = np.all([np.asarray(f) <= n for f, n in zip(fills, rows)], axis=0)
     got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
     np.testing.assert_allclose(got[live], ref[live], atol=tol, rtol=tol)
     assert not got[~live].any()
@@ -63,7 +123,7 @@ def test_one_layers_own_leaf_and_a_scalar_fill():
     """``layer`` None is the unscanned model's [b, S, h, d] leaf, a scalar
     fill the single-stream ``generate()``: the same read."""
     q, kl, vl = _leaves(jnp.float32, 8, seed=1)
-    got = live_decode_attention(q, kl[2], vl[2], jnp.int32(BK + 3),
+    got = live_decode_attention(q, [(kl[2], vl[2], jnp.int32(BK + 3))],
                                 block_k=BK)
     ref = masked_cache_attention(q, kl[2], vl[2], BK + 2, 1.0 / np.sqrt(D))
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
@@ -89,11 +149,11 @@ def test_the_gate_names_why_it_refuses(case):
     assert reason is not None and names in reason, reason
     with pytest.raises(kernels.KernelUnsupported, match=names):
         s, dt = shape["s"], shape["dtype"]
+        leaf = jnp.zeros((2, shape["b"], shape["S"], shape["h"], shape["d"]),
+                         dt)
         live_decode_attention(
             jnp.zeros((shape["b"], s, shape["h"], shape["d"]), jnp.bfloat16),
-            *(jnp.zeros((2, shape["b"], shape["S"], shape["h"],
-                         shape["d"]), dt),) * 2,
-            jnp.ones((shape["b"],), jnp.int32), 0)
+            [(leaf, leaf, jnp.ones((shape["b"],), jnp.int32))], 0)
 
 
 def test_the_gate_accepts_the_cells_shape():
@@ -102,17 +162,14 @@ def test_the_gate_accepts_the_cells_shape():
     assert da.live_block(2048) == 128
 
 
-@pytest.fixture
-def past_auto_path(monkeypatch):
-    """``"auto"`` resolved as on the chip: the kernel where its gate
-    accepts. What was asked and answered is handed to the test."""
-    asked = []
-
-    def auto_path(kernel, refusal):
-        asked.append((kernel, refusal))
-        return refusal is None
-    monkeypatch.setattr(kernels, "auto_path", auto_path)
-    return asked
+def test_the_gate_is_asked_of_every_pairs_leaf():
+    """``serve-longdoc``: 16 lanes of 2,048 window rows beside 2,048
+    summary rows; one block size serves both leaves."""
+    assert live_decode_refusal(16, (2048, 2048), 32, 128, jnp.bfloat16) is None
+    assert "2000 is not a multiple of the 128-row block" in \
+        live_decode_refusal(16, (2048, 2000), 32, 128, jnp.bfloat16)
+    assert "96 is not a multiple of the 64-row block" in \
+        live_decode_refusal(16, (96, 64), 32, 128, jnp.bfloat16)
 
 
 def _cell_cfg(**kw):

@@ -295,38 +295,66 @@ def test_live_rows_decode_read_compiles_for_v5e_at_serve_batchs_shape(
     bf16 inside a layer loop that carries the two 2.1 GB leaves and writes
     a token into them first, and the program holds no copy of a layer's
     rows (temporaries of kilobytes beside 4.29 GB of aliased arena)."""
+    compiled = _live_read_in_a_layer_loop(v5e_2x2, monkeypatch, L=16, b=8,
+                                          rows=(2048,))
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * 16 * 8 * 2048 * 32 * 128 * 2
+    assert mem.temp_size_in_bytes < 4 * 2 ** 20, mem
+
+
+def _live_read_in_a_layer_loop(devices, monkeypatch, L, b, rows):
+    """A layer loop of ``L`` that carries a (key, value) pair of
+    ``[L, b, n, 32, 128]`` bf16 leaves for every ``n`` of ``rows``, writes a
+    token into each and reads the live rows of all of them under one
+    softmax, compiled for the first of ``devices``."""
     from jax.sharding import SingleDeviceSharding
     from deepspeed_tpu.models.gpt import _kv_write
     from deepspeed_tpu.ops.pallas import decode_attention as da
     monkeypatch.setattr(da, "interpret_mode", lambda: False)
-    L, b, S, h, d = 16, 8, 2048, 32, 128
+    h, d = 32, 128
 
-    def step(x, kl, vl, cur):
+    def step(x, leaves, cur):
         def body(c, layer):
-            x, kl, vl = c
+            x, leaves = c
             q = x.reshape(b, 1, h, d)
-            kl, vl = _kv_write(kl, q, cur, layer), _kv_write(vl, q, cur,
-                                                             layer)
-            o = da.live_decode_attention(q, kl, vl, cur + 1, layer)
-            return (o.reshape(b, h * d), kl, vl), None
-        return jax.lax.scan(body, (x, kl, vl),
+            leaves = [_kv_write(leaf, q, cur % leaf.shape[2], layer)
+                      for leaf in leaves]
+            o = da.live_decode_attention(
+                q, [(k, v, cur % k.shape[2] + 1)
+                    for k, v in zip(leaves[::2], leaves[1::2])], layer)
+            return (o.reshape(b, h * d), leaves), None
+        return jax.lax.scan(body, (x, leaves),
                             jnp.arange(L, dtype=jnp.int32))[0]
 
-    one = SingleDeviceSharding(v5e_2x2[0])
-    leaf = jax.ShapeDtypeStruct((L, b, S, h, d), jnp.bfloat16, sharding=one)
+    one = SingleDeviceSharding(devices[0])
+    leaves = [jax.ShapeDtypeStruct((L, b, n, h, d), jnp.bfloat16,
+                                   sharding=one) for n in rows for _ in "kv"]
     cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+        return jax.jit(step, donate_argnums=(1,)).lower(
             jax.ShapeDtypeStruct((b, h * d), jnp.bfloat16, sharding=one),
-            leaf, leaf,
+            leaves,
             jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
+
+
+def test_two_pair_live_read_compiles_for_v5e_at_serve_longdocs_shape(
+        v5e_2x2, monkeypatch):
+    """``serve-longdoc``'s decode read: the window pair and the summary
+    pair, 16 lanes x (2,048 + 2,048) rows x 32 heads of 128 in bf16, inside
+    a layer loop of 8 that carries the four leaves and writes into them
+    first. ONE kernel call, the four leaves aliased (8.59 GB) and no copy
+    of a layer's rows beside them."""
+    compiled = _live_read_in_a_layer_loop(v5e_2x2, monkeypatch, L=8, b=16,
+                                          rows=(2048, 2048))
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
         == 1
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes == 2 * L * b * S * h * d * 2
+    assert mem.alias_size_in_bytes == 4 * 8 * 16 * 2048 * 32 * 128 * 2
     assert mem.temp_size_in_bytes < 4 * 2 ** 20, mem
 
 
