@@ -1077,6 +1077,15 @@ class GPT(nn.Module):
         count = getattr(_kind(self.cfg.block), "step_counters", None)
         return None if count is None else count(self.cfg, positions, live)
 
+    @nn.nowrap
+    def serving_refusal(self, **asked) -> Optional[str]:
+        """Why this model cannot be served with the widths and options
+        ``asked`` of ``ServingEngine`` that are on (``speculative``,
+        ``fused_prefill``, ``paged``, ``tp``); None when it can, or when the
+        block's kind leaves the question to the programs' traces."""
+        refusal = getattr(_kind(self.cfg.block), "serving_refusal", None)
+        return None if refusal is None else refusal(self.cfg, **asked)
+
 
 def _kind(block):
     """The module that defines a block kind's config is the kind's door:
@@ -1084,8 +1093,9 @@ def _kind(block):
     ``FinalNorm(cfg)``, ``decode_read_block(cfg, b)``; where it has them
     ``Head(cfg)``, ``routing_counters(cfg, routed, live)``,
     ``PREFILL_TAKES_LENGTHS``, ``lane_rows(cfg)``, ``blocks_read(cfg,
-    positions, block)``, ``step_counters(cfg, positions, live)``
-    (models/mla.py, models/eva.py). None: this file."""
+    positions, block)``, ``step_counters(cfg, positions, live)``,
+    ``serving_refusal(cfg, **asked)`` (models/mla.py, models/eva.py,
+    models/afmoe.py). None: this file."""
     import sys
     return sys.modules[type(block).__module__] if block is not None else None
 

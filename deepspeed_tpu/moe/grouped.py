@@ -27,15 +27,23 @@ import jax.numpy as jnp
 f32 = jnp.float32
 
 
-def sigmoid_topk(x, router, k: int, scaling: float, normalize: bool = True):
+def sigmoid_topk(x, router, k: int, scaling: float, normalize: bool = True,
+                 bias=None):
     """The router, in float32 whatever ``x`` is computed in: scores
     ``sigmoid(x @ router)`` over the published experts ``[T, E]``, the ``k``
     largest, and their weights ``scaling * s_i / (sum of the k + 1e-20)``
-    (normalised over all ``k`` chosen, held here or not). Returns
+    (normalised over all ``k`` chosen, held here or not). ``bias [E]``: a
+    per-expert selection bias; the ``k`` largest of ``s + bias`` are chosen
+    and the weights are made of ``s`` alone. Returns
     ``(choice [T, k] int32, weights [T, k] float32)``."""
     logits = jnp.dot(x.astype(f32), router.astype(f32),
                      precision=jax.lax.Precision.HIGHEST)
-    top_s, choice = jax.lax.top_k(jax.nn.sigmoid(logits), k)
+    if bias is not None:
+        scores = jax.nn.sigmoid(logits)
+        _, choice = jax.lax.top_k(scores + bias.astype(f32), k)
+        top_s = jnp.take_along_axis(scores, choice, axis=-1)
+    else:
+        top_s, choice = jax.lax.top_k(jax.nn.sigmoid(logits), k)
     if normalize:
         top_s = top_s / (jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
     return choice.astype(jnp.int32), top_s * scaling

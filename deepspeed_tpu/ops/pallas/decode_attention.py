@@ -885,3 +885,19 @@ def masked_cache_attention(q, ck, cv, first_q_pos, scale, window=None):
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, cv)
 
+
+
+def live_decode_grouped_refusal(h: int, kv_heads: int) -> Optional[str]:
+    """Why :func:`live_decode_attention` cannot read a cache whose rows hold
+    ``kv_heads`` key heads under ``h`` query heads; None when every query
+    head has its own (appended here, below the kernels' call sites, whose
+    line numbers their compiled bodies carry). ``_live_kernel`` meets the
+    ``h`` queries with a block's ``[block_k * h, d]`` rows and keeps of
+    column ``(k, g)`` the row ``g`` alone: a row of ``kv_heads * d`` values
+    that ``h / kv_heads`` query heads share wants an own-GROUP mask and a
+    schedule over rows a quarter as wide, which is ROADMAP R1a's."""
+    if kv_heads != h:
+        return (f"grouped-query heads: {h} query heads read {kv_heads} key "
+                f"heads, and the live-rows read keeps one key row a query "
+                f"head")
+    return None

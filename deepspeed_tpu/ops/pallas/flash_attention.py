@@ -368,3 +368,128 @@ def flash_attention(q, k, v, causal: bool = True,
         refuse("flash_attention", q.shape, reason)
     return _flash_attention(q, k, v, causal, scale,
                             _pick_block(s, block_q), _pick_block(s, block_k))
+
+
+# ---------------------------------------------------------------------------
+# Forward over grouped heads and a band (appended below everything the other
+# programs call: a Mosaic kernel's body carries the line numbers of its call
+# sites, so a line moved above them compiles every such program cold)
+# ---------------------------------------------------------------------------
+
+def _band_blocks(win_ref, qi, block_q, block_k):
+    """First and last key block a query block meets: keys ``j`` of query
+    ``i`` with ``0 <= i - j < window``."""
+    lo = jnp.maximum(qi * block_q - (win_ref[0] - 1), 0) // block_k
+    return lo, (qi * block_q + block_q - 1) // block_k
+
+
+def _fwd_band_kernel(win_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+                     acc_scr, *, scale, block_q, block_k):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    nk = pl.num_programs(3)
+    window = win_ref[0]
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    # skip the tiles above the diagonal and those wholly behind the band
+    lo, hi = _band_blocks(win_ref, qi, block_q, block_k)
+
+    @pl.when(jnp.logical_and(ki >= lo, ki <= hi))
+    def _compute():
+        q, kb, vb = q_ref[0], k_ref[0], v_ref[0]
+        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        rows = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        cols = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        s = jnp.where(jnp.logical_and(rows >= cols, rows - cols < window),
+                      s, NEG_INF)
+        m_prev, l_prev = m_scr[...], l_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # a row whose keys in this tile are all outside the band keeps
+        # m = NEG_INF: exp(NEG_INF - NEG_INF) would count them
+        p = jnp.where(s > NEG_INF, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        m_scr[...] = m_new
+        l_scr[...] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot(
+            p.astype(vb.dtype), vb, preferred_element_type=jnp.float32)
+
+    @pl.when(ki == nk - 1)
+    def _finalize():
+        l = l_scr[...]
+        o_ref[0] = (acc_scr[...] / jnp.where(l == 0, 1.0, l)
+                    ).astype(o_ref.dtype)
+
+
+def flash_band_refusal(s: int, d: int, h: int, kv_heads: int,
+                       block_q: int = 512,
+                       block_k: int = 512) -> Optional[str]:
+    """Why :func:`flash_attention_band` cannot run this shape; None when it
+    can. It reads the heads as 128-lane column blocks of flat ``[B, S, H*D]``
+    rows (no transposed copy of q, k, v or the output), so a head is whole
+    128-lane rows."""
+    if d % 128 != 0:
+        return (f"head size d={d} is not whole 128-lane rows: a head is no "
+                f"column block of the flat [B, S, H*D] rows")
+    if h % kv_heads != 0:
+        return f"{h} query heads do not divide over {kv_heads} key heads"
+    return flash_refusal(s, block_q, block_k)
+
+
+def flash_attention_band(q, k, v, window, sm_scale: Optional[float] = None,
+                         block_q: int = 512, block_k: int = 512):
+    """Causal attention, forward only, over grouped heads and a band.
+    ``q [B, S, H, D]``; ``k, v [B, S, Hk, D]`` with ``H`` a multiple of
+    ``Hk``: query head ``n`` reads key head ``n // (H / Hk)`` where it lies
+    (the key block's index, not a repeated copy). ``window``: an int32
+    scalar, traced or not; query ``i`` sees keys ``j`` with ``0 <= i - j <
+    window`` (``window >= S`` is plain causal attention, so ONE program
+    serves a stack whose layers differ in it). Key tiles wholly outside the
+    band are neither computed nor fetched (their block index is clamped
+    into the band, and a repeated index is no new DMA), as the tiles above
+    the diagonal. Operands meet the MXU in their own dtype with float32
+    accumulation; the probabilities are rounded to ``v``'s dtype before
+    they meet the values. Returns ``[B, S, H, D]``."""
+    b, s, h, d = q.shape
+    hk = k.shape[2]
+    reason = flash_band_refusal(s, d, h, hk, block_q, block_k)
+    if reason is not None:
+        refuse("flash_attention_band", q.shape, reason)
+    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
+    bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
+    group = h // hk
+
+    def q_at(bi, hi, qi, ki, win):
+        return bi, qi, hi
+
+    def kv_at(bi, hi, qi, ki, win):
+        lo, hi_blk = _band_blocks(win, qi, bq, bk)
+        return bi, jnp.clip(ki, lo, hi_blk), hi // group
+
+    kernel = functools.partial(_fwd_band_kernel, scale=scale, block_q=bq,
+                               block_k=bk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, h, s // bq, s // bk),
+        in_specs=[pl.BlockSpec((1, bq, d), q_at),
+                  pl.BlockSpec((1, bk, d), kv_at),
+                  pl.BlockSpec((1, bk, d), kv_at)],
+        out_specs=pl.BlockSpec((1, bq, d), q_at),
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, d), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, s, h * d), q.dtype),
+        name="flash_attention_fwd_band",
+        interpret=interpret_mode(),
+    )(jnp.asarray(window, jnp.int32).reshape(1), q.reshape(b, s, h * d),
+      k.reshape(b, s, hk * d), v.reshape(b, s, hk * d))
+    return out.reshape(b, s, h, d)

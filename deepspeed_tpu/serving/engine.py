@@ -299,6 +299,13 @@ class ServingEngine:
             self.spec_k = 0
 
         self.paged = bool(paged)
+        # a block kind that cannot take a width or option says so by name
+        refusal = getattr(self.module, "serving_refusal", None)
+        why = refusal and refusal(
+            speculative=self.speculative, fused_prefill=self.fused_prefill,
+            paged=self.paged, tp=self.tp)
+        if why:
+            raise NotImplementedError(why)
         if self.megakernel:
             self._check_megakernel_gates(cfg, int(kv_block_size))
         if self.paged:
@@ -487,8 +494,11 @@ class ServingEngine:
                                    mutable=["cache"], **told(true_lens))
             logits, routing = logits_and_routing(
                 out, positions < true_lens[:, None])
-            last = jnp.take_along_axis(
-                logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]  # [n,V]
+            # a model told where each row ends may hand out that row's
+            # last logits alone (a large vocabulary at a long bucket)
+            last = logits[:, 0] if logits.shape[1] == 1 else \
+                jnp.take_along_axis(
+                    logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]
             tok = sample_(last, rng, temperature_, top_k_, top_p_)
             if routing is not None:
                 return tok, vc["cache"], routing

@@ -358,6 +358,45 @@ def test_two_pair_live_read_compiles_for_v5e_at_serve_longdocs_shape(
     assert mem.temp_size_in_bytes < 4 * 2 ** 20, mem
 
 
+def test_band_kernel_compiles_for_v5e_at_serve_agents_longest_prefill(
+        v5e_2x2, monkeypatch):
+    """``serve-agent``'s prefill attention at its longest bucket: 16,384
+    tokens, 32 query heads on 4 key heads of 128 in bf16, the window a traced
+    scalar. Mosaic takes ``flash_attention_band`` over the flat ``[B, S,
+    H x D]`` rows (the heads as 128-lane column blocks, the key head by
+    ``head // 8``, the key block's index clamped into the band)."""
+    from jax.sharding import SingleDeviceSharding
+    import importlib
+    # (the package exports a function of the module's name)
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "interpret_mode", lambda: False)
+    one = SingleDeviceSharding(v5e_2x2[0])
+    s, h, hk, d = 16384, 32, 4, 128
+
+    def attend(q, k, v, window):
+        return fa.flash_attention_band(q, k, v, window)
+
+    def shape(heads):
+        return jax.ShapeDtypeStruct((1, s, heads, d), jnp.bfloat16,
+                                    sharding=one)
+
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(attend).lower(
+            shape(h), shape(hk), shape(hk),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == 1
+    # at most one relayout of q's [.., 32, 128] tiles into flat rows (none
+    # where the projection's own layout is free, as in the model's program);
+    # the kernel the repo had transposes q, k, v and the output
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 1.5 * s * h * d * 2
+
+
 # ------------------------------------------------------------------ parity
 @pytest.mark.parametrize("layers,mesh", SHAPES, ids=["dp4xtp2", "dp8"])
 @pytest.mark.parametrize("stage", [1, 2, 3])
