@@ -503,8 +503,8 @@ def kernel_leg(cfg, sz, on_chip: bool) -> None:
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.ops.pallas.decode_attention import (
-        decode_attention, live_decode_attention, masked_cache_attention,
-        paged_decode_attention)
+        decode_attention, live_decode_attention, live_latent_attention,
+        masked_cache_attention, paged_decode_attention)
     from deepspeed_tpu.ops.pallas.flash_attention import (
         flash_attention, reference_attention)
     from deepspeed_tpu.ops.pallas.gelu import bias_gelu, bias_gelu_reference
@@ -638,6 +638,25 @@ def kernel_leg(cfg, sz, on_chip: bool) -> None:
               q, k[1], v[1], n - 1, 1.0 / Dl ** 0.5), 0),
               ql, kl, vl, jnp.asarray(fills)),
           ["decode_attention_live"])
+
+    # ---- the latent block's decode read (models/mla.py): each lane's live
+    # blocks of the ONE layer-stacked leaf whose rows are key and value of
+    # every head, the value the row's first columns; the same fills
+    rowl, rl = 256, 128
+    lat, qrow = rn(Ll, B, S, rowl), rn(B, Hl, rowl)
+    seen = jnp.arange(S)[None, None, :] < jnp.asarray(fills)[:, None, None]
+
+    def absorbed(q, rows):
+        p = jax.nn.softmax(jnp.where(
+            seen, jnp.einsum("bhc,btc->bht", q, rows) / 16.0, -1e10), -1)
+        return jnp.where(live[:, 0], jnp.einsum("bht,btc->bhc", p,
+                                                rows[..., :rl]), 0)
+    agree("live_latent_attention layer-stacked rows",
+          lambda q, leaf, n, i: jnp.where(live[:, 0], live_latent_attention(
+              q, leaf, n, i, 1 / 16.0, rl), 0),
+          (qrow, lat, jnp.asarray(fills), jnp.int32(1)),
+          ref_of(lambda q, leaf: absorbed(q, leaf[1]), qrow, lat),
+          ["mla_decode_attention_live"])
 
     # ---- sampling epilogue: first-index argmax and the kept sets are
     # exact by construction. The references run under jit like the kernel:

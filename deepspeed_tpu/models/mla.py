@@ -118,10 +118,10 @@ def _dot(x, w):
 
 
 def latent_attention(cfg, p, x, positions, latent, cur, layer, absorbed):
-    """MLA over one layer's weights ``p``. ``latent`` None: no cache.
-    Otherwise the layer-stacked leaf; this call's rows are written at
-    ``(layer, row, cur)`` and the leaf is returned. ``absorbed``: attend over
-    the cache (it was handed in) instead of over this call's own tokens."""
+    """MLA over one layer's weights ``p``; with a ``latent`` leaf this call's
+    rows are written at ``(layer, lane, cur)`` and the leaf is returned. Not
+    ``absorbed`` (prefill): expanded heads over its own tokens, flash kernel.
+    ``absorbed`` (a handed cache): :func:`decode_read_block` says the read."""
     lc = cfg.block
     b, s, _ = x.shape
     h, r = cfg.num_heads, lc.kv_lora_rank
@@ -148,22 +148,22 @@ def latent_attention(cfg, p, x, positions, latent, cur, layer, absorbed):
 
     if absorbed:
         with jax.named_scope("mla/decode"):
-            rows = _layer_rows(latent, layer)               # [b, S, row]
             q_lat = jnp.einsum("bshn,chn->bshc", q[..., :dn], w_uk,
                                preferred_element_type=f32).astype(x.dtype)
             q_row = jnp.concatenate(
                 [q_lat, q[..., dn:], jnp.zeros((b, s, h, pad), x.dtype)], -1)
-            scores = jnp.einsum("bshc,btc->bhst", q_row, rows,
-                                preferred_element_type=f32) * scale
-            # query j of a row sits at cur + j and sees the keys up to there
-            last = jnp.reshape(cur, (-1, 1, 1, 1)) \
-                + jnp.arange(s, dtype=jnp.int32)[None, None, :, None]
-            seen = jnp.arange(rows.shape[1], dtype=jnp.int32) <= last
-            probs = jax.nn.softmax(jnp.where(seen, scores, -1e10), axis=-1)
-            # P over the whole row and the rope part dropped after: slicing
-            # c out of the cached rows first would copy the layer's cache
-            o_lat = jnp.einsum("bhst,btc->bshc", probs.astype(x.dtype), rows,
-                               preferred_element_type=f32)[..., :r]
+            if s == 1 and decode_read_block(cfg, b) is not None:
+                # one token a lane: the kernel takes the carried leaf whole
+                # and streams each lane's live blocks once, key and value
+                # in one buffer (ops/pallas/decode_attention.py)
+                from ..ops.pallas.decode_attention import \
+                    live_latent_attention
+                o_lat = live_latent_attention(
+                    q_row[:, 0], latent, cur + 1, layer, scale, r)[:, None]
+            else:
+                # any other handed width: every row of every lane
+                o_lat = _absorbed_over_the_whole_leaf(
+                    q_row, _layer_rows(latent, layer), cur, scale)[..., :r]
             ctx = jnp.einsum("bshc,chv->bshv", o_lat.astype(x.dtype), w_uv,
                              preferred_element_type=f32).astype(x.dtype)
     else:
@@ -367,8 +367,30 @@ FinalNorm = RMSNorm
 
 
 def decode_read_block(cfg, b: int):
-    """Absorbed attention reads every row of every lane's latent."""
-    return None
+    """Rows a block of the live-rows decode read carries, where a one-token
+    decode step of ``b`` lanes takes it; None where absorbed attention reads
+    every row of every lane. Which call attends how: prefill (no cache
+    handed) in EXPANDED heads over its own tokens through the flash kernel;
+    a handed cache and one token a lane ABSORBED over each lane's live
+    blocks of the latent leaf (ops/pallas/decode_attention.
+    live_latent_attention, kernel ``mla_decode_attention_live``); any other
+    handed width (speculative verify, fused prefill), and every call where
+    the gate refuses, ABSORBED over the whole leaf by the masked einsum
+    (:func:`_absorbed_over_the_whole_leaf`). ``decode_impl="auto"`` alone
+    chooses, from the platform, the mesh, the dtype and the shapes, as
+    ``gpt.live_read_block`` does for a NeoX block."""
+    if cfg.decode_impl != "auto":
+        return None
+    from ..ops.pallas import _utils as kernels
+    from ..ops.pallas.decode_attention import (live_latent_block,
+                                               live_latent_refusal)
+    from .gpt import _decode_mesh_refusal
+    refusal = live_latent_refusal(b, cfg.max_seq_len, cfg.num_heads,
+                                  cfg.block.cache_row, cfg.dtype) \
+        or _decode_mesh_refusal()
+    if not kernels.auto_path("mla_decode_attention", refusal):
+        return None
+    return live_latent_block(cfg.max_seq_len)
 
 
 def routing_counters(cfg, routed, live):
@@ -379,3 +401,21 @@ def routing_counters(cfg, routed, live):
                  expert_offset=cfg.block.expert_offset,
                  experts_held=cfg.block.experts_held)
 
+
+def _absorbed_over_the_whole_leaf(q_row, rows, cur, scale):
+    """Absorbed attention of ``q_row [b, s, h, row]`` over ALL of one layer's
+    cached ``rows [b, S, row]``, masked afterwards: query j of a lane sits at
+    ``cur + j`` and sees the keys up to there. Returns the float32
+    ``[b, s, h, row]`` of probabilities times rows; the caller drops the rope
+    part (slicing ``c`` out of the cached rows first would copy the layer's
+    cache). Below the prefill's flash call site, whose compiled body carries
+    the line numbers above it."""
+    s = q_row.shape[1]
+    scores = jnp.einsum("bshc,btc->bhst", q_row, rows,
+                        preferred_element_type=f32) * scale
+    last = jnp.reshape(cur, (-1, 1, 1, 1)) \
+        + jnp.arange(s, dtype=jnp.int32)[None, None, :, None]
+    seen = jnp.arange(rows.shape[1], dtype=jnp.int32) <= last
+    probs = jax.nn.softmax(jnp.where(seen, scores, -1e10), axis=-1)
+    return jnp.einsum("bhst,btc->bshc", probs.astype(q_row.dtype), rows,
+                      preferred_element_type=f32)

@@ -901,3 +901,258 @@ def live_decode_grouped_refusal(h: int, kv_heads: int) -> Optional[str]:
                 f"heads, and the live-rows read keeps one key row a query "
                 f"head")
     return None
+
+
+# --------------------------------------------------------------------------
+# Absorbed decode over each lane's LIVE rows of the layer-stacked latent leaf
+# (appended below every older call site: a compiled kernel body carries its
+# callers' line numbers, and the cells that run them keep their programs)
+# --------------------------------------------------------------------------
+
+def _live_latent_kernel(layer_ref, fill_ref, q_hbm, leaf_hbm, o_hbm, kv_buf,
+                        q_buf, o_buf, zero_buf, m_ref, l_ref, acc_ref, kv_sem,
+                        q_sem, o_sem, zero_sem, lane_of, blk_of, *, scale,
+                        block_k, b, S, vw):
+    """One program for all lanes over the ONE latent leaf [L, b, S, row],
+    whole in HBM, whose rows are key and value at once and shared by every
+    head. The schedule is :func:`_live_kernel`'s: the scalar core writes one
+    (lane, block) entry a LIVE block into SMEM, lane after lane (a fill of f
+    has ceil(f / block_k); a masked lane, its fill past S, has none and is
+    sent zeros), and ONE double-buffered loop walks it, the next block's DMA
+    in flight under this block's dots. The queries [b, h, row] and the
+    results [b, h, vw] stay in HBM too (64 lanes of 128 heads are 10.5 and
+    8.4 MB): a lane's [h, row] queries arrive in one of two slots, asked for
+    at the first block of the lane BEFORE it, and its result leaves from one
+    of two slots while the next lane computes.
+
+    Per block the ONE buffer [block_k, row] is both operands: scores
+    ``q [h, row] x block^T`` on the leaf's dtype with float32 accumulation,
+    masked by ``k < fill`` alone, and ``p x block[:, :vw]`` (the value is the
+    row's first columns, a lane-aligned slice of the buffer). Online-softmax
+    state lies in VMEM (the [h, vw] float32 accumulator is the vector
+    registers' whole file), reset at a lane's first block."""
+    layer = layer_ref[0]
+
+    def n_blocks(lane):
+        f = fill_ref[lane]
+        return jnp.where(f > S, 0, (f + block_k - 1) // block_k)
+
+    def kv_copy(i, slot):
+        at = (layer, lane_of[i], pl.ds(blk_of[i] * block_k, block_k))
+        return pltpu.make_async_copy(leaf_hbm.at[at], kv_buf.at[slot],
+                                     kv_sem.at[slot])
+
+    def q_copy(lane, slot):
+        return pltpu.make_async_copy(q_hbm.at[lane], q_buf.at[slot],
+                                     q_sem.at[slot])
+
+    def o_copy(lane, slot):
+        return pltpu.make_async_copy(o_buf.at[slot], o_hbm.at[lane],
+                                     o_sem.at[slot])
+
+    def zero_copy(lane):
+        return pltpu.make_async_copy(zero_buf, o_hbm.at[lane], zero_sem)
+
+    zero_buf[...] = jnp.zeros_like(zero_buf)
+
+    def lane_blocks(lane, carry):
+        n, dead = carry
+        nb = n_blocks(lane)
+
+        def put(j, n):
+            # stores to the kernel's SMEM scratch refs, not host state
+            lane_of[n] = lane   # tracelint: disable=mutation-in-trace
+            blk_of[n] = j       # tracelint: disable=mutation-in-trace
+            return n + 1
+
+        @pl.when(nb == 0)
+        def _no_rows():
+            zero_copy(lane).start()
+        return (jax.lax.fori_loop(0, nb, put, n),
+                dead + (nb == 0).astype(jnp.int32))
+
+    total, dead = jax.lax.fori_loop(0, b, lane_blocks,
+                                    (jnp.int32(0), jnp.int32(0)))
+    lane_of[total] = b      # no lane's: the last entry ends its lane
+
+    @pl.when(total > 0)
+    def _prologue():
+        kv_copy(0, 0).start()
+        q_copy(lane_of[0], 0).start()
+
+    col = jax.lax.broadcasted_iota(jnp.int32, (q_buf.shape[1], block_k), 1)
+
+    def body(i, begun):
+        """``begun``: the live lanes whose first block lies before entry i;
+        the lane of entry i uses query and result slot (begun - 1) % 2."""
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < total)
+        def _prefetch():
+            kv_copy(i + 1, 1 - slot).start()
+
+        lane, blk = lane_of[i], blk_of[i]
+        first = blk == 0
+        begun = begun + first.astype(jnp.int32)
+        mine = jax.lax.rem(begun - 1, 2)
+
+        @pl.when(first)
+        def _lane_begins():
+            q_copy(lane, mine).wait()
+            after = lane_of[i + n_blocks(lane)]
+
+            @pl.when(after < b)
+            def _next_lanes_queries():
+                q_copy(after, 1 - mine).start()
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        kv_copy(i, slot).wait()
+        sc = jax.lax.dot_general(
+            q_buf[mine], kv_buf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale        # [h, block_k]
+        sc = jnp.where(col < fill_ref[lane] - blk * block_k, sc, NEG_INF)
+        # a scheduled block holds a live key, so every head's max is finite
+        # and a masked column's exp is an exact zero
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        pv = jax.lax.dot(p.astype(kv_buf.dtype), kv_buf[slot, :, :vw],
+                         preferred_element_type=jnp.float32)    # [h, vw]
+        # stores to the kernel's VMEM scratch refs, not host state
+        l_ref[...] = (      # tracelint: disable=mutation-in-trace
+            l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True))
+        acc_ref[...] = (    # tracelint: disable=mutation-in-trace
+            acc_ref[...] * corr + pv)
+        m_ref[...] = m_new  # tracelint: disable=mutation-in-trace
+
+        @pl.when(lane_of[i + 1] != lane)
+        def _lane_done():
+            @pl.when(begun > 2)
+            def _slots_earlier_result_has_left():
+                o_copy(lane, mine).wait()
+            o_buf[mine] = (acc_ref[...] / l_ref[...]).astype(o_buf.dtype)
+            o_copy(lane, mine).start()
+        return begun
+
+    begun = jax.lax.fori_loop(0, total, body, jnp.int32(0))
+    for last in (1, 2):         # the results still on their way out
+        @pl.when(begun >= last)
+        def _drain(last=last):
+            o_copy(0, jax.lax.rem(begun - last, 2)).wait()
+
+    def zeros_landed(_, c):
+        zero_copy(0).wait()
+        return c
+    jax.lax.fori_loop(0, dead, zeros_landed, 0)
+
+
+# Rows a DMA of the latent live-rows read carries: one [rows, 640] buffer
+# that is key and value, 655 KB in bf16. A block costs ~0.67 us whatever its
+# rows (the [128, 512] float32 accumulator rescaled, the copy issued and
+# waited for, the loop's branches) and ~0.22 us more every 128 rows, so
+# larger blocks win until rounding a fill up outweighs them: five layers at
+# serve-reason's shapes and fills (64 lanes x 4096, 1,187 live rows a lane,
+# two lanes masked) took 2.70 ms at 128 rows, 1.68 at 256 and 1.37 at 512,
+# where the einsum over the whole leaf took 7.66; with every row live 8.63,
+# 4.96 and 3.60 (466 GB/s); under 300 rows a lane 0.67, 0.52 and 0.62 (my
+# chip run, PR 33). The reverse of _LIVE_BLOCK's finding: that kernel's
+# block is bound by its DMA, this one's by what surrounds its dots.
+_LIVE_LATENT_BLOCK = 512
+
+
+def live_latent_block(S: int) -> int:
+    """Rows a DMA of the latent live-rows read carries over S positions."""
+    return min(_LIVE_LATENT_BLOCK, S)
+
+
+def live_latent_refusal(b: int, S: int, h: int, row: int, dtype, s: int = 1,
+                        block_k: Optional[int] = None) -> Optional[str]:
+    """Why :func:`live_latent_attention` cannot run this shape; None when it
+    can: one query a lane, a plain floating leaf whose rows are whole
+    128-lane tiles, heads in whole sublane tiles, S in whole blocks. (The
+    mesh is the caller's to ask: ``gpt._decode_mesh_refusal``.)"""
+    if s != 1:
+        return ("more than one query a lane: the latent live-rows read "
+                "takes the decode width alone (speculative and fused-prefill "
+                "widths take the absorbed einsum over the whole leaf)")
+    dt = jnp.dtype(dtype)
+    if not jnp.issubdtype(dt, jnp.floating):
+        return (f"cache dtype {dt.name}: the latent live-rows read has no "
+                f"dequant in its window")
+    if row % 128 != 0:
+        return (f"a latent row of {row} values is not whole 128-lane tiles "
+                f"(models/mla.py pads 576 to 640)")
+    sublane = 32 // dt.itemsize
+    if h % sublane != 0:
+        return (f"h={h} heads are not whole {sublane}-row sublane tiles of "
+                f"{dt.name}")
+    bk = block_k or live_latent_block(S)
+    if S % bk != 0 or bk % sublane != 0:
+        return (f"cache length {S} is not a multiple of the {bk}-row block "
+                f"(itself whole {sublane}-row tiles)")
+    return None
+
+
+def live_latent_attention(q_row: jnp.ndarray, latent: jnp.ndarray, fills,
+                          layer, scale: float, v_width: int,
+                          block_k: Optional[int] = None) -> jnp.ndarray:
+    """Absorbed attention of one query a lane over the LIVE rows of a latent
+    cache (models/mla.py): ``softmax(q_row . row * scale) row[:v_width]``.
+
+    ``q_row`` [b, h, row]: a lane's queries in the cache row's own space
+    (``q_nope W_uk^T | rotated q_rope | zeros``). ``latent``: the leaf as the
+    layer loop carries it, [L, b, S, row], with ``layer`` a (traced) index;
+    every row is key and value of ALL h heads. ``fills``: valid rows a lane
+    (this token's among them), scalar or [b]; a lane whose fill is past S
+    is MASKED (the serving engine's retired-lane sentinel writes at
+    ``max_seq_len``): nothing of it is read and its output is zeros the
+    caller discards. Reads ceil(fill / block) blocks of each lane's rows and
+    nothing else of the leaf; no slice or reshape of the leaf is made on the
+    way in. Operands in the leaf's dtype, every accumulation float32, the
+    result rounded once. Returns [b, h, v_width] (the kernel computes whole
+    128-lane tiles of columns; a narrower value is cut out of them here)."""
+    b, h, row = q_row.shape
+    S = latent.shape[2]
+    bk = block_k or live_latent_block(S)
+    reason = live_latent_refusal(b, S, h, row, latent.dtype, 1, bk)
+    if reason is None and latent.shape[3] != row:
+        reason = f"queries of {row} values against rows of {latent.shape[3]}"
+    if reason is not None:
+        refuse("live_latent_attention",
+               f"q_row={q_row.shape} latent={latent.shape}", reason)
+    vw = -(-v_width // 128) * 128
+    dtype = latent.dtype
+    kernel = functools.partial(_live_latent_kernel, scale=scale, block_k=bk,
+                               b=b, S=S, vw=vw)
+    n_max = b * (S // bk) + 1       # every block of every lane, and the end
+    in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                # layer index, per-lane fills
+        grid=(1,),
+        in_specs=[in_hbm, in_hbm],
+        out_specs=in_hbm,
+        scratch_shapes=[
+            pltpu.VMEM((2, bk, row), dtype),              # kv_buf
+            pltpu.VMEM((2, h, row), dtype),               # q_buf
+            pltpu.VMEM((2, h, vw), dtype),                # o_buf
+            pltpu.VMEM((h, vw), dtype),                   # zero_buf
+            pltpu.VMEM((h, 1), jnp.float32),              # m
+            pltpu.VMEM((h, 1), jnp.float32),              # l
+            pltpu.VMEM((h, vw), jnp.float32),             # acc
+            pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA(()),
+            pltpu.SMEM((n_max,), jnp.int32), pltpu.SMEM((n_max,), jnp.int32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, vw), dtype),
+        name="mla_decode_attention_live",
+        interpret=interpret_mode(),
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      jnp.broadcast_to(jnp.asarray(fills, jnp.int32), (b,)),
+      q_row.astype(dtype), latent)
+    return out[..., :v_width]

@@ -358,6 +358,52 @@ def test_two_pair_live_read_compiles_for_v5e_at_serve_longdocs_shape(
     assert mem.temp_size_in_bytes < 4 * 2 ** 20, mem
 
 
+def test_latent_live_read_compiles_for_v5e_at_serve_reasons_shape(
+        v5e_2x2, monkeypatch):
+    """``serve-reason``'s decode read: 64 lanes x 4,096 rows of 640 under 128
+    heads in bf16, inside a layer loop of 5 that carries the ONE 1.68 GB
+    latent leaf and writes a token into it first. ONE kernel call, the leaf
+    aliased and no copy of a layer's rows beside it; queries and results
+    (10.5 and 8.4 MB) stay in HBM, so the kernel fits Mosaic's default
+    scoped VMEM at every block size ISSUE 33 had measured."""
+    from jax.sharding import SingleDeviceSharding
+    from deepspeed_tpu.models.gpt import _kv_write
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(da, "interpret_mode", lambda: False)
+    L, b, S, h, row, r = 5, 64, 4096, 128, 640, 512
+
+    def step(block):
+        def run(x, leaf, cur):
+            def body(c, layer):
+                x, leaf = c
+                leaf = _kv_write(leaf, x[:, :1], cur, layer)
+                o = da.live_latent_attention(x, leaf, cur + 1, layer, 0.07,
+                                             r, block_k=block)
+                return (jnp.pad(o, ((0, 0), (0, 0), (0, row - r))), leaf), None
+            return jax.lax.scan(body, (x, leaf),
+                                jnp.arange(L, dtype=jnp.int32))[0]
+        return run
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        for block in (da._LIVE_LATENT_BLOCK, 128, 256, 512):
+            compiled = jax.jit(step(block), donate_argnums=(1,)).lower(
+                jax.ShapeDtypeStruct((b, h, row), jnp.bfloat16, sharding=one),
+                jax.ShapeDtypeStruct((L, b, S, row), jnp.bfloat16,
+                                     sharding=one),
+                jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one)).compile()
+            hlo = compiled.as_text()
+            assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+            assert "mla_decode_attention_live" in hlo
+            mem = compiled.memory_analysis()
+            assert mem.alias_size_in_bytes >= L * b * S * row * 2
+            assert mem.temp_size_in_bytes < 4 * 2 ** 20, mem
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+
+
 def test_band_kernel_compiles_for_v5e_at_serve_agents_longest_prefill(
         v5e_2x2, monkeypatch):
     """``serve-agent``'s prefill attention at its longest bucket: 16,384
