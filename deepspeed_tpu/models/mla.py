@@ -183,17 +183,17 @@ def latent_attention(cfg, p, x, positions, latent, cur, layer, absorbed):
 def _tile_rows(cfg, tokens: int) -> int:
     """Rows of one tile of the expert loop: four times what an expert
     receives when routing is even (``tokens * k / n_routed_experts``), as a
-    power of two from 32 to 512. A tile reads its expert's matrices whole,
+    power of two from 32 to 256. A tile reads its expert's matrices whole,
     so an expert that needs a second tile is read twice: with tiles of 8
     rows at 64 lanes a popular expert did, and tokens/s moved 4 % with the
     seed's router (PERF.md, PR 26); at 32 rows a decode step reads every
     touched expert once. A tile's other costs follow its rows (the scatter
     of its result most of all: a tile of 256 under 16 rows was most of a
-    short prefill), so it is no larger than that. Nothing is dropped
-    either way: a popular expert takes more tiles."""
+    short prefill), so it is no larger than that, and never 512: PERF.md,
+    PR 34. Nothing is dropped: a popular expert takes more tiles."""
     lc = cfg.block
     even = tokens * lc.experts_per_token / max(lc.n_routed_experts, 1)
-    return int(min(512, max(32, 2 ** math.ceil(math.log2(max(4 * even, 1))))))
+    return int(min(256, max(32, 2 ** math.ceil(math.log2(max(4 * even, 1))))))
 
 
 def expert_ffn(cfg, p, banks, x):

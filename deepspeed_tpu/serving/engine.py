@@ -42,13 +42,13 @@ rivals the model's step time.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple, Union
 
 import numpy as np
 
 from ..telemetry import core as telemetry
 from ..utils.logging import log_dist
+from .device_timeline import DISAGGREGATED, DeviceTimeline
 from .kv_cache import SlotKVCacheManager
 from .metrics import ServingMetrics
 # The sampling policy moved to serving/sampling.py (one reference shared
@@ -93,9 +93,8 @@ class _InflightChunk:
     valid: Any           # [B, K] device (lane was live entering the step)
     state: Tuple         # (tok[B], pos[B], act[B], rem[B], eos[B]) device,
     #                      + hist[B, S] in speculative mode
-    # unconditional perf_counter stamp at launch: the collective-overlap
-    # gauge accumulates launch->retire wall seconds from it
-    wall_t0: float = 0.0
+    # its handle in the device timeline (None while telemetry is off)
+    program: Any = None
     # the chunk's routing counters, summed on the device over its steps
     # (a model with expert layers; fetched with the tokens, no sync of its own)
     routing: Any = None
@@ -227,7 +226,6 @@ class ServingEngine:
                 cfg = dataclasses.replace(cfg, **rebuild)
                 self.module = type(self.module)(cfg)
         self._overlap_active = bool(getattr(cfg, "tp_overlap", False))
-        self._overlap_seconds = 0.0
         self.max_batch = int(max_batch)
         self.max_seq_len = int(max_seq)
         self.max_prompt_len = int(max_prompt_len or max_seq)
@@ -440,6 +438,8 @@ class ServingEngine:
         # sync has returned with nothing dispatched behind it: the chip
         # idles until the next dispatch leaves the span (_device_fed)
         self._starved = None
+        self._timeline = DeviceTimeline(    # device time by program
+            DISAGGREGATED if self._handoff_sharding is not None else None)
         # crash flight recorder (telemetry.flight_recorder), attached by
         # the owning ServingFrontend; engine-side records are host-only
         # deque appends — no device work, no retrace surface
@@ -1212,7 +1212,7 @@ class ServingEngine:
                         self._device_state(self._pending))
                 self._consume_chunk(self._pending,
                                     device_queue_empty=nxt is None)
-                self._admit()
+                self._admit(ahead=nxt)
                 self._pending = nxt
         self._drop_starved_if_idle()
         return self.scheduler.finished[before:]
@@ -1368,6 +1368,7 @@ class ServingEngine:
         """Called where the next program is about to be dispatched."""
         if self._starved is not None:
             self._starved.__exit__(None, None, None)
+            self._timeline.starved(self._starved.t1 - self._starved.t0)
             self._starved = None
 
     def _drop_starved_if_idle(self) -> None:
@@ -1377,6 +1378,38 @@ class ServingEngine:
                 and not self.scheduler.has_work()):
             self._starved.drop()
             self._starved = None
+            self._timeline.reset()
+
+    # ------------------------------------------------- the device timeline
+    def _tl(self) -> Optional[DeviceTimeline]:
+        """The device timeline while telemetry is on (and the engine's
+        programs share one device queue), else None. Off means off: a
+        caller that gets None asks no array ``is_ready()``, waits on
+        nothing it did not wait on before and calls nothing of the
+        timeline's."""
+        if not telemetry.get_runtime().enabled:
+            return None
+        return self._timeline.on()
+
+    def _prefill_wait_stamped(self, tl: DeviceTimeline,
+                              ahead: Optional[_InflightChunk], program,
+                              toks) -> np.ndarray:
+        """``serve/prefill_wait`` with the timeline on: its two waits told
+        apart. The device runs the chunk ``pump()`` launched ahead of this
+        admission first, then the prefill, and ``np.asarray(toks)`` alone
+        would wait through both; blocking on the chunk's tokens first costs
+        nothing and makes its end a stamp of its own."""
+        import jax
+        if ahead is not None and tl.is_open(ahead.program):
+            exact = not ahead.tokens.is_ready()
+            with telemetry.span("serve/prefill_wait_chunk_ahead") as wait:
+                jax.block_until_ready(ahead.tokens)
+            tl.stamp(ahead.program, wait, exact)
+        exact = not toks.is_ready()
+        with telemetry.span("serve/prefill_wait_own") as wait:
+            toks_host = np.asarray(toks)
+        tl.stamp(program, wait, exact)
+        return toks_host
 
     def _count_routing(self, kind: str, routing) -> None:
         """``routing``: [] or [the counters a program summed on the device]
@@ -1404,8 +1437,10 @@ class ServingEngine:
                 return b
         return self._buckets[-1]    # unreachable: submit() length guard
 
-    def _admit(self) -> None:
-        """Admit every currently-runnable request. Dense: group by
+    def _admit(self, ahead: Optional[_InflightChunk] = None) -> None:
+        """Admit every currently-runnable request (``ahead``: the chunk
+        ``pump()`` has in flight in front of whatever is dispatched here,
+        for the device timeline). Dense: group by
         prefill bucket, ONE batched prefill per group, one fused arena
         insert per group. Paged: prefix-cache HITS skip prefill entirely
         (a block-table fork + the cached first token); MISSES take the
@@ -1432,12 +1467,12 @@ class ServingEngine:
             if not admitted:
                 return
             if self.fused_prefill:
-                self._fused_admit(admitted)
+                self._fused_admit(admitted, ahead)
                 if self.paged:
                     self._gauge_block_pool()
                 return
             if not self.paged:
-                self._prefill_admit(admitted)
+                self._prefill_admit(admitted, ahead=ahead)
                 return
             hits: List[Tuple[Request, Any]] = []
             misses: List[Tuple[Request, Any]] = []
@@ -1448,7 +1483,8 @@ class ServingEngine:
                 self._admit_prefix_hit(req, plan)
             if misses:
                 self._prefill_admit([r for r, _ in misses],
-                                    plans={r.slot: p for r, p in misses})
+                                    plans={r.slot: p for r, p in misses},
+                                    ahead=ahead)
             self._gauge_block_pool()
 
     def _admit_prefix_hit(self, req: Request, plan) -> None:
@@ -1496,7 +1532,8 @@ class ServingEngine:
             return (1 + self.spec_k) if self.speculative else 1
         return min(self.prefill_chunk, req.prompt_len)
 
-    def _fused_admit(self, admitted: List[Request]) -> None:
+    def _fused_admit(self, admitted: List[Request],
+                     ahead: Optional[_InflightChunk] = None) -> None:
         """Fused-mode admission: no bucketed prefill program. Inline
         lanes enter the scan in prefill mode (the scan body appends
         their KV chunk by chunk); paged MISSES only install their block
@@ -1537,7 +1574,8 @@ class ServingEngine:
                                    slot=req.slot,
                                    prompt_len=req.prompt_len)
         if sp_reqs:
-            self._prefill_admit(sp_reqs, plans=sp_plans or None)
+            self._prefill_admit(sp_reqs, plans=sp_plans or None,
+                                ahead=ahead)
 
     def _record_fused_admit_patch(self, req: Request) -> None:
         """Lane state for a freshly admitted INLINE prefill lane: pos 0,
@@ -1601,11 +1639,13 @@ class ServingEngine:
                             float(rep["promote_wait_p50_s"]))
 
     def _prefill_admit(self, admitted: List[Request],
-                       plans: Optional[Dict[int, Any]] = None) -> None:
+                       plans: Optional[Dict[int, Any]] = None,
+                       ahead: Optional[_InflightChunk] = None) -> None:
         """Bucketed batched prefill + fused cache insert for ``admitted``
         (the dense path verbatim; paged misses ride it too, with the
         block-scatter insert and a prefix-cache commit per request)."""
         import jax.numpy as jnp
+        tl = self._tl()
         groups: Dict[Tuple[int, bool], List[Request]] = {}
         for req in admitted:
             use_sp = (self._jit_prefill_sp is not None
@@ -1629,9 +1669,12 @@ class ServingEngine:
             # first tokens on the host, not the prefill's device time:
             # its dispatch queues behind whatever is already dispatched
             # (pump() launches the next decode chunk before it admits),
-            # and serve/prefill_wait, the np.asarray(toks) sync alone,
-            # waits for all of that. The device's own time is in the
-            # profiler's trace, under the programs' names
+            # and serve/prefill_wait waits for all of that. Its two
+            # children tell the waits apart (serve/prefill_wait_chunk_ahead,
+            # serve/prefill_wait_own), and the device's own time for this
+            # call is serve/device_prefill, which the timeline records from
+            # the ends of the two (a group's insert_batch runs after its
+            # sync and rides with the next group's prefill or the next chunk)
             with telemetry.span("serve/prefill", n=n, bucket=bucket,
                                 sp=use_sp,
                                 uids=str([r.uid for r in reqs])):
@@ -1645,12 +1688,22 @@ class ServingEngine:
                     toks, cache, *routing = prefill_fn(
                         self._prefill_params, jnp.asarray(ids),
                         jnp.asarray(lens), self._next_rng())
+                    program = None if tl is None else tl.dispatched(
+                        "prefill", n=n, bucket=bucket, sp=use_sp,
+                        prompt_tokens=int(lens.sum()),
+                        padded_tokens=n * bucket)
                     if self._handoff_sharding is not None:
                         cache = self._handoff(cache, reqs, bucket)
                     self.kv.insert_batch(cache, [r.slot for r in reqs],
                                          lens)
+                    if tl is not None:
+                        tl.dispatched("insert_batch")
                 with telemetry.span("serve/prefill_wait"):
-                    toks_host = np.asarray(toks)
+                    if tl is None:
+                        toks_host = np.asarray(toks)
+                    else:
+                        toks_host = self._prefill_wait_stamped(
+                            tl, ahead, program, toks)
                     self._count_routing("prefill", routing)
                 # everything dispatched before that sync has run
                 self._starve("serve/starved_after_prefill")
@@ -1839,6 +1892,9 @@ class ServingEngine:
                     vals[:, slots] = np.array([v[:4] for v in patches]).T
                 tok, pos, act, rem, eos = self._jit_lane_patch(
                     tok, pos, act, rem, eos, deact, admit, vals)
+                tl = self._tl()
+                if tl is not None:
+                    tl.dispatched("lane_patch")
                 if self._admit_patches:
                     # the fused and speculative programs' extra state: the
                     # admitted rows alone (a history row is max_seq_len wide)
@@ -1912,8 +1968,12 @@ class ServingEngine:
             self.kv.update(new_cache)
         inflight = _InflightChunk(
             slot_uids={s: r.uid for s, r in self.scheduler.running.items()},
-            tokens=toks, valid=valid, state=carry,
-            wall_t0=time.perf_counter(), routing=routing)
+            tokens=toks, valid=valid, state=carry, routing=routing)
+        tl = self._tl()
+        if tl is not None:
+            inflight.program = tl.dispatched(
+                "decode_chunk", k=self.decode_chunk,
+                lanes=len(inflight.slot_uids))
         if self.flight is not None:
             self.flight.record("chunk_launch", k=self.decode_chunk,
                                slot_uids=dict(inflight.slot_uids))
@@ -1952,18 +2012,19 @@ class ServingEngine:
         steps) and feed it through the scheduler. ``device_queue_empty``:
         no chunk was launched ahead of this sync, so when it returns the
         chip has nothing to run."""
-        with telemetry.span("serve/chunk_host_wait"):
+        # a chunk the timeline has already closed (stamped from inside a
+        # prefill's wait a pump ago) is ready by now and stamps nothing
+        tl = self._tl()
+        stamps = tl is not None and tl.is_open(chunk.program)
+        exact = stamps and not chunk.tokens.is_ready()
+        with telemetry.span("serve/chunk_host_wait") as wait:
             toks = np.asarray(chunk.tokens)
             valid = np.asarray(chunk.valid)
             self._count_routing("decode", chunk.routing)
+        if stamps:
+            tl.stamp(chunk.program, wait, exact)
         if device_queue_empty:
             self._starve("serve/starved_after_chunk")
-        if self._overlap_active and chunk.wall_t0:
-            # cumulative wall seconds of decode chunks served with the
-            # RS/AG collective/MLP overlap decomposition active
-            self._overlap_seconds += time.perf_counter() - chunk.wall_t0
-            telemetry.gauge("serve/collective_overlap_s",
-                            self._overlap_seconds)
         inline_tokens = 0
         n_first = 0
         pf_steps = None

@@ -126,9 +126,11 @@ class _Span:
     in ``__exit__`` (after the optional ``sync`` block), so attribute
     setup and lock acquisition never pollute the measured window. The
     profiler's annotation of the same region is entered before the clock
-    starts and left after it stops."""
+    starts and left after it stops. ``t0`` and ``t1`` are the two clock
+    readings, for a caller that chains what it records to a span's ends
+    (``ServingEngine``'s device timeline)."""
 
-    __slots__ = ("_rt", "name", "attrs", "_sync", "_t0", "_annotation")
+    __slots__ = ("_rt", "name", "attrs", "_sync", "t0", "t1", "_annotation")
 
     def __init__(self, rt: "TelemetryRuntime", name: str, sync,
                  attrs: Optional[Dict[str, Any]]):
@@ -136,7 +138,7 @@ class _Span:
         self.name = name
         self.attrs = attrs
         self._sync = sync
-        self._t0 = 0.0
+        self.t0 = self.t1 = 0.0
         self._annotation = None
 
     def __enter__(self):
@@ -144,16 +146,16 @@ class _Span:
         self._annotation = jax.profiler.TraceAnnotation(
             self.name, **(self.attrs or {}))
         self._annotation.__enter__()
-        self._t0 = self._rt.clock()
+        self.t0 = self._rt.clock()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         if self._sync is not None:
             import jax
             jax.block_until_ready(self._sync)
-        t1 = self._rt.clock()
+        self.t1 = self._rt.clock()
         self._annotation.__exit__(exc_type, exc, tb)
-        self._rt._record_span(self.name, self._t0, t1, self.attrs)
+        self._rt._record_span(self.name, self.t0, self.t1, self.attrs)
         return False
 
     def drop(self) -> None:

@@ -197,6 +197,20 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_experts(tile):
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
 
 
+@pytest.mark.parametrize("tokens,rows", [
+    (64, 32), (512, 64), (2048, 256), (3 * 1024, 256), (4 * 2048, 256)])
+def test_an_expert_tile_is_never_over_256_rows(tokens, rows):
+    """Four times an expert's even share as a power of two, from 32 to 256:
+    at 512 rows the prefill of three prompts of 1,024 took 0.63 s on the chip
+    where two took 0.08 (PERF.md, PR 34)."""
+    from deepspeed_tpu.models.mla import _tile_rows
+    cfg = arch.build_model({
+        **TOY, "n_routed_experts": 16, "num_experts_per_tok": 8,
+        "published": {"n_routed_experts": 256},
+        "deployment_share": {"expert_offset": 0}}).cfg
+    assert _tile_rows(cfg, tokens) == rows
+
+
 def test_the_banks_are_read_at_their_layer():
     import jax.numpy as jnp
     from deepspeed_tpu.moe.grouped import grouped_experts

@@ -330,11 +330,13 @@ def mega_model():
     return _mk_model()
 
 
-def _serve(model, params, prompts, megakernel, **kw):
+def _serve(model, params, prompts, megakernel, prepare=None, **kw):
     from deepspeed_tpu.serving import ServingEngine
     eng = ServingEngine(model, model_parameters=params,
                         dtype=jnp.float32, max_batch=4, max_prompt_len=16,
                         decode_chunk=4, megakernel=megakernel, **kw)
+    if prepare is not None:
+        prepare(eng)
     return eng, eng.run([p.copy() for p in prompts], max_new_tokens=10)
 
 
@@ -389,23 +391,32 @@ class TestMegakernelEngineParity:
         assert run(0) == run(0)
         assert run(0) != run(1)
 
-    def test_tp2_megakernel_bit_parity_with_overlap(self):
+    def test_tp2_megakernel_bit_parity_with_overlap(self, telemetry_on):
         """tp=2 + parallel residual: the megakernel engine flips
         cfg.tp_overlap on, decodes under its own variant name, and the
         deferred RS/AG collective keeps greedy bit-identical to the
-        composed tp=2 engine (two-term sum either way)."""
+        composed tp=2 engine (two-term sum either way). Its chunks' device
+        time is on the serve loop's device timeline (tp > 1 is one SPMD
+        queue), where the launch-to-retire wall seconds of a gauge of its
+        own used to be."""
         if len(jax.devices()) < 2:
             pytest.skip("needs 2 devices")
         from deepspeed_tpu.analysis.auditor import TraceAuditor
+        from tests.test_device_timeline import never_ready
         model, params = _mk_model(parallel_residual=True)
         prompts = self._prompts()
         _, base = _serve(model, params, prompts, megakernel=False, tp=2)
+        telemetry_on.clear()
+        # never_ready: every sync finds its array not ready (on the CPU a
+        # toy chunk may have ended before the host looks) and is a stamp
         with TraceAuditor(audit_jaxprs=False) as aud:
             eng, mega = _serve(model, params, prompts, megakernel=True,
-                               tp=2)
+                               prepare=never_ready, tp=2)
         assert eng.module.cfg.tp_overlap is True
         assert eng._overlap_active
-        assert eng._overlap_seconds > 0.0
+        stats = telemetry_on.span_stats()
+        assert stats["serve/device_decode_chunk"]["count"] == \
+            stats["serve/chunk_host_wait"]["count"] >= 2
         assert aud.compiles("decode_chunk_megakernel_tp2_fn") >= 1
         assert aud.compiles("decode_chunk_tp2_fn") == 0
         for b, g in zip(base, mega):
