@@ -589,10 +589,16 @@ def blocks_read(cfg, t, block: int):
 
 def routing_counters(cfg, routed, live):
     """moe/grouped.py::routing_counters over what :class:`AfmoeStack` handed
-    out: every expert is held here."""
-    from ..moe.grouped import routing_counters as count
-    return count(routed["expert_choice"], live, expert_offset=0,
-                 experts_held=cfg.block.n_routed_experts)
+    out: every expert is held here. The tiles are :func:`expert_ffn`'s: its
+    tile rows at the call's tokens, through the kernel where its call is."""
+    from ..moe.grouped import routing_counters as count, takes_kernel
+    choice = routed["expert_choice"]
+    tokens = choice.shape[1] * choice.shape[2]
+    tile = _tile_rows(cfg, tokens)
+    return count(choice, live, expert_offset=0,
+                 experts_held=cfg.block.n_routed_experts, tile=tile,
+                 kernel=takes_kernel(tokens, cfg.d_model, cfg.block.moe_d_ff,
+                                     tile, cfg.dtype))
 
 
 def step_counters(cfg, positions, live):

@@ -395,11 +395,17 @@ def decode_read_block(cfg, b: int):
 
 def routing_counters(cfg, routed, live):
     """moe/grouped.py::routing_counters over what :class:`LatentStack`
-    handed out, for the experts this chip holds."""
-    from ..moe.grouped import routing_counters as count
-    return count(routed["expert_choice"], live,
-                 expert_offset=cfg.block.expert_offset,
-                 experts_held=cfg.block.experts_held)
+    handed out, for the experts this chip holds. The tiles are
+    :func:`expert_ffn`'s: its tile rows at the call's tokens, through the
+    kernel where its call is."""
+    from ..moe.grouped import routing_counters as count, takes_kernel
+    choice = routed["expert_choice"]
+    tokens = choice.shape[1] * choice.shape[2]
+    tile = _tile_rows(cfg, tokens)
+    return count(choice, live, expert_offset=cfg.block.expert_offset,
+                 experts_held=cfg.block.experts_held, tile=tile,
+                 kernel=takes_kernel(tokens, cfg.d_model, cfg.block.moe_d_ff,
+                                     tile, cfg.dtype))
 
 
 def _absorbed_over_the_whole_leaf(q_row, rows, cur, scale):

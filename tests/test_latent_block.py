@@ -279,7 +279,7 @@ def test_routing_counters_count_live_tokens_only():
     live = np.array([[True, True, False], [True, False, False]])
     got = {k: float(v) for k, v in routing_counters(
         jnp.asarray(choice), jnp.asarray(live), expert_offset=4,
-        experts_held=4).items()}
+        experts_held=4, tile=2).items()}
     assert set(got) == set(COUNTERS)
     # layer 0 live: (4,9) (4,5) (7,4) -> loads 4:3 5:1 7:1; layer 1 live:
     # (4,5) (6,7) (4,5) -> loads 4:2 5:2 6:1 7:1
@@ -288,9 +288,32 @@ def test_routing_counters_count_live_tokens_only():
     assert got["load_max"] == 3 + 2
     assert got["load_mean"] == pytest.approx(11 / 4)
     assert got["steps"] == 2
+    # tiles are what RAN, live or not: layer 0 loads 4:3 5:2 6:1 7:1 at two
+    # rows a tile, layer 1 loads 4:4 5:2 6:1 7:1; none through the kernel here
+    assert got["tiles"] == (2 + 1 + 1 + 1) + (2 + 1 + 1 + 1)
+    assert got["kernel_tiles"] == 0
     none = routing_counters(jnp.asarray(choice), jnp.zeros((2, 3), bool),
-                            expert_offset=4, experts_held=4)
-    assert all(float(v) == 0 for v in none.values())
+                            expert_offset=4, experts_held=4, tile=2)
+    assert all(float(v) == 0 for k, v in none.items() if k != "tiles")
+    assert float(none["tiles"]) == got["tiles"]
+    through = routing_counters(jnp.asarray(choice), jnp.asarray(live),
+                               expert_offset=4, experts_held=4, tile=2,
+                               kernel=True)
+    assert float(through["kernel_tiles"]) == got["tiles"]
+
+
+def test_a_decode_step_reads_a_touched_expert_once(toy):
+    """64 tokens, every lane live, tiles of 32 rows: no held expert gets a
+    second tile, so ``tiles / experts_touched`` is 1.0; on the CPU ``auto``
+    takes the loop, so none of them went through the kernel."""
+    import jax.numpy as jnp
+    model, params = toy
+    ids = _ids(np.random.default_rng(12), 64, 1)
+    _, routed = model.apply({"params": params}, ids)
+    got = {k: float(v) for k, v in model.routing_counters(
+        routed, jnp.ones((64, 1), bool)).items()}
+    assert got["tiles"] == got["experts_touched"] > 0
+    assert got["kernel_tiles"] == 0
 
 
 # ----------------------------------------- (c) the cut model, through serving

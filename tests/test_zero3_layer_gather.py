@@ -443,6 +443,50 @@ def test_band_kernel_compiles_for_v5e_at_serve_agents_longest_prefill(
         < 1.5 * s * h * d * 2
 
 
+@pytest.mark.parametrize("tokens,held,d,f", [
+    (64, 128, 2048, 1024), (64, 16, 7680, 2048), (256, 16, 7680, 2048)],
+    ids=["serve-agent-decode", "serve-reason-decode", "serve-reason-256"])
+def test_expert_tiles_kernel_compiles_for_v5e_at_the_cells_shapes(
+        v5e_2x2, monkeypatch, past_auto_path, tokens, held, d, f):
+    """The expert tiles of a decode step of both sparse cells and of
+    ``serve-reason``'s 256-token prefill, top-8, tiles of 32 rows, the banks
+    of four layers read at a traced layer: Mosaic takes ``grouped_mlp`` with
+    an expert of ``[2048, 1024]`` in one grid step (both buffers 25.2 MB)
+    and one of ``[7680, 2048]`` in blocks of 512 columns (47.2 MB) under its
+    100 MiB limit, the transposed one-hot product among its dots, and the
+    program holds ONE custom call and no copy of a bank beside it."""
+    from jax.sharding import SingleDeviceSharding
+    from deepspeed_tpu.moe.grouped import grouped_experts
+    from deepspeed_tpu.ops.pallas import grouped_mlp as gm
+    monkeypatch.setattr(gm, "interpret_mode", lambda: False)
+    one = SingleDeviceSharding(v5e_2x2[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def experts(x, choice, weights, gate, up, down, layer):
+        return grouped_experts(x, choice, weights, gate, up, down,
+                               lead=(layer,), tile=32)
+
+    bank = 4 * held * d * f * 2
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(experts).lower(
+            sds((tokens, d), jnp.bfloat16), sds((tokens, 8), jnp.int32),
+            sds((tokens, 8), jnp.float32),
+            sds((4, held, d, f), jnp.bfloat16),
+            sds((4, held, d, f), jnp.bfloat16),
+            sds((4, held, f, d), jnp.bfloat16),
+            sds((), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert ("grouped_mlp", None) in past_auto_path
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < bank // 100
+
+
 # ------------------------------------------------------------------ parity
 @pytest.mark.parametrize("layers,mesh", SHAPES, ids=["dp4xtp2", "dp8"])
 @pytest.mark.parametrize("stage", [1, 2, 3])
