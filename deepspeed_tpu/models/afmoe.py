@@ -41,13 +41,18 @@ say which kind a layer is and which slot of its leaf it owns:
     all four leaves through the layer loop. Every layer issues BOTH writes
     and the one into the leaf it does not own is sent out of range and
     dropped (``gpt._kv_write``), so the writes do not branch; the READ
-    branches on the kind (``lax.cond``), each side a masked einsum over its
-    leaf's rows where they lie. The queries meet the flat rows as
-    ``[h, hk * dh]`` with zeros outside their own head's 128 columns, so no
+    branches on the kind (``lax.cond``), each side a read of its own leaf's
+    rows where they lie: on a TPU the live-rows kernel over each lane's
+    LIVE blocks of that one pair (``ops/pallas/decode_attention.
+    live_decode_attention``, :func:`decode_read_block` says where), else a
+    masked einsum over the leaf whole (:func:`cache_attention`, also the
+    kernel's reference). Either way the queries meet the flat rows as
+    ``[h, hk * dh]`` with zeros outside their own head's columns, so no
     per-head view of the rows is made (a reshape of a tiled leaf is a copy of
     it). A cursor at or past ``max_seq_len`` is the serving engine's
     retired-lane sentinel: ``max_seq_len mod w`` is a row of the ring, so a
-    dead lane's ring row is sent past the leaf explicitly.
+    dead lane's ring row, and its fill of either leaf, is sent past the leaf
+    explicitly.
   * a call that has no cache, or creates one (prefill), attends over its own
     tokens through ONE attention program for both kinds, the window a traced
     scalar (``ops/pallas/flash_attention.flash_attention_band`` where its
@@ -255,8 +260,19 @@ def afmoe_attention(cfg, p, x, positions, is_full, leaves, cur, slot):
         gk = _kv_write(gk, k.astype(gk.dtype), glob_row, slot)
         gv = _kv_write(gv, v.astype(gv.dtype), glob_row, slot)
         n_ring, n_glob = live_rows(cfg, cur)
+        block = decode_read_block(cfg, b)
 
         def read(key, value, fill):
+            if block is not None:
+                # each lane's live blocks of the pair this layer owns; a dead
+                # lane's fill lies past the leaf (max_seq_len mod w would
+                # read as live ring rows)
+                from ..ops.pallas.decode_attention import \
+                    live_decode_attention
+                past = key.shape[2] + 1
+                return live_decode_attention(
+                    q, [(key, value, jnp.where(dead, past, fill))], slot,
+                    block_k=block)
             seen = jnp.arange(key.shape[2], dtype=jnp.int32)[None, :] \
                 < fill[:, None]
             return cache_attention(q, _layer_rows(key, slot),
@@ -554,27 +570,26 @@ def serving_refusal(cfg, **asked):
 
 def decode_read_block(cfg, b: int):
     """Rows a block of the live-rows decode read carries where a decode step
-    of ``b`` lanes takes it; None where it reads the leaf of the layer's
-    kind whole with the masked einsum. ``live_decode_attention`` keeps one
-    key row a query head and refuses grouped heads by name, so None wherever
-    ``hk < h``; the question is asked so that the path taken is logged, and
-    ``step_counters`` says what it costs."""
+    of ``b`` lanes takes it (``ops/pallas/decode_attention.
+    live_decode_attention`` over the flat rows of the ONE pair a layer owns,
+    its block sized by the rows' bytes); None where it reads the leaf of the
+    layer's kind whole with the masked einsum. ``decode_impl="auto"`` alone
+    chooses, from the platform, the mesh, the dtype and both leaves'
+    shapes, as ``gpt.live_read_block`` does for a NeoX block;
+    ``step_counters`` and the serving engine count the blocks it reads."""
     if cfg.decode_impl != "auto":
         return None
     from ..ops.pallas import _utils as kernels
-    from ..ops.pallas.decode_attention import (live_block,
-                                               live_decode_grouped_refusal,
-                                               live_decode_refusal)
+    from ..ops.pallas.decode_attention import live_block, live_decode_refusal
     from .gpt import _decode_mesh_refusal
     bc = cfg.block
     rows = (bc.sliding_window, cfg.max_seq_len)
-    refusal = (live_decode_grouped_refusal(cfg.num_heads, bc.num_kv_heads)
-               or live_decode_refusal(b, rows, cfg.num_heads, bc.head_dim,
-                                      cfg.dtype)
-               or _decode_mesh_refusal())
+    refusal = live_decode_refusal(b, rows, cfg.num_heads, bc.head_dim,
+                                  cfg.dtype, row=bc.row) \
+        or _decode_mesh_refusal()
     if not kernels.auto_path("decode_attention", refusal):
         return None
-    return live_block(min(rows))
+    return live_block(min(rows), bc.row * jnp.dtype(cfg.dtype).itemsize)
 
 
 def blocks_read(cfg, t, block: int):

@@ -20,7 +20,9 @@ over the KV cache.
   LIST of (k leaf, v leaf, fills) pairs under one softmax a lane: one for
   a NeoX block, two for a block that keeps a window beside chunk summaries
   (models/eva.py: each lane's live window blocks, then its live summary
-  blocks, walked by the same loop).
+  blocks, walked by the same loop). Leaves of one rank less are flat rows
+  of grouped heads (models/afmoe.py: a ring pair or a global pair a
+  layer), read by the same loop in blocks sized by the row's bytes.
 * :func:`decode_attention` / :func:`paged_decode_attention` — the older
   kernels, asked for BY NAME (``decode_impl="pallas"``,
   ``megakernel=True``): all lanes ride one DMA window sized by the
@@ -375,16 +377,20 @@ def decode_attention(q: jnp.ndarray, cached_key: jnp.ndarray,
 # Dense decode over each lane's LIVE rows of the layer-stacked arena
 # --------------------------------------------------------------------------
 
-def _live_kernel(layer_ref, *refs, scale, block_k, b, rows, h, d):
+def _live_kernel(layer_ref, *refs, scale, block_k, b, rows, h, d,
+                 kv_heads=None):
     """One program for all lanes, over ``len(rows)`` (k leaf, v leaf, fills)
     pairs under ONE softmax a lane. ``refs``: the pairs' fills (scalar
     prefetch), the queries, then each pair's arena leaves
-    [L, b, rows[p], h, d] WHOLE in HBM; k_buf/v_buf: [2, block_k, h, d] VMEM
-    slots that every pair's blocks share. The scalar core first writes the
-    step's schedule into SMEM — one entry (lane, block) per LIVE block, lane
-    after lane, and within a lane pair after pair: a fill of f has
-    ceil(f / block_k) of them, a masked lane (a fill past its leaf's rows,
-    the engine's retired-lane sentinel) none in any pair — and ONE
+    [L, b, rows[p], h, d] WHOLE in HBM (``kv_heads`` given: FLAT rows
+    [L, b, rows[p], d] of that many key heads that the h queries share);
+    k_buf/v_buf: VMEM slots of [2, block_k, h, d] ([2, block_k, d]) that
+    every pair's blocks share.
+    The scalar core first writes the step's schedule into SMEM — one entry
+    (lane, block) per LIVE block, lane after lane, and within a lane pair
+    after pair: a fill of f has ceil(f / block_k) of them, a masked lane (a
+    fill past its leaf's rows, the engine's retired-lane sentinel) none in
+    any pair — and ONE
     double-buffered loop then walks it, so the DMA of a lane's first block
     is in flight while the lane before it computes its last, whichever leaf
     either lies in. Online-softmax state rides the loop carry and starts
@@ -397,7 +403,12 @@ def _live_kernel(layer_ref, *refs, scale, block_k, b, rows, h, d):
     free reshape, h being whole sublane tiles), the h queries meet all of
     them in one dot on bf16 operands with float32 accumulation, and the
     mask keeps of column (k, g) the row g alone (and k < fill), so the
-    rest of the body is flash attention with one query row a head."""
+    rest of the body is flash attention with one query row a head. Over
+    flat rows the queries come widened to the row's d columns (zeros
+    outside their own key head's) and meet the [block_k, d] block as it
+    lies: scores [h, block_k], masked by k < fill alone; p @ v gives every
+    head's context in its own key head's columns of [h, d], and a lane's
+    result keeps those columns alone, [h, d / kv_heads]."""
     P = len(rows)
     fill_refs, q_ref, leaves = refs[:P], refs[P], refs[P + 1:3 * P + 1]
     o_ref, k_buf, v_buf, k_sem, v_sem, lane_of, blk_of, *pair_of = \
@@ -447,14 +458,20 @@ def _live_kernel(layer_ref, *refs, scale, block_k, b, rows, h, d):
     def _prologue():
         start(0, 0)
 
-    # column (k, g) of a block's [h, block_k*h] scores is key k under head
-    # g's rows: row g keeps it while k is under the lane's fill, no other
-    # row ever does
-    n = block_k * h
-    col = jax.lax.broadcasted_iota(jnp.int32, (h, n), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (h, n), 0)
-    never = max(rows)                                  # under no fill
-    own_key = jnp.where(col % h == row, col // h, never)
+    flat = kv_heads is not None
+    if flat:
+        # column k of a block's [h, block_k] scores is key k for every head
+        n = block_k
+        own_key = jax.lax.broadcasted_iota(jnp.int32, (h, n), 1)
+    else:
+        # column (k, g) of a block's [h, block_k*h] scores is key k under
+        # head g's rows: row g keeps it while k is under the lane's fill, no
+        # other row ever does
+        n = block_k * h
+        col = jax.lax.broadcasted_iota(jnp.int32, (h, n), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (h, n), 0)
+        never = max(rows)                              # under no fill
+        own_key = jnp.where(col % h == row, col // h, never)
 
     def body(i, carry):
         m_prev, l_prev, acc = carry                # [h,1] [h,1] [h,d]
@@ -495,7 +512,16 @@ def _live_kernel(layer_ref, *refs, scale, block_k, b, rows, h, d):
 
         @pl.when(lane_of[i + 1] != lane)
         def _lane_done():
-            o_ref[lane] = (acc / l_new).astype(o_ref.dtype)
+            out = acc / l_new
+            if flat:
+                # head n's context lies in key head n // (h / kv_heads)'s
+                # columns; the others hold what its zeros met
+                dh, group = d // kv_heads, h // kv_heads
+                head = jax.lax.broadcasted_iota(jnp.int32, (h, 1), 0)
+                out = functools.reduce(jnp.add, [
+                    jnp.where(head // group == g, out[:, g * dh:(g + 1) * dh],
+                              0.0) for g in range(kv_heads)])
+            o_ref[lane] = out.astype(o_ref.dtype)
         return m_new, l_new, acc
 
     jax.lax.fori_loop(
@@ -511,21 +537,38 @@ def _live_kernel(layer_ref, *refs, scale, block_k, b, rows, h, d):
 # at every fill tried (1.43 against 1.33 ms for serve-batch's 16 layers; my
 # chip run, PR 29).
 _LIVE_BLOCK = 128
+# Bytes of a leaf a block of FLAT rows carries at the least: a row of
+# grouped heads is a few key heads wide (512 values in serve-agent, 1 KiB),
+# so 128 of them would be a 128 KiB DMA that the loop's own cost a block
+# outweighs. serve-agent's five layers' read at its cell's shapes took 4.38
+# ms in blocks of 128 rows, 3.34 at 256, 3.05 at 512, 3.16 at 1,024 and
+# 3.23 at 2,048 (the masked einsum 6.65; one TPU v5e chip): past 512 KiB
+# a block rounds a fill up by more than it saves.
+_LIVE_BLOCK_BYTES = 1 << 19
 
 
-def live_block(S: int) -> int:
-    """Rows a DMA of the live-rows read carries over a cache of S rows."""
-    return min(_LIVE_BLOCK, S)
+def live_block(S: int, row_bytes: Optional[int] = None) -> int:
+    """Rows a DMA of the live-rows read carries over a cache of S rows:
+    ``_LIVE_BLOCK`` of a head a query head; of flat rows of ``row_bytes``
+    (grouped heads) the largest power of two that carries
+    ``_LIVE_BLOCK_BYTES``, and never fewer than ``_LIVE_BLOCK``."""
+    rows = _LIVE_BLOCK
+    if row_bytes is not None:
+        rows = max(rows, 1 << ((_LIVE_BLOCK_BYTES // row_bytes).bit_length()
+                               - 1))
+    return min(rows, S)
 
 
 def live_decode_refusal(b: int, S, h: int, d: int, dtype, s: int = 1,
-                        block_k: Optional[int] = None) -> Optional[str]:
+                        block_k: Optional[int] = None,
+                        row: Optional[int] = None) -> Optional[str]:
     """Why :func:`live_decode_attention` cannot run this shape; None when
     it can. ``S``: the rows of a lane's leaf, or of each pair's leaves (one
-    block size serves them all). It reads the rank-4 rows as they lie, so
-    it takes what lies without padding: a head of whole 128-lane rows,
-    heads in whole sublane tiles, a plain floating cache, one query a
-    lane."""
+    block size serves them all). ``row``: the values of a FLAT row of
+    grouped heads (``hk * d``), None for rows of a head a query head. It
+    reads the rows as they lie, so it takes what lies without padding: a
+    head (a flat row) of whole 128-lane rows, heads in whole sublane
+    tiles, a plain floating cache, one query a lane."""
     if s != 1:
         return ("more than one query a lane: the live-rows read takes the "
                 "decode width alone (prefill, speculative and fused-prefill "
@@ -535,14 +578,21 @@ def live_decode_refusal(b: int, S, h: int, d: int, dtype, s: int = 1,
         return (f"cache dtype {dt.name}: the live-rows read has no dequant "
                 f"in its window")
     sublane = 32 // dt.itemsize
-    if d % 128 != 0:
+    if row is None and d % 128 != 0:
         return (f"head size d={d} is not whole 128-lane rows: a rank-4 "
                 f"[b, S, h, d] leaf is lane-padded in HBM")
+    if row is not None and row % 128 != 0:
+        return (f"a flat row of {row} values is not whole 128-lane rows: "
+                f"its blocks would be lane-padded in VMEM")
+    if row is not None and (row % d != 0 or h % (row // d) != 0):
+        return (f"a flat row of {row} values is not key heads of d={d} "
+                f"that h={h} query heads share evenly")
     if h % sublane != 0:
         return (f"h={h} heads are not whole {sublane}-row sublane tiles of "
                 f"{dt.name}")
     rows = (S,) if isinstance(S, int) else tuple(S)
-    bk = block_k or live_block(min(rows))
+    bk = block_k or live_block(min(rows),
+                               None if row is None else row * dt.itemsize)
     for n in rows:
         if n % bk != 0:
             return (f"cache length {n} is not a multiple of the {bk}-row "
@@ -559,20 +609,26 @@ def live_decode_attention(q: jnp.ndarray, pairs, layer=None,
     models/eva.py: two). The leaves as the layer loop carries them,
     [L, b, S, h, d] with ``layer`` a (traced) index, or one layer's
     [b, S, h, d] with ``layer`` None; S may differ from pair to pair.
-    ``fills``: the pair's valid rows a lane (this token's included, already
-    written), scalar or [b]; a lane with a fill past its leaf's S is MASKED
-    (the serving engine's retired-lane sentinel writes at ``max_seq_len``):
-    nothing of it is read in any pair and its output is zeros the caller
-    discards. Reads ceil(fill / block) blocks of each lane's rows in each
-    pair and nothing else of the leaves; no slice or reshape of a leaf is
-    made on the way in. Returns [b, 1, h, d]."""
+    Leaves of one rank less are FLAT rows of grouped heads, [L, b, S,
+    hk * d] (models/afmoe.py): query head n reads key head n // (h / hk),
+    so the queries are widened to the row's columns with zeros outside
+    their own key head's, and each head's context is read back out of its
+    own columns. ``fills``: the pair's valid rows a lane (this token's
+    included, already written), scalar or [b]; a lane with a fill past its
+    leaf's S is MASKED (the serving engine's retired-lane sentinel writes
+    at ``max_seq_len``): nothing of it is read in any pair and its output
+    is zeros the caller discards. Reads ceil(fill / block) blocks of each
+    lane's rows in each pair and nothing else of the leaves; no slice or
+    reshape of a leaf is made on the way in. Returns [b, 1, h, d]."""
     b, s_q, h, d = q.shape
     if layer is None:
         pairs, layer = [(k[None], v[None], f) for k, v, f in pairs], 0
     rows = tuple(k.shape[2] for k, _, _ in pairs)
     dtype = pairs[0][0].dtype
-    bk = block_k or live_block(min(rows))
-    reason = live_decode_refusal(b, rows, h, d, dtype, s_q, bk)
+    row = pairs[0][0].shape[3] if pairs[0][0].ndim == 4 else None
+    bk = block_k or live_block(
+        min(rows), None if row is None else row * jnp.dtype(dtype).itemsize)
+    reason = live_decode_refusal(b, rows, h, d, dtype, s_q, bk, row=row)
     if reason is not None:
         refuse("live_decode_attention",
                f"q={q.shape} cache={[k.shape for k, _, _ in pairs]}", reason)
@@ -581,20 +637,31 @@ def live_decode_attention(q: jnp.ndarray, pairs, layer=None,
     fills = [jnp.broadcast_to(jnp.asarray(f, jnp.int32), (b,))
              for _, _, f in pairs]
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    queries, width, block, kv_heads = q.reshape(b, h, d), d, (h, d), None
+    if row is not None:
+        # [h, hk]: 1 where query head n reads key head n // (h / hk)
+        kv_heads = row // d
+        own = (jnp.arange(h)[:, None] // (h // kv_heads)
+               == jnp.arange(kv_heads)[None, :]).astype(q.dtype)
+        queries = (queries[:, :, None, :] * own[None, :, :, None]
+                   ).reshape(b, h, row)
+        width, block = row, (row,)
     kernel = functools.partial(_live_kernel, scale=scale, block_k=bk, b=b,
-                               rows=rows, h=h, d=d)
+                               rows=rows, h=h, d=width, kv_heads=kv_heads)
     # the schedule: every block of every lane, and the entry that ends it
     n_max = b * sum(S // bk for S in rows) + 1
-    whole = pl.BlockSpec((b, h, d), lambda g, *prefetched: (0, 0, 0))
+
+    def whole(n):
+        return pl.BlockSpec((b, h, n), lambda g, *prefetched: (0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1 + len(pairs),   # layer index + per-lane fills
         grid=(1,),
-        in_specs=[whole] + [pl.BlockSpec(memory_space=pltpu.HBM)]
+        in_specs=[whole(width)] + [pl.BlockSpec(memory_space=pltpu.HBM)]
         * (2 * len(pairs)),
-        out_specs=whole,
+        out_specs=whole(d),
         scratch_shapes=[
-            pltpu.VMEM((2, bk, h, d), dtype),
-            pltpu.VMEM((2, bk, h, d), dtype),
+            pltpu.VMEM((2, bk) + block, dtype),
+            pltpu.VMEM((2, bk) + block, dtype),
             pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA((2,)),
         ] + [pltpu.SMEM((n_max,), jnp.int32)]
         * (2 if len(pairs) == 1 else 3),
@@ -604,7 +671,7 @@ def live_decode_attention(q: jnp.ndarray, pairs, layer=None,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         name="decode_attention_live",
         interpret=interpret_mode(),
-    )(layer, *fills, q.reshape(b, h, d),
+    )(layer, *fills, queries,
       *(leaf for k, v, _ in pairs for leaf in (k, v)))
     return out[:, None]
 
@@ -884,23 +951,6 @@ def masked_cache_attention(q, ck, cv, first_q_pos, scale, window=None):
     logits = jnp.where(visible, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, cv)
-
-
-
-def live_decode_grouped_refusal(h: int, kv_heads: int) -> Optional[str]:
-    """Why :func:`live_decode_attention` cannot read a cache whose rows hold
-    ``kv_heads`` key heads under ``h`` query heads; None when every query
-    head has its own (appended here, below the kernels' call sites, whose
-    line numbers their compiled bodies carry). ``_live_kernel`` meets the
-    ``h`` queries with a block's ``[block_k * h, d]`` rows and keeps of
-    column ``(k, g)`` the row ``g`` alone: a row of ``kv_heads * d`` values
-    that ``h / kv_heads`` query heads share wants an own-GROUP mask and a
-    schedule over rows a quarter as wide, which is ROADMAP R1a's."""
-    if kv_heads != h:
-        return (f"grouped-query heads: {h} query heads read {kv_heads} key "
-                f"heads, and the live-rows read keeps one key row a query "
-                f"head")
-    return None
 
 
 # --------------------------------------------------------------------------

@@ -1997,11 +1997,14 @@ class ServingEngine:
             -(-getattr(self.kv, "rows_per_slot", self.max_seq_len) // block)
         read = arena
         if self._kv_read_block is not None:
+            # every lane's positions in the chunk, asked of the model at once
+            # and summed once (a block kind's count may be a mean over its
+            # layers, a fraction a lane)
             fill = self.kv.allocator.fill
-            read = sum(
-                int(np.sum(self.module.blocks_read(
-                    int(fill[slot]) + np.arange(len(seq)), block)))
-                for slot, seq in per_slot.items())
+            positions = np.concatenate([np.zeros(0, np.int64)] + [
+                int(fill[slot]) + np.arange(len(seq))
+                for slot, seq in per_slot.items()])
+            read = float(np.sum(self.module.blocks_read(positions, block)))
         telemetry.count("serve/kv_blocks_read", float(read))
         telemetry.count("serve/kv_blocks_arena", float(arena))
         self.metrics.on_kv_read(read, arena)

@@ -196,10 +196,11 @@ class ServingMetrics:
         self.spec_proposed += int(proposed)
         self.spec_accepted += int(accepted)
 
-    def on_kv_read(self, read: int, arena: int) -> None:
+    def on_kv_read(self, read: float, arena: int) -> None:
         """One decode chunk consumed: blocks of a layer's KV rows its steps
-        read, and the blocks those steps would read of the whole arena."""
-        self.kv_blocks_read += int(read)
+        read, and the blocks those steps would read of the whole arena
+        (``read`` may be fractional: a mean over a block's layers)."""
+        self.kv_blocks_read += read
         self.kv_blocks_arena += int(arena)
 
     # ------------------------------------------------------------ reading

@@ -470,7 +470,12 @@ def test_a_retired_lane_s_rows_stay_as_they_were(served):
     next occupant's prefill is inserted, chunks run with that lane's cursor
     at ``max_seq_len``: all four of its leaves are bit for bit what they
     were."""
-    snaps = served[4]
+    assert _retired_lanes_held(served[4]) >= 3
+
+
+def _retired_lanes_held(snaps):
+    """(pump, lane) pairs a lane was nobody's on both sides of, after
+    asserting that all four of its leaves are bit for bit what they were."""
     held = 0
     for (run0, leaves0), (run1, leaves1) in zip(snaps, snaps[1:]):
         for lane in range(3):
@@ -481,7 +486,7 @@ def test_a_retired_lane_s_rows_stay_as_they_were(served):
                 assert np.array_equal(leaves0[name][:, lane],
                                       leaves1[name][:, lane]), (name, lane)
             held += 1
-    assert held >= 3
+    return held
 
 
 def test_the_arena_is_two_kinds_of_leaf_and_a_layer_owns_one(served):
@@ -497,7 +502,9 @@ def test_the_arena_is_two_kinds_of_leaf_and_a_layer_owns_one(served):
     assert rep["rows_per_slot"] == (4 * W + MAX) // 5 == 25
     assert eng.kv.head_dim(4) is None       # flat rows: no leaf has heads
     assert arch.lane_bytes(dict(TOY)) == per_slot // 2      # at 2 B a value
-    # grouped heads: the step reads both kinds' leaves of every lane whole
+    # on the CPU "auto" takes the einsum (and the toy's flat rows of 32
+    # values are not whole 128-lane rows): the step reads both kinds'
+    # leaves of every lane whole
     assert eng.module.decode_read_block(3) is None
     assert eng._kv_read_block is None
     m = eng.metrics
@@ -529,6 +536,74 @@ def test_the_chunk_program_counts_live_rows_from_positions(served):
     assert routing["prefill_pairs_held"] == sum(PROMPT_LENS) * 4 * 2
 
 
+# 8 query heads on 2 key heads of 64: flat rows of 128 values, the widths
+# the live-rows read takes (the toy's rows of 32 values are not); float32
+# holds h to whole tiles of 8 rows. Blocks of 16 rows: the whole ring, a
+# quarter of a global leaf
+KERNEL_TOY = dict(TOY, num_attention_heads=8, head_dim=64)
+KERNEL_BLOCK = 16
+
+
+@pytest.fixture(scope="module")
+def served_by_both_reads():
+    """``KERNEL_TOY``'s requests as ``served``'s, once through the masked
+    einsum and once with the live-rows read's ``"auto"`` resolved as on a
+    TPU (the kernel, interpreted) for as long as the engine is built and
+    runs; every other kernel's choice stays the CPU's."""
+    import jax
+    from deepspeed_tpu.ops.pallas import _utils as kernels
+    model = arch.build_model(KERNEL_TOY)
+    params = jax.jit(lambda k: arch.init_params(model, k))(
+        jax.random.PRNGKey(3))
+    einsum = _serve(KERNEL_TOY, params)
+    cpu_path = kernels.auto_path
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(kernels, "auto_path", lambda kernel, why: (
+            why is None if kernel == "decode_attention"
+            else cpu_path(kernel, why)))
+        return einsum, _serve(KERNEL_TOY, params)
+
+
+def test_served_tokens_through_the_live_read_are_the_einsum_s(
+        served_by_both_reads):
+    """Token for token the einsum path's, lanes crossing the window's edge,
+    wrapped rings and retired lanes in the same chunks."""
+    einsum, kernel = served_by_both_reads
+    assert einsum[0]._kv_read_block is None
+    assert kernel[0]._kv_read_block == KERNEL_BLOCK
+    for by_einsum, by_kernel, budget in zip(einsum[3], kernel[3], BUDGETS):
+        assert by_einsum.status == by_kernel.status == "done"
+        assert len(by_kernel.tokens) == budget
+        assert list(by_kernel.tokens) == list(by_einsum.tokens)
+
+
+def test_the_live_read_counts_the_blocks_it_reads(served_by_both_reads):
+    """``kv_rows_read`` against a replay by hand: a live lane at position t
+    reads ``ceil(min(t + 1, 16) / 16)`` blocks of 16 rows in each of the
+    four sliding layers and ``ceil((t + 1) / 16)`` in the full one; a
+    retired lane reads nothing, so the rows read follow the requests alone
+    and not the chunks. The rows live are the einsum path's."""
+    einsum, kernel = served_by_both_reads
+    prompts, reqs = kernel[2], kernel[3]
+    ring = glob = 0
+    for prompt, req in zip(prompts, reqs):
+        for t in range(len(prompt), len(prompt) + len(req.tokens) - 1):
+            ring += 4 * -(-min(t + 1, W) // KERNEL_BLOCK)
+            glob += -(-(t + 1) // KERNEL_BLOCK)
+    got = kernel[0].metrics.state_rows
+    assert got["kv_rows_read"] == KERNEL_BLOCK * (ring + glob)
+    assert got["kv_rows_read"] < einsum[0].metrics.state_rows["kv_rows_read"]
+    for name in ("kv_window_rows_live", "kv_global_rows_live"):
+        assert got[name] == einsum[0].metrics.state_rows[name]
+    m = kernel[0].metrics
+    assert 0 < m.kv_blocks_read < m.kv_blocks_arena
+
+
+def test_a_retired_lane_s_rows_stay_as_they_were_through_the_live_read(
+        served_by_both_reads):
+    assert _retired_lanes_held(served_by_both_reads[1][4]) >= 3
+
+
 def test_step_counters_count_live_lanes_only(toy):
     import jax.numpy as jnp
     model = toy[0]
@@ -542,16 +617,49 @@ def test_step_counters_count_live_lanes_only(toy):
         == [(4 * 1 + 1) / 5, (4 * 2 + 3) / 5, (4 * 2 + 6) / 5]
 
 
-def test_grouped_heads_are_refused_by_name(toy, past_auto_path):
-    """``"auto"`` resolved as on a TPU: the live-rows read is asked and
-    refuses grouped heads BY NAME, whatever else would hold."""
-    from deepspeed_tpu.ops.pallas.decode_attention import (
-        live_decode_grouped_refusal)
-    assert toy[0].decode_read_block(3) is None
-    kernel, refusal = past_auto_path[-1]
-    assert kernel == "decode_attention" and "grouped-query heads" in refusal
-    assert "4 query heads read 2 key heads" in refusal
-    assert live_decode_grouped_refusal(32, 32) is None
+def _cell_cfg(**over):
+    """``trinity-mini-l5``'s attention at the cell's shape: 32 query heads
+    on 4 key heads of 128, a ring of 2,048 rows beside 20,480 global rows,
+    bfloat16."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.models import afmoe
+    from deepspeed_tpu.models.gpt import GPTConfig
+    block = dict(num_kv_heads=4, head_dim=128, sliding_window=2048,
+                 layer_types=(S, S, S, F, S), n_routed_experts=128,
+                 moe_d_ff=1024)
+    block.update({k: v for k, v in over.items() if k in block})
+    kw = dict(vocab_size=200192, max_seq_len=20480, num_layers=5,
+              num_heads=32, d_model=2048, d_ff=6144, dtype=jnp.bfloat16)
+    kw.update({k: v for k, v in over.items() if k not in block})
+    return GPTConfig(block=afmoe.AfmoeBlockConfig(**block), **kw)
+
+
+def test_the_cells_shape_takes_the_live_read_in_blocks_of_512_rows(
+        past_auto_path):
+    """``"auto"`` resolved as on a TPU: the live-rows read takes grouped
+    heads over flat rows, asked by the name the NeoX read is asked by, in
+    blocks of 512 flat rows of 512 bf16 values (512 KiB a leaf)."""
+    from deepspeed_tpu.models import afmoe
+    assert afmoe.decode_read_block(_cell_cfg(), 64) == 512
+    assert past_auto_path == [("decode_attention", None)]
+    assert afmoe.decode_read_block(_cell_cfg(decode_impl="xla"), 64) is None
+
+
+@pytest.mark.parametrize("over,named", [
+    (dict(head_dim=16), "a flat row of 64 values is not whole 128-lane rows"),
+    (dict(num_heads=12, num_kv_heads=3), "h=12 heads are not whole 16-row"),
+    (dict(num_heads=32, num_kv_heads=3, head_dim=128),
+     "not key heads of d=128 that h=32 query heads share evenly"),
+    (dict(max_seq_len=20000), "20000 is not a multiple of the 512-row"),
+    (dict(sliding_window=1280), "1280 is not a multiple of the 512-row"),
+], ids=["row of 64", "12 heads", "3 key heads", "global rows", "ring rows"])
+def test_the_grouped_gate_refuses_by_name(past_auto_path, over, named):
+    """What the read cannot take, each refused by name through
+    ``auto_path``: the step then reads both leaves whole with the einsum."""
+    from deepspeed_tpu.models import afmoe
+    assert afmoe.decode_read_block(_cell_cfg(**over), 64) is None
+    (kernel, refusal), = past_auto_path
+    assert kernel == "decode_attention" and named in refusal, refusal
 
 
 @pytest.mark.parametrize("asked,named", [

@@ -119,6 +119,127 @@ def test_live_rows_read_matches_the_masked_einsum(dtype, h, tol, case):
     assert not got[~live].any()
 
 
+# grouped heads over FLAT rows (models/afmoe.py): 32 query heads on 4 key
+# heads of 128, a ring-sized pair (a few blocks) and a global-sized one. The
+# five lanes: an empty fill, one row, a fill inside a block, the whole
+# leaf, a fill past the leaf (a masked lane)
+GH, GHK = 32, 4
+GROUPED_FILLS = {"ring": (4 * BK, (0, 1, BK + 5, 4 * BK, 4 * BK + 1)),
+                 "global": (16 * BK, (0, 1, 3 * BK + 7, 16 * BK, 16 * BK + 1))}
+
+
+@pytest.mark.parametrize("pair", list(GROUPED_FILLS))
+@pytest.mark.parametrize("dtype,tol", [(jnp.bfloat16, 2e-2),
+                                       (jnp.float32, 2e-5)],
+                         ids=["bf16", "f32"])
+def test_grouped_heads_over_flat_rows_match_cache_attention(dtype, tol,
+                                                            pair):
+    """Layer-stacked flat leaves ``[L, b, S, hk * d]`` at a traced layer:
+    lanes with a live row agree with ``afmoe.cache_attention`` (the queries
+    widened to the rows' columns, the masked einsum over the layer's whole
+    rows) to the einsum's own tolerance; a lane with no live row, or with
+    a fill past the leaf, reads nothing and is zeros. NaN in every block no
+    fill reaches (never read) and 1e30 in the dead rows of a block a fill
+    ends in (read and masked) never reach the output."""
+    from deepspeed_tpu.models.afmoe import cache_attention
+    rows, fills = GROUPED_FILLS[pair]
+    fills = jnp.asarray(fills, jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(keys[0], (B, 1, GH, D), dtype)
+    k, v = (jax.random.normal(key, (L, B, rows, GHK * D), dtype)
+            for key in keys[1:])
+    layer = 2
+
+    def filled(leaf, blocks, partial):
+        return _dead_rows_filled(leaf[..., None], layer, fills, blocks,
+                                 partial)[..., 0]
+    got = jax.jit(lambda q, k, v, f, i: live_decode_attention(
+        q, [(k, v, f)], i, block_k=BK))(
+            q, filled(k, np.nan, 1e30), filled(v, np.nan, 1e30), fills,
+            jnp.int32(layer))
+    assert got.shape == q.shape and got.dtype == q.dtype
+    seen = jnp.arange(rows)[None, :] < fills[:, None]
+    ref = cache_attention(q, filled(k, 0.0, 0.0)[layer],
+                          filled(v, 0.0, 0.0)[layer], seen, GHK, dtype)
+    live = np.asarray((fills > 0) & (fills <= rows))
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    np.testing.assert_allclose(got[live], ref[live], atol=tol, rtol=tol)
+    assert not got[~live].any()
+
+
+@pytest.mark.parametrize("layout", ["rank-5 heads", "flat grouped rows"])
+def test_one_kernel_reads_either_layout_by_the_leaf_s_rank(layout):
+    """The rank-5 leaf of a head a query head (NeoX, EvaByte) and the flat
+    leaf of grouped heads go through the ONE kernel, told apart by the
+    leaf's rank alone: each equals its own masked einsum at the same
+    fills."""
+    from deepspeed_tpu.models.afmoe import cache_attention
+    fills = jnp.asarray((1, BK, BK + 1, S, 7), jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(9), 3)
+    q = jax.random.normal(keys[0], (B, 1, 8, D), jnp.float32)
+    if layout == "rank-5 heads":
+        k, v = (jax.random.normal(key, (L, B, S, 8, D)) for key in keys[1:])
+        ref = masked_cache_attention(q, k[1], v[1], fills - 1,
+                                     1.0 / np.sqrt(D))
+    else:
+        k, v = (jax.random.normal(key, (L, B, S, 2 * D)) for key in keys[1:])
+        ref = cache_attention(q, k[1], v[1],
+                              jnp.arange(S)[None, :] < fills[:, None], 2,
+                              jnp.float32)
+    got = live_decode_attention(q, [(k, v, fills)], 1, block_k=BK)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+def _kernel_ops(fn, *args):
+    """(primitive name, output shape) of every equation of the one
+    ``pallas_call``'s kernel that ``fn`` traces to, nested bodies included."""
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            yield e.primitive.name, tuple(tuple(v.aval.shape)
+                                          for v in e.outvars)
+            for p in e.params.values():
+                for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from walk(sub)
+    calls = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    return list(walk(calls[0].params["jaxpr"]))
+
+
+@pytest.mark.parametrize("pairs", [1, 2], ids=["one pair", "two pairs"])
+def test_the_rank5_call_traces_none_of_the_flat_branch(pairs):
+    """The layout is chosen while tracing, from the leaf's rank: the rank-5
+    kernel (NeoX one pair, EvaByte two) meets the h queries with
+    ``block_k * h`` key rows, scores ``[h, block_k * h]`` under the
+    own-head mask ``col % h == row``, and keeps the whole ``[h, d]``
+    context a lane; none of the flat branch's ``[h, block_k]`` scores or
+    its per-key-head selection of the result is in it. The flat call is
+    the other way round."""
+    h, hk = 16, 4
+    q = jnp.zeros((B, 1, h, D), jnp.bfloat16)
+    f = jnp.zeros((B,), jnp.int32)
+    rank5 = [jnp.zeros((L, B, n, h, D), jnp.bfloat16) for n in (S, S2)]
+    flat = [jnp.zeros((L, B, n, hk * D), jnp.bfloat16) for n in (S, S2)]
+
+    def call(*leaves):
+        return live_decode_attention(
+            q, [(k, k, f) for k in leaves[:pairs]], 1, block_k=BK)
+    for leaves, mine, other in ((rank5, (h, BK * h), (h, BK)),
+                                (flat, (h, BK), (h, BK * h))):
+        ops = _kernel_ops(call, *leaves)
+        dots = [outs[0] for name, outs in ops if name == "dot_general"]
+        assert mine in dots and other not in dots
+        own_head_mask = any(name == "rem" and out == ((h, BK * h),)
+                            for name, out in ops)
+        head_select = any(name == "iota" and out == ((h, 1),)
+                          for name, out in ops)
+        assert own_head_mask == (leaves is rank5)
+        assert head_select == (leaves is flat)
+
+
 def test_one_layers_own_leaf_and_a_scalar_fill():
     """``layer`` None is the unscanned model's [b, S, h, d] leaf, a scalar
     fill the single-stream ``generate()``: the same read."""
@@ -160,6 +281,17 @@ def test_the_gate_accepts_the_cells_shape():
     """``serve-batch``: 8 lanes of 2048 rows, 32 heads of 128, bf16."""
     assert live_decode_refusal(8, 2048, 32, 128, jnp.bfloat16) is None
     assert da.live_block(2048) == 128
+
+
+@pytest.mark.parametrize("S,row_bytes,block", [
+    (2048, None, 128),          # rows of a head a query head: 128 rows
+    (2048, 8192, 128),          # 4,096 bf16 values a flat row: never fewer
+    (2048, 1024, 512),          # serve-agent's 512 bf16 values: 512 KiB
+    (20480, 768, 512),          # 384 values: the power of two under 682
+    (16, 512, 16),              # never more rows than the leaf holds
+], ids=["heads", "4096 values", "512 values", "384 values", "short leaf"])
+def test_a_block_of_flat_rows_is_chosen_by_their_bytes(S, row_bytes, block):
+    assert da.live_block(S, row_bytes) == block
 
 
 def test_the_gate_is_asked_of_every_pairs_leaf():

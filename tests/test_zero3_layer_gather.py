@@ -404,6 +404,59 @@ def test_latent_live_read_compiles_for_v5e_at_serve_reasons_shape(
         jax.config.update("jax_enable_compilation_cache", cache)
 
 
+def test_grouped_live_read_compiles_for_v5e_at_serve_agents_shape(
+        v5e_2x2, monkeypatch):
+    """``serve-agent``'s decode read: 64 lanes, flat rows of 4 key heads of
+    128 under 32 query heads in bf16, a ring pair of 4 x 2,048 rows beside
+    a global pair of 1 x 20,480; a scan over the four expert layers
+    writes a token into both pairs and reads the pair its layer owns under
+    ``lax.cond``, as ``models/afmoe.py`` does. One kernel call a branch at
+    the block ``live_block`` gives (512 rows), the four leaves aliased
+    (3.76 GB) and no copy of a layer's rows beside them."""
+    from jax.sharding import SingleDeviceSharding
+    from deepspeed_tpu.models.gpt import _kv_write
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    monkeypatch.setattr(da, "interpret_mode", lambda: False)
+    b, h, d, row, w, S = 64, 32, 128, 512, 2048, 20480
+    assert da.live_block(w, row * 2) == 512
+
+    def step(q, leaves, cur):
+        def body(c, at):
+            q, (wk, wv, gk, gv) = c
+            full, slot = at
+            k = q.reshape(b, 1, h * d)[..., :row]
+            wk, wv = (_kv_write(x, k, cur % w, slot) for x in (wk, wv))
+            gk, gv = (_kv_write(x, k, cur, slot) for x in (gk, gv))
+            o = jax.lax.cond(
+                full,
+                lambda: da.live_decode_attention(q, [(gk, gv, cur + 1)], slot),
+                lambda: da.live_decode_attention(
+                    q, [(wk, wv, jnp.minimum(cur + 1, w))], slot))
+            return (o, (wk, wv, gk, gv)), None
+        return jax.lax.scan(body, (q, tuple(leaves)), (
+            jnp.array([False, False, True, False]),
+            jnp.array([1, 2, 0, 3], jnp.int32)))[0]
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+    leaves = [jax.ShapeDtypeStruct((n, b, rows, row), jnp.bfloat16,
+                                   sharding=one)
+              for n, rows in ((4, w), (4, w), (1, S), (1, S))]
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(
+            jax.ShapeDtypeStruct((b, 1, h, d), jnp.bfloat16, sharding=one),
+            leaves,
+            jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * 64 * (4 * w + S) * row * 2
+    assert mem.temp_size_in_bytes < 16 * 2 ** 20, mem
+
+
 def test_band_kernel_compiles_for_v5e_at_serve_agents_longest_prefill(
         v5e_2x2, monkeypatch):
     """``serve-agent``'s prefill attention at its longest bucket: 16,384
